@@ -53,7 +53,7 @@ from jax.sharding import PartitionSpec as P
 
 #: Compiled window-step executables shared across aggregation instances,
 #: keyed by (step_cache_key(), vcap, mesh, tree-ness). Compiling the fused
-#: window program costs seconds on a remote TPU; a fresh aggregation object
+#: window program costs seconds; a fresh aggregation object
 #: per stream must not pay it again. Bounded FIFO: each cached closure
 #: pins the aggregation instance it was built from (and thereby one
 #: summary pytree), so unbounded growth would leak device arrays across

@@ -159,4 +159,7 @@ def supervised_emissions(path: str, every, make_stream, work,
 
 def run_main(main_fn):
     """python -m entry point."""
+    from ..utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main_fn(sys.argv[1:])

@@ -125,7 +125,9 @@ def _auto_carry() -> str:
 
         native.CompactUnionFind()
         return "host"
-    except Exception:
+    except RuntimeError:
+        # no toolchain (native.build_error() says why): the forest carry
+        # needs no native code
         return "forest"
 
 

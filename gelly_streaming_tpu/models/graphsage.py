@@ -73,24 +73,26 @@ def sage_layer(
     """One GraphSAGE layer: act(h @ W_self + mean_nbr(h) @ W_nbr + b).
 
     ``use_pallas=True`` routes the dense dual-matmul through the fused
-    Pallas kernel (``ops/pallas_kernels.py``) — relu activation only;
-    aggregation stays on the XLA scatter path either way.
+    Pallas kernel (``ops/pallas_kernels.py``) — relu activation only, TPU
+    only (it raises elsewhere); aggregation stays on the XLA scatter path
+    either way.
     """
     agg = mean_aggregate(h, src, dst, mask, h.shape[0], axis_name=axis_name)
     if use_pallas:
-        from ..ops.pallas_kernels import fused_sage_matmul, pallas_available
+        from ..ops.pallas_kernels import (
+            fused_sage_matmul,
+            require_tpu_for_pallas,
+        )
 
         if activation is not jax.nn.relu:
             raise ValueError(
                 "use_pallas=True supports only the default relu activation"
             )
-        if pallas_available():
-            return fused_sage_matmul(
-                h, agg, params["w_self"], params["w_nbr"], params["b"],
-                activation="relu",
-            )
-        # off-TPU: the XLA dense path below is the fast fallback
-        # (interpret mode is a test-only emulator)
+        require_tpu_for_pallas()
+        return fused_sage_matmul(
+            h, agg, params["w_self"], params["w_nbr"], params["b"],
+            activation="relu",
+        )
     out = (
         jnp.dot(h, params["w_self"], preferred_element_type=jnp.float32)
         + jnp.dot(agg, params["w_nbr"], preferred_element_type=jnp.float32)

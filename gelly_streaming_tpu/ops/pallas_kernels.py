@@ -25,8 +25,9 @@ candidates were evaluated with measurements (round-2):
   detail), three orders of magnitude above the host-bound end-to-end
   rate; streaming row pairs by hand cannot move any system number.
 
-All kernels run in ``interpret=True`` mode off-TPU, which is how the CPU
-test suite covers them.
+Off-TPU the kernel runs only in ``interpret=True`` mode, which the caller
+passes explicitly (the CPU test suite does); asking for the compiled
+kernel where it cannot run raises.
 """
 
 from __future__ import annotations
@@ -112,9 +113,15 @@ def fused_sage_matmul(
     return out[:V, :o_dim]
 
 
-def pallas_available() -> bool:
-    """True when a real TPU backend is present (interpret mode aside)."""
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+def require_tpu_for_pallas() -> None:
+    """Raise unless the default device is a TPU: the compiled kernel runs
+    nowhere else. Asking for it off-TPU is an error, not a silent detour
+    through XLA; a test that wants the interpreter passes
+    ``interpret=True`` to :func:`fused_sage_matmul` itself."""
+    platform = jax.devices()[0].platform
+    if platform != "tpu":
+        raise RuntimeError(
+            f"the Pallas kernel was asked for on platform {platform!r}; it "
+            "compiles only for a TPU (tests pass interpret=True to "
+            "fused_sage_matmul directly)"
+        )

@@ -154,9 +154,10 @@ def segmented_fold(
     .. warning:: **Cost model — prefer tiers 1-2 at scale.** Arrival-order
        semantics with an arbitrary (possibly non-associative) ``fold_fn``
        force a SEQUENTIAL ``lax.scan`` over the whole window: per-window
-       depth is the edge count, so throughput is per-edge scan-step rate
-       (~1-5M eps, measured in ``BENCH_DETAIL.json: segmented_fold_eps``)
-       regardless of window size — three orders below the scatter tiers.
+       depth is the edge count, so throughput is the per-edge scan-step
+       rate regardless of window size — far below the scatter tiers
+       (``bench.py --all`` times it as ``segmented_fold_eps``; not
+       measured on the current code).
        Use it only when the fold is genuinely order-dependent and
        non-associative, exactly like the reference's sequential
        ``EdgesFold``. Otherwise:

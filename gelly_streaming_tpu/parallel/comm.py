@@ -35,29 +35,14 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh
 
-try:  # jax>=0.6 moved shard_map to jax.shard_map
-    from jax import shard_map as _shard_map_fn  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_fn  # type: ignore
-
-
-# the relaxed-check kwarg was renamed check_rep -> check_vma across JAX
-# releases; resolve which one this install accepts ONCE at import
-import inspect as _inspect
-
-_SM_PARAMS = _inspect.signature(_shard_map_fn).parameters
-_SM_CHECK_KW = (
-    "check_vma" if "check_vma" in _SM_PARAMS
-    else "check_rep" if "check_rep" in _SM_PARAMS
-    else None
-)
-
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma: bool = False):
-    """Thin wrapper over jax.shard_map with relaxed varying-manual-axes checks."""
-    kw = {} if _SM_CHECK_KW is None else {_SM_CHECK_KW: check_vma}
-    return _shard_map_fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         **kw)
+    """Thin wrapper over ``jax.shard_map`` with relaxed
+    varying-manual-axes checks."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_vma,
+    )
 
 
 # --------------------------------------------------------------------------- #

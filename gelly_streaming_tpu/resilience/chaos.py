@@ -132,6 +132,10 @@ def worker_main(cfg: dict) -> None:
 
     jax.config.update("jax_platforms", "cpu")
 
+    from ..utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from ..aggregate.autockpt import AutoCheckpoint
     from ..core.stream import SimpleEdgeStream
     from ..core.window import CountWindow
@@ -238,6 +242,10 @@ def mp_worker_main(cfg: dict) -> None:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
+
+    from ..utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     import numpy as np
 
@@ -376,6 +384,10 @@ def failover_main(cfg: dict) -> None:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
+
+    from ..utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     import numpy as np
 

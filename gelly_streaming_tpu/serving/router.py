@@ -1785,8 +1785,10 @@ def router_main(cfg: dict) -> None:
 
     from ..obs import trace as obs_trace
     from ..obs.cluster import ShardSink
+    from ..utils.compile_cache import enable_compile_cache
     from .rpc import RpcServer
 
+    enable_compile_cache()
     sink = None
     if cfg.get("events"):
         sink = ShardSink(cfg["events"], shard=cfg.get("shard"))

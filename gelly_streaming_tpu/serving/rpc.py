@@ -1334,6 +1334,10 @@ def replica_main(cfg: dict) -> None:
 
     jax.config.update("jax_platforms", "cpu")
 
+    from ..utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from ..obs import flight as obs_flight
     from ..obs import trace as obs_trace
     from ..obs.cluster import ShardSink

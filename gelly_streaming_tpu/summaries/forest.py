@@ -58,7 +58,7 @@ _I32_MAX = jnp.iinfo(jnp.int32).max
 
 #: jitted per-(Tcap, Wcap, vcap, mesh, tree, degree) window steps;
 #: bounded FIFO like the engine's step cache (each signature costs
-#: seconds on a remote TPU).
+#: seconds of compilation).
 _FOREST_STEP_CACHE: dict = {}
 _FOREST_STEP_CACHE_MAX = 32
 
@@ -307,7 +307,9 @@ class WindowPrep:
             from .. import native
 
             self._native = native.NativeWindowPrep()
-        except Exception:
+        except RuntimeError:
+            # no toolchain: the numpy twin below; native.build_error()
+            # keeps the compiler's message for callers that must refuse it
             self._native = None
 
     def prep(self, src_h, dst_h, vcap: int):

@@ -145,7 +145,7 @@ def test_decode_fallback_matches_native(monkeypatch):
 
     with_native, parsed_native = decode_all()
     monkeypatch.setattr(native, "_lib", None)
-    monkeypatch.setattr(native, "_lib_failed", True)
+    monkeypatch.setattr(native, "_lib_error", "forced away by the test")
     without, parsed_py = decode_all()
     for (s1, d1, v1), (s2, d2, v2) in zip(with_native, without):
         assert s1.tolist() == s2.tolist()

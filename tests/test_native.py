@@ -274,3 +274,76 @@ def test_native_window_prep_matches_numpy_fallback():
         # ids out of range raise
         with pytest.raises(ValueError):
             prep.run(np.array([V], np.int32), np.array([0], np.int32), V)
+
+
+def test_failed_native_build_keeps_the_compiler_message(
+    monkeypatch, tmp_path
+):
+    """The numpy twins keep the package working without a toolchain, but
+    the reason is no longer thrown away: a caller that must not run on
+    them (the chip smoke, a benchmark) can refuse and say why."""
+    from gelly_streaming_tpu.summaries.forest import WindowPrep
+
+    # point the lazy build at a private source + output and forget the
+    # loaded library, so _load runs its one build attempt again
+    src = tmp_path / "ingest.cpp"
+    src.write_text("this is not C++ @@@ ;\n#error refused\n")
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_SO", str(tmp_path / "_ingest.so"))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_lib_error", None)
+    assert native.native_available() is False
+    msg = native.build_error()
+    assert "g++" in msg and "exited" in msg
+    assert "error" in msg and "refused" in msg  # the compiler's own words
+    # one attempt, remembered: no rebuild per call
+    assert native.native_available() is False
+    assert native.build_error() == msg
+    # no half-written output is left behind
+    assert [p.name for p in tmp_path.iterdir()] == ["ingest.cpp"]
+    # the twins still serve; the forest prep says which one it is
+    prep = WindowPrep()
+    assert prep._native is None
+    tids, lu, lv = prep.prep(
+        np.array([3, 1], np.int32), np.array([1, 2], np.int32), 8
+    )
+    assert sorted(tids.tolist()) == [1, 2, 3]
+    with pytest.raises(RuntimeError, match="unavailable"):
+        native.cc_baseline(np.zeros(1, np.int64), np.zeros(1, np.int64), 1)
+
+
+def test_two_processes_building_together_do_not_race(tmp_path):
+    """Fresh checkouts start processes in bunches (a replica fleet, two
+    smokes): each builds to its own temporary name and renames it into
+    place, so none loads — or fails on — another's half-written file."""
+    import os
+    import shutil
+    import subprocess
+    import sys
+
+    if not native.native_available():
+        pytest.skip("no native toolchain")
+    pkg = os.path.dirname(os.path.abspath(native.__file__))
+    shutil.copy(os.path.join(pkg, "ingest.cpp"), tmp_path / "ingest.cpp")
+    code = (
+        "import sys, os\n"
+        "import gelly_streaming_tpu.native as n\n"
+        f"n._SRC = {str(tmp_path / 'ingest.cpp')!r}\n"
+        f"n._SO = {str(tmp_path / '_ingest.so')!r}\n"
+        "ok = n.native_available()\n"
+        "print('BUILD', ok, n.build_error())\n"
+        "sys.exit(0 if ok else 1)\n"
+    )
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root)
+    procs = [
+        subprocess.Popen(
+            [sys.executable, "-c", code], env=env,
+            stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
+        )
+        for _ in range(3)
+    ]
+    outs = [p.communicate(timeout=300)[0] for p in procs]
+    assert [p.returncode for p in procs] == [0, 0, 0], outs
+    left = sorted(p.name for p in tmp_path.iterdir())
+    assert left == ["_ingest.so", "_ingest.so.hash", "ingest.cpp"], left
