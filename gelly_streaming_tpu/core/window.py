@@ -759,6 +759,7 @@ class Windower:
         event-time ``timestamp_fn`` sees compact ids in columns 0/1.
         """
         policy = self.policy
+        chunks = _timed_pulls(chunks)
         if isinstance(policy, CountWindow):
             yield from self._chunk_count_windows(chunks, policy.size, encoded)
         elif isinstance(policy, EventTimeWindow):
@@ -796,6 +797,25 @@ class Windower:
         runs = iter_time_slot_runs(chunks, policy, val_dtype=self.val_dtype)
         for index, (slot, src, dst, val) in enumerate(runs):
             yield self._info(index, slot), build(src, dst, val)
+
+
+_NO_CHUNK = object()
+
+
+def _timed_pulls(chunks: Iterable[Tuple]) -> Iterator[Tuple]:
+    """``chunks``, with every pull on it under the span
+    ``ingest.wait_source``: the time the ingest thread waits for its
+    next input (a paced source, a socket, a file parse), which is where
+    an idle device's gaps belong. The span wraps the pull ALONE and is
+    closed before the chunk is yielded: a span left open across a
+    generator's yield would mis-nest the thread's span stack."""
+    it = iter(chunks)
+    while True:
+        with _trace.span("ingest.wait_source"):
+            cols = next(it, _NO_CHUNK)
+        if cols is _NO_CHUNK:
+            return
+        yield cols
 
 
 def take_cols(pend: list, take: int, val_dtype=np.float64):

@@ -81,6 +81,7 @@ from .trace import (
     activate,
     current_context,
     current_span,
+    device_trace,
     disable,
     enable,
     enabled,
@@ -165,6 +166,7 @@ __all__ = [
     "current_context",
     "current_span",
     "detach_sink",
+    "device_trace",
     "disable",
     "enable",
     "enabled",

@@ -8,7 +8,16 @@ global registry's ``trace.span_seconds{span=...}`` histogram, so span
 timing shows up in the same snapshot/Prometheus surface as every other
 metric. Optionally (``enable(jax_annotations=True)``) each span also
 opens a ``jax.profiler.TraceAnnotation`` so host stages line up against
-device ops in TensorBoard traces.
+device ops in TensorBoard traces; :func:`device_trace` is the operator's
+form of that (profiler on, annotations on, for one block).
+
+ONE CLOCK: every span event carries ``t0``, the span's START on
+``time.perf_counter()``, beside ``dur_s`` (same clock) and ``ts`` (wall
+clock at exit; the cluster timeline and the JSONL replay read that one).
+Cross-thread ``record_span`` events are never profiler annotations, so
+``t0`` is what places them on a device trace: bracket any annotation the
+profiler did record with two ``perf_counter`` reads and the offset
+between the two clocks is known to within that bracket.
 
 CROSS-PROCESS TRACES (ISSUE 9): a :class:`TraceContext` carries a trace
 id + a parent span id across threads, futures, and the RPC wire. The
@@ -43,6 +52,7 @@ documents for throughput measurement.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import os
 import threading
@@ -51,12 +61,14 @@ from typing import Optional
 
 
 class _Config:
-    __slots__ = ("enabled", "annotate_jax", "registry_spans")
+    __slots__ = ("enabled", "annotate_jax", "registry_spans",
+                 "key_metadata_was")
 
     def __init__(self):
         self.enabled = False
         self.annotate_jax = False
         self.registry_spans = True
+        self.key_metadata_was = None
 
 
 _CFG = _Config()
@@ -84,14 +96,67 @@ def enable(*, jax_annotations: bool = False,
     histogram (on by default — it is what makes span timing visible to
     the Prometheus/snapshot exporters).
     """
-    _CFG.annotate_jax = bool(jax_annotations)
+    _set_annotate(bool(jax_annotations))
     _CFG.registry_spans = bool(registry_spans)
     _CFG.enabled = True
 
 
 def disable() -> None:
     _CFG.enabled = False
-    _CFG.annotate_jax = False
+    _set_annotate(False)
+
+
+_KEY_METADATA = "jax_compilation_cache_include_metadata_in_key"
+
+
+def _set_annotate(on: bool) -> None:
+    """``annotate_jax``, and with it whether JAX's persistent compilation
+    cache keys a program by its metadata too. A device trace names every
+    op by the metadata of the EXECUTABLE that ran (the ``forest.*``
+    scopes, the source lines). The cache's key leaves metadata out by
+    default, so a hit hands back whatever was first compiled from the
+    same HLO — on a machine whose cache an older checkout filled, that
+    checkout's names. Someone who turns annotations on is about to read
+    a device trace: from then on a program is compiled or loaded under a
+    key that holds its names, and the setting goes back with the
+    annotations. Programs already in memory keep the names they came
+    with."""
+    if on == _CFG.annotate_jax:
+        return
+    _CFG.annotate_jax = on
+    try:
+        import jax
+
+        if on:
+            _CFG.key_metadata_was = getattr(jax.config, _KEY_METADATA)
+            jax.config.update(_KEY_METADATA, True)
+        elif _CFG.key_metadata_was is not None:
+            jax.config.update(_KEY_METADATA, _CFG.key_metadata_was)
+            _CFG.key_metadata_was = None
+    except (ImportError, AttributeError):
+        pass   # no jax, or one without the option: spans still record
+
+
+@contextlib.contextmanager
+def device_trace(log_dir: str):
+    """Profile the block into ``log_dir`` (``jax.profiler``; TensorBoard
+    and ``ProfileData.from_file`` read the ``.xplane.pb`` it leaves) with
+    every span opened inside it written into the same trace as a
+    ``TraceAnnotation``, next to the device's ``forest.*`` /
+    ``query.chase`` scopes. Turns span recording and ``annotate_jax`` on
+    for the block and puts both back as they were: a server that runs
+    with tracing off pays for spans only while it is being traced."""
+    import jax
+
+    was = (_CFG.enabled, _CFG.annotate_jax)
+    _set_annotate(True)
+    _CFG.enabled = True
+    try:
+        with jax.profiler.trace(log_dir):
+            yield
+    finally:
+        _CFG.enabled = was[0]
+        _set_annotate(was[1])
 
 
 def add_sink(sink) -> None:
@@ -216,11 +281,14 @@ def record_span(
     sid: Optional[int] = None,
     attrs: Optional[dict] = None,
     ts: Optional[float] = None,
+    t0: Optional[float] = None,
 ) -> Optional[int]:
     """Emit one already-finished span event without entering the
     thread's span stack — the async/cross-thread form of ``span()``
     (future callbacks and drained-queue settles know their duration
     only after the fact, on a thread that never opened the span).
+    ``t0`` is the span's start on ``time.perf_counter()`` where the
+    call site knows it; left out, the span is taken to end now.
 
     Returns the span's sid (pass ``sid=`` to emit under a pre-reserved
     id from :func:`next_sid`), or None when tracing is disabled — the
@@ -232,6 +300,7 @@ def record_span(
         "kind": "span",
         "name": name,
         "ts": time.time() if ts is None else ts,
+        "t0": time.perf_counter() - dur_s if t0 is None else float(t0),
         "dur_s": float(dur_s),
         "sid": span_id,
         "depth": 0,
@@ -346,6 +415,7 @@ class Span:
             "kind": "span",
             "name": self.name,
             "ts": time.time(),
+            "t0": self.t0,
             "dur_s": self.dur_s,
             "sid": self.sid,
             "depth": self.depth,

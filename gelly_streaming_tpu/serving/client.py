@@ -512,6 +512,7 @@ class RpcClient:
                 _trace.record_span(
                     "rpc.client.resubmit",
                     time.perf_counter() - b.t_send,
+                    t0=b.t_send,
                     trace_id=b.ctx.trace_id,
                     parent=b.ctx.parent_sid,
                     attrs={"id": b.id},
@@ -652,6 +653,7 @@ class RpcClient:
             _trace.record_span(
                 "rpc.client.retry",
                 time.perf_counter() - batch.t_send,
+                t0=batch.t_send,
                 trace_id=batch.ctx.trace_id,
                 parent=batch.ctx.parent_sid,
                 attrs={"attempts": batch.attempts,
@@ -705,7 +707,7 @@ class RpcClient:
             # view can never account for)
             now = time.perf_counter()
             _trace.record_span(
-                "rpc.client.batch", e2e_s,
+                "rpc.client.batch", e2e_s, t0=batch.t0,
                 trace_id=batch.ctx.trace_id,
                 sid=batch.ctx.parent_sid,
                 parent=batch.parent_sid,

@@ -57,6 +57,7 @@ import numpy as np
 from jax import lax
 
 from ..core.edgeblock import bucket_capacity
+from ..obs import trace as _trace
 from ..obs.registry import get_registry
 from .snapshot_store import PublishedSnapshot
 
@@ -287,11 +288,13 @@ def decode_pull_doc(doc) -> dict:
 def _batch_roots(canon: jax.Array, ids: jax.Array) -> jax.Array:
     """Chase a BATCH of start ids to their forest roots. Read-only on
     ``canon``; terminates by the min-root invariant (chains strictly
-    decrease). Padding lanes chase from 0, always self-rooted."""
-    r = canon[ids]
-    return lax.while_loop(
-        lambda r: jnp.any(canon[r] != r), lambda r: canon[r], r
-    )
+    decrease). Padding lanes chase from 0, always self-rooted. Its ops
+    carry the scope ``query.chase`` in a device trace."""
+    with jax.named_scope("query.chase"):
+        r = canon[ids]
+        return lax.while_loop(
+            lambda r: jnp.any(canon[r] != r), lambda r: canon[r], r
+        )
 
 
 @jax.jit
@@ -327,6 +330,18 @@ def _pad_ids(ids: np.ndarray) -> np.ndarray:
     out = np.zeros(cap, np.int32)
     out[:n] = ids
     return out
+
+
+def _fetch(out: jax.Array, n: int) -> np.ndarray:
+    """The first ``n`` lanes of a dispatched batch kernel's result, on
+    the host. The copy blocks until the device has run the kernel, which
+    queues behind every fold dispatched before it: the span
+    ``serving.device_wait`` is that wait alone (the dispatch stays
+    outside it), the fold's part of ``serving.answer``."""
+    with _trace.span(
+        "serving.device_wait", {"n": n} if _trace.on() else None
+    ):
+        return np.asarray(out)[:n]
 
 
 def _lookup_batch(vdict, raw: np.ndarray) -> np.ndarray:
@@ -436,9 +451,10 @@ class QueryEngine:
     def _roots(self, table, ids: np.ndarray) -> np.ndarray:
         if self.prefer_host:
             return _host_batch_roots(table, ids)
-        return np.asarray(
-            _batch_roots(jnp.asarray(table), jnp.asarray(_pad_ids(ids)))
-        )[: len(ids)]
+        return _fetch(
+            _batch_roots(jnp.asarray(table), jnp.asarray(_pad_ids(ids))),
+            len(ids),
+        )
 
     # -- per-class batch kernels --------------------------------------- #
     def connected(
@@ -495,9 +511,10 @@ class QueryEngine:
         if self.prefer_host:
             out = np.asarray(sizes)[np.asarray(lab)[safe]]
         else:
-            out = np.asarray(
-                _gather_sizes(lab, sizes, jnp.asarray(_pad_ids(safe)))
-            )[: len(cv)]
+            out = _fetch(
+                _gather_sizes(lab, sizes, jnp.asarray(_pad_ids(safe))),
+                len(cv),
+            )
         return np.where(valid, out, 0).astype(np.int64)
 
     def summary_pull(
@@ -830,9 +847,10 @@ class QueryEngine:
         if self.prefer_host:
             got = table[safe]
         else:
-            got = np.asarray(
-                _gather(jnp.asarray(table), jnp.asarray(_pad_ids(safe)))
-            )[: len(cv)]
+            got = _fetch(
+                _gather(jnp.asarray(table), jnp.asarray(_pad_ids(safe))),
+                len(cv),
+            )
         return np.where(valid, got, fill)
 
     # -- heterogeneous batch ------------------------------------------- #

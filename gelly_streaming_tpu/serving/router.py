@@ -1068,6 +1068,7 @@ class ShardRouter:
             _trace.record_span(
                 "serving.router.pull",
                 time.perf_counter() - t0,
+                t0=t0,
                 trace_id=grp.ctx.trace_id,
                 parent=grp.sid,
                 sid=_trace.next_sid(),
@@ -1610,6 +1611,7 @@ class ShardRouter:
             _trace.record_span(
                 "serving.router.fanout",
                 time.perf_counter() - g.t0,
+                t0=g.t0,
                 trace_id=g.ctx.trace_id,
                 parent=g.ctx.parent_sid,
                 sid=g.sid,

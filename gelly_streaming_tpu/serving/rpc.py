@@ -494,7 +494,7 @@ class RpcServer:
                     decode_s = time.perf_counter() - t_recv
                     if ctx is not None:
                         _trace.record_span(
-                            "rpc.decode", decode_s,
+                            "rpc.decode", decode_s, t0=t_recv,
                             trace_id=ctx.trace_id,
                             parent=ctx.parent_sid,
                             attrs={"id": qid},
@@ -600,7 +600,7 @@ class RpcServer:
             batch.decode_s = decode_s
             batch.admit_s = time.perf_counter() - t_admit
             _trace.record_span(
-                "rpc.admit", batch.admit_s,
+                "rpc.admit", batch.admit_s, t0=t_admit,
                 trace_id=ctx.trace_id, parent=ctx.parent_sid,
                 attrs={"n": len(queries)},
             )
@@ -677,11 +677,12 @@ class RpcServer:
             now = time.perf_counter()
             ctx = batch.ctx
             _trace.record_span(
-                "rpc.reply", now - t_reply,
+                "rpc.reply", now - t_reply, t0=t_reply,
                 trace_id=ctx.trace_id, parent=ctx.parent_sid,
             )
             _trace.record_span(
                 "rpc.server.batch", now - batch.t_recv,
+                t0=batch.t_recv,
                 trace_id=ctx.trace_id, parent=ctx.parent_sid,
                 attrs={
                     "n": len(batch.slots),

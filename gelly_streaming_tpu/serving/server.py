@@ -638,6 +638,14 @@ class StreamServer:
         queries = [q for q, *_rest in batch]
         tracing = _trace.on()
         t_dispatch = time.perf_counter()
+        if tracing:
+            # how long the sweep's OLDEST entry sat in the admission
+            # queue (the value the admission tap takes below); every
+            # sweep, whether or not a TraceContext rides the batch
+            _trace.record_span(
+                "serving.queue_wait", t_dispatch - batch[0][2],
+                t0=batch[0][2], attrs={"batch": len(batch)},
+            )
         try:
             with _trace.span(
                 "serving.answer",
@@ -711,6 +719,7 @@ class StreamServer:
                 _trace.record_span(
                     "serving.query",
                     now - t0_min,
+                    t0=t0_min,
                     trace_id=ctx.trace_id,
                     parent=ctx.parent_sid,
                     attrs={

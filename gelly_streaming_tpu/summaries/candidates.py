@@ -29,7 +29,16 @@ import numpy as np
 from jax import lax
 
 from ..core.edgeblock import bucket_capacity
-from .forest import chase_and_group, commit_roots, pad_window
+from ..obs import trace as _trace
+from .forest import (
+    chase_and_group,
+    commit_roots,
+    new_roots,
+    note_buckets,
+    pad_window,
+    reroot,
+    window_span,
+)
 from .labels import _propagate, init_labels
 
 
@@ -88,8 +97,6 @@ def cover_grow(state: Dict[str, jax.Array], old_vcap: int, new_vcap: int) -> Dic
 _COVER_STEP_CACHE: dict = {}
 _COVER_STEP_CACHE_MAX = 32
 
-_I32_MAX = np.iinfo(np.int32).max
-
 
 def _cover_step_fn(tcap: int, wcap: int, vcap: int):
     """Window-local signed-cover step (round 5): the forest CC step
@@ -125,18 +132,20 @@ def _cover_step_fn(tcap: int, wcap: int, vcap: int):
         lv2 = jnp.concatenate([lv + tcap, lv])
         emask2 = jnp.concatenate([emask, emask])
         r, v2, key_, iota = chase_and_group(canon, tid2, tmask2, tcap2, vcap2)
-        u = jnp.concatenate([lu2, iota])
-        w = jnp.concatenate([lv2, v2])
-        m = jnp.concatenate([emask2, jnp.ones(tcap2, bool)])
-        local = _propagate(iota, u, w, m)
+        with jax.named_scope("forest.fixpoint"):
+            u = jnp.concatenate([lu2, iota])
+            w = jnp.concatenate([lv2, v2])
+            m = jnp.concatenate([emask2, jnp.ones(tcap2, bool)])
+            local = _propagate(iota, u, w, m)
         canon, nr = commit_roots(
             canon, local, key_, r, tid2, tmask2, tcap2, vcap2
         )
         # sibling conflict over the touched lanes (see docstring)
-        conflict = jnp.any(
-            tmask & (nr[:tcap] == nr[tcap:])
-        )
-        return canon, failed | conflict
+        with jax.named_scope("forest.latch"):
+            failed = failed | jnp.any(
+                tmask & (nr[:tcap] == nr[tcap:])
+            )
+        return canon, failed
 
     fn = jax.jit(step)
     if len(_COVER_STEP_CACHE) >= _COVER_STEP_CACHE_MAX:
@@ -151,17 +160,20 @@ def cover_forest_window(canon, failed, src_h, dst_h, vcap: int, prep):
     n = len(src_h)
     if n == 0:
         return canon, failed, np.zeros(0, np.int32)
-    tids, tcap, wcap, tid, tmask, lu, lv = pad_window(
-        prep, src_h, dst_h, vcap
-    )
-    emask = np.zeros(wcap, bool)
-    emask[:n] = True
-    step = _cover_step_fn(tcap, wcap, vcap)
-    canon, failed = step(
-        canon, failed,
-        jnp.asarray(tid), jnp.asarray(tmask),
-        jnp.asarray(lu), jnp.asarray(lv), jnp.asarray(emask),
-    )
+    with window_span(n) as sp:
+        tids, tcap, wcap, tid, tmask, lu, lv = pad_window(
+            prep, src_h, dst_h, vcap
+        )
+        note_buckets(sp, tids, tcap, wcap)
+        with _trace.span("forest.dispatch"):
+            emask = np.zeros(wcap, bool)
+            emask[:n] = True
+            step = _cover_step_fn(tcap, wcap, vcap)
+            canon, failed = step(
+                canon, failed,
+                jnp.asarray(tid), jnp.asarray(tmask),
+                jnp.asarray(lu), jnp.asarray(lv), jnp.asarray(emask),
+            )
     return canon, failed, tids
 
 
@@ -213,23 +225,22 @@ def _cover_superbatch_fn(tcap: int, wcap: int, vcap: int, k: int):
         def body(c, xs):
             lab, fail = c
             lu_k, lv_k, em_k = xs
-            u = jnp.concatenate([lu_k, iota])
-            w = jnp.concatenate([lv_k, lab])
-            m = jnp.concatenate([em_k, jnp.ones(tcap2, bool)])
-            lab = _propagate(lab, u, w, m)
-            minr = jnp.full(tcap2, _I32_MAX, jnp.int32).at[lab].min(key_)
-            nr = minr[lab]
-            fail = fail | jnp.any(tmask & (nr[:tcap] == nr[tcap:]))
+            with jax.named_scope("forest.fixpoint"):
+                u = jnp.concatenate([lu_k, iota])
+                w = jnp.concatenate([lv_k, lab])
+                m = jnp.concatenate([em_k, jnp.ones(tcap2, bool)])
+                lab = _propagate(lab, u, w, m)
+            with jax.named_scope("forest.commit"):
+                nr = new_roots(lab, key_, tcap2)
+            with jax.named_scope("forest.latch"):
+                fail = fail | jnp.any(tmask & (nr[:tcap] == nr[tcap:]))
             return (lab, fail), (nr, fail)
 
         (_lab_end, fail_end), (nr_s, fail_s) = lax.scan(
             body, (lab0, failed), (lu2, lv2, emask2)
         )
-        nr_end = nr_s[-1]
-        sid_r = jnp.where(tmask2, r, vcap2)
-        canon = canon.at[sid_r].set(nr_end, mode="drop")
-        tid_s = jnp.where(tmask2, tid2, vcap2)
-        canon = canon.at[tid_s].set(nr_end, mode="drop")
+        with jax.named_scope("forest.commit"):
+            canon = reroot(canon, nr_s[-1], r, tid2, tmask2, vcap2)
         return canon, fail_end, r, nr_s, fail_s
 
     fn = jax.jit(step)

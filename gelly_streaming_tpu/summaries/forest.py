@@ -36,6 +36,17 @@ emission holding it) and the step-2 scratch memset — two linear HBM
 passes per window instead of the dense path's ~10-20 full-table
 gather/scatter fixpoint passes.
 
+Tracing (``obs/trace.py``): the device phases are ``jax.named_scope``s
+inside the jitted step, shared by the per-window and the superbatch
+steps of both carries (CC and the signed cover) — ``forest.chase``
+(step 2's pointer chase), ``forest.group`` (its vcap-sized same-root
+scratch), ``forest.fixpoint`` (step 3), ``forest.commit`` (step 4) and,
+on the cover, ``forest.latch``. A device trace carries the scope in
+the ``tf_op`` stat of each ``XLA Ops`` event's metadata; the jitted
+programs keep the name ``jit_step``. On the host one window is the span ``forest.window`` with
+the children ``forest.prep`` (step 1 and the padding) and
+``forest.dispatch`` (uploads and the jit call: enqueue time).
+
 Reference parity: this is the ``UpdateCC``/``CombineCC`` pair of
 ``library/ConnectedComponents.java:83-126`` with the DisjointSet's
 pointer forest kept on device and its find-with-path-compression
@@ -52,6 +63,7 @@ import numpy as np
 from jax import lax
 
 from ..core.edgeblock import bucket_capacity
+from ..obs import trace as _trace
 from .labels import _propagate
 
 _I32_MAX = jnp.iinfo(jnp.int32).max
@@ -97,18 +109,20 @@ def chase_and_group(canon, tid, tmask, tcap: int, vcap: int):
     Returns ``(r, v2, key_, iota)``: current roots per lane, the group-
     edge targets, the root-value keys (+inf on pads), and the lane iota.
     """
-    r0 = jnp.where(tmask, canon[tid], 0)
-    r = lax.while_loop(
-        lambda r: jnp.any(canon[r] != r), lambda r: canon[r], r0
-    )
-    iota = jnp.arange(tcap, dtype=jnp.int32)
-    sid_r = jnp.where(tmask, r, vcap)
-    scratch = jnp.full(vcap, _I32_MAX, jnp.int32).at[sid_r].min(
-        jnp.where(tmask, iota, _I32_MAX), mode="drop"
-    )
-    rep = scratch[jnp.where(tmask, r, 0)]
-    v2 = jnp.where(tmask, rep, iota)
-    key_ = jnp.where(tmask, r, _I32_MAX)
+    with jax.named_scope("forest.chase"):
+        r0 = jnp.where(tmask, canon[tid], 0)
+        r = lax.while_loop(
+            lambda r: jnp.any(canon[r] != r), lambda r: canon[r], r0
+        )
+    with jax.named_scope("forest.group"):
+        iota = jnp.arange(tcap, dtype=jnp.int32)
+        sid_r = jnp.where(tmask, r, vcap)
+        scratch = jnp.full(vcap, _I32_MAX, jnp.int32).at[sid_r].min(
+            jnp.where(tmask, iota, _I32_MAX), mode="drop"
+        )
+        rep = scratch[jnp.where(tmask, r, 0)]
+        v2 = jnp.where(tmask, rep, iota)
+        key_ = jnp.where(tmask, r, _I32_MAX)
     return r, v2, key_, iota
 
 
@@ -120,13 +134,26 @@ def commit_roots(canon, local, key_, r, tid, tmask, tcap: int, vcap: int):
     touched lanes (pads dropped). Returns ``(canon, nr)`` — ``nr`` is
     each lane's final root value (the cover carry's conflict latch reads
     it)."""
+    with jax.named_scope("forest.commit"):
+        nr = new_roots(local, key_, tcap)
+        canon = reroot(canon, nr, r, tid, tmask, vcap)
+    return canon, nr
+
+
+def new_roots(local, key_, tcap: int):
+    """Each lane's merged component's min old root (callers name the
+    scope: ``forest.commit``)."""
     minr = jnp.full(tcap, _I32_MAX, jnp.int32).at[local].min(key_)
-    nr = minr[local]
+    return minr[local]
+
+
+def reroot(canon, nr, r, tid, tmask, vcap: int):
+    """The masked scatter pair of the commit: old roots, then the
+    touched lanes (path compression); pads drop at index ``vcap``."""
     sid_r = jnp.where(tmask, r, vcap)
     canon = canon.at[sid_r].set(nr, mode="drop")
     tid_s = jnp.where(tmask, tid, vcap)
-    canon = canon.at[tid_s].set(nr, mode="drop")
-    return canon, nr
+    return canon.at[tid_s].set(nr, mode="drop")
 
 
 def _make_local_fixpoint(tcap: int, mesh=None, tree: bool = False,
@@ -193,7 +220,8 @@ def _forest_step_fn(tcap: int, wcap: int, vcap: int, mesh=None,
 
     def step(canon, tid, tmask, lu, lv):
         r, v2, key_, iota = chase_and_group(canon, tid, tmask, tcap, vcap)
-        local = fixpoint(iota, lu, lv, v2)
+        with jax.named_scope("forest.fixpoint"):
+            local = fixpoint(iota, lu, lv, v2)
         canon, _nr = commit_roots(canon, local, key_, r, tid, tmask, tcap, vcap)
         return canon
 
@@ -255,16 +283,14 @@ def _forest_superbatch_fn(tcap: int, wcap: int, vcap: int, k: int,
 
         def body(lab, xs):
             lu_k, lv_k = xs
-            lab = fixpoint(lab, lu_k, lv_k, lab)
-            minr = jnp.full(tcap, _I32_MAX, jnp.int32).at[lab].min(key_)
-            return lab, minr[lab]
+            with jax.named_scope("forest.fixpoint"):
+                lab = fixpoint(lab, lu_k, lv_k, lab)
+            with jax.named_scope("forest.commit"):
+                return lab, new_roots(lab, key_, tcap)
 
         lab_end, nr_s = lax.scan(body, lab0, (lu, lv))
-        nr_end = nr_s[-1]
-        sid_r = jnp.where(tmask, r, vcap)
-        canon = canon.at[sid_r].set(nr_end, mode="drop")
-        tid_s = jnp.where(tmask, tid, vcap)
-        canon = canon.at[tid_s].set(nr_end, mode="drop")
+        with jax.named_scope("forest.commit"):
+            canon = reroot(canon, nr_s[-1], r, tid, tmask, vcap)
         return canon, r, nr_s
 
     fn = jax.jit(step)
@@ -335,19 +361,31 @@ def pad_window(prep, src_h, dst_h, vcap: int, wmin: int = 8):
     zero-padded (pad rows are (0,0) self-loops; carries whose space
     makes those meaningful — the cover — add their own edge mask)."""
     n = len(src_h)
-    tids, lu_r, lv_r = prep.prep(src_h, dst_h, vcap)
-    t = len(tids)
-    tcap = bucket_capacity(t, minimum=8)
-    wcap = bucket_capacity(n, minimum=wmin)
-    tid = np.zeros(tcap, np.int32)
-    tid[:t] = tids
-    tmask = np.zeros(tcap, bool)
-    tmask[:t] = True
-    lu = np.zeros(wcap, np.int32)
-    lv = np.zeros(wcap, np.int32)
-    lu[:n] = lu_r
-    lv[:n] = lv_r
+    with _trace.span("forest.prep"):
+        tids, lu_r, lv_r = prep.prep(src_h, dst_h, vcap)
+        t = len(tids)
+        tcap = bucket_capacity(t, minimum=8)
+        wcap = bucket_capacity(n, minimum=wmin)
+        tid = np.zeros(tcap, np.int32)
+        tid[:t] = tids
+        tmask = np.zeros(tcap, bool)
+        tmask[:t] = True
+        lu = np.zeros(wcap, np.int32)
+        lv = np.zeros(wcap, np.int32)
+        lu[:n] = lu_r
+        lv[:n] = lv_r
     return tids, tcap, wcap, tid, tmask, lu, lv
+
+
+def window_span(n: int):
+    """The ``forest.window`` span of one per-window fold (CC and cover
+    share the name, as they share :func:`pad_window`)."""
+    return _trace.span("forest.window", {"edges": n} if _trace.on() else None)
+
+
+def note_buckets(sp, tids, tcap: int, wcap: int) -> None:
+    if sp.recording:
+        sp.set(touched=len(tids), tcap=tcap, wcap=wcap)
 
 
 def forest_window(
@@ -391,17 +429,20 @@ def forest_window(
         # the bucket minimum keeps every bucket divisible for ANY axis
         # width (the edgeblock.py convention), not just powers of two
         wmin = max(wmin, mesh.shape[EDGE_AXIS])
-    tids, tcap, wcap, tid, tmask, lu, lv = pad_window(
-        prep, src_h, dst_h, vcap, wmin
-    )
-    step = _forest_step_fn(tcap, wcap, vcap, mesh, tree, degree)
-    canon = step(
-        canon,
-        jnp.asarray(tid),
-        jnp.asarray(tmask),
-        jnp.asarray(lu),
-        jnp.asarray(lv),
-    )
+    with window_span(n) as sp:
+        tids, tcap, wcap, tid, tmask, lu, lv = pad_window(
+            prep, src_h, dst_h, vcap, wmin
+        )
+        note_buckets(sp, tids, tcap, wcap)
+        with _trace.span("forest.dispatch"):
+            step = _forest_step_fn(tcap, wcap, vcap, mesh, tree, degree)
+            canon = step(
+                canon,
+                jnp.asarray(tid),
+                jnp.asarray(tmask),
+                jnp.asarray(lu),
+                jnp.asarray(lv),
+            )
     return canon, tids
 
 

@@ -1,3 +1,3 @@
 from .types import SampledEdge, SignedVertex, TriangleEstimate
-from .profiling import StreamProfiler, WindowStats, device_trace, profiled
+from .profiling import StreamProfiler, WindowStats, profiled
 from .config import EngineConfig

@@ -1,4 +1,4 @@
-"""Per-window step timing and device tracing (SURVEY.md §5).
+"""Per-window step timing and roofline accounting (SURVEY.md §5).
 
 The reference has no profiling beyond ``getNetRuntime()`` printed by one
 example (``CentralizedWeightedMatching.java:62-64``); its pom references
@@ -16,13 +16,12 @@ reference's design stance that metrics are ordinary output streams
   ``profiler.window_edges`` so the same numbers surface through the
   Prometheus/JSONL exporters; percentiles use the repo-wide
   :func:`~gelly_streaming_tpu.obs.registry.nearest_rank` rule.
-- :func:`device_trace` wraps ``jax.profiler.trace`` for TensorBoard-
-  readable TPU traces.
+
+Device traces are the tracing system's: ``obs.device_trace(log_dir)``.
 """
 
 from __future__ import annotations
 
-import contextlib
 import time
 from typing import Any, Iterator, List, NamedTuple, Optional, Tuple
 
@@ -119,15 +118,6 @@ def profiled(
         prof.record(stats)
         yield result, stats
         idx += 1
-
-
-@contextlib.contextmanager
-def device_trace(log_dir: str):
-    """TensorBoard-readable device trace around a block of stream steps."""
-    import jax
-
-    with jax.profiler.trace(log_dir):
-        yield
 
 
 # --------------------------------------------------------------------- #

@@ -1,0 +1,263 @@
+"""One traced run of a benchmark cell, read by named scope and by the
+spans inside the program.
+
+    chiprun -- python3 tools/trace_phases.py --workload <cell> --seed <n> \\
+        --seconds <s> [--dump chiprun_out/<dir>]
+
+Runs the cell exactly as ``benchmarks/run.py --trace 1`` does (the same
+``cellrun.run_cell``) and adds, to the cell's per-layer metrics, the
+ones ``PROPOSED`` lists: the forest step's phases and loop trips
+(``benchmarks/lib/scope_reduce.py``), the host fold's spans and the
+serving waits. They are not entries of ``BENCHMARK.json``: the harness
+requires every per-layer metric of a cell on the line of a traced run,
+and a program that lacks the span or scope (the parent of the PR that
+adds it) would then print no line at all. Until a ``benchmark`` PR
+takes them over (PERF.md, section 7) this is where they are read. The
+reader kinds are registered here, at run time; no file of the
+benchmark is edited.
+
+The last line of standard output is the result document, with
+``phases`` (the phases' sum against the whole step), ``events`` (span
+events per window and per sweep) and ``clock`` (how far a span's ``t0``,
+mapped through the traced slice's bracket, lies from the same span's
+annotation on the profiler's clock) beside the harness's keys.
+``--dump`` writes the first executions' stretch of the trace as plain
+lists, names uncut and scopes in them, for a by-hand look and for
+cutting a test recording.
+"""
+
+from __future__ import annotations
+
+import time
+
+_T_PROCESS = time.perf_counter()
+
+import argparse
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+SAT = ["cc-g500-s28.ingest-saturated", "bip-g500-s27.ingest-saturated-poll"]
+CC = ["cc-g500-s28.ingest-saturated", "cc-g500-s28.paced-query-heavy"]
+
+
+def _scope(kind: str, scope: str) -> dict:
+    return {"kind": kind, "program": "jit_step", "scope": scope}
+
+
+#: name -> (unit, layer, moves, cells, reader): what a ``benchmark`` PR
+#: would enter in BENCHMARK.json and benchmarks/layer_metrics/
+PROPOSED = {
+    **{f"forest_{p}_ms.sat": ("ms", "forest step", "edges_per_s", SAT,
+                              _scope("scope_mean_ms", f"forest.{p}"))
+       for p in ("chase", "group", "fixpoint", "commit")},
+    "forest_latch_ms.sat": ("ms", "forest step", "edges_per_s", SAT[1:],
+                            _scope("scope_mean_ms", "forest.latch")),
+    **{f"forest_{p}_rounds.sat": ("count", "forest step", "edges_per_s", SAT,
+                                  _scope("scope_rounds_mean", f"forest.{p}"))
+       for p in ("chase", "fixpoint")},
+    "fold_host_ms.sat": ("ms", "window host step", "edges_per_s", SAT,
+                         {"kind": "span_mean_ms", "span": "forest.window"}),
+    "fold_prep_ms.sat": ("ms", "window host step", "edges_per_s", SAT,
+                         {"kind": "span_mean_ms", "span": "forest.prep"}),
+    "fold_dispatch_ms.sat": ("ms", "window host step", "edges_per_s", SAT,
+                             {"kind": "span_mean_ms",
+                              "span": "forest.dispatch"}),
+    "queue_wait_ms": ("ms", "serving", "query_p95_ms", CC,
+                      {"kind": "span_mean_ms", "span": "serving.queue_wait"}),
+    "answer_wait_ms": ("ms", "serving", "query_p95_ms", CC,
+                       {"kind": "span_mean_ms",
+                        "span": "serving.device_wait"}),
+}
+
+
+def add_proposed(cell) -> list:
+    added = []
+    for name, (unit, layer, moves, cells, reader) in PROPOSED.items():
+        if cell.name in cells and name not in cell.per_layer:
+            cell.per_layer[name] = {"name": name, "unit": unit,
+                                    "layer": layer, "moves": moves}
+            cell.readers[name] = {"reader": reader}
+            added.append(name)
+    return added
+
+
+def _span_counts(ctx: dict) -> dict:
+    """Span events per window and per sweep inside the measured window."""
+    by_name: dict = {}
+    for e in ctx["spans"]:
+        by_name[e["name"]] = by_name.get(e["name"], 0) + 1
+    windows = by_name.get("forest.window", 0)
+    sweeps = by_name.get("serving.answer", 0)
+    ingest = sum(by_name.get(n, 0) for n in (
+        "ingest.wait_source", "window.pack", "forest.window",
+        "forest.prep", "forest.dispatch"))
+    serve = sum(by_name.get(n, 0) for n in (
+        "serving.queue_wait", "serving.answer", "serving.device_wait"))
+    return {"by_name": by_name,
+            "per_window": ingest / windows if windows else None,
+            "per_sweep": serve / sweeps if sweeps else None}
+
+
+def _clock_check(ctx: dict) -> dict:
+    """A span's ``t0`` placed on the profiler's clock through the traced
+    slice's bracket, against the same span's own annotation there."""
+    from benchmarks.lib import trace_reduce
+
+    a = ctx["traced"]["lo"]           # perf_counter just before the mark
+    mark = ctx["lo"]                  # the mark's start, profiler's clock
+    out = {}
+    for name in ("forest.window", "serving.answer"):
+        ann = sorted(
+            s for p in ctx["planes"]
+            if not trace_reduce.DEVICE_PLANE_RE.match(p["name"])
+            for ln in p["lines"] for n, s, _d in ln["events"] if n == name)
+        mapped = sorted(mark + (e["t0"] - a) * 1e9
+                        for e in ctx["run"]["spans"] if e["name"] == name)
+        mapped = [t for t in mapped if ctx["lo"] <= t <= ctx["hi"]]
+        ann = [t for t in ann if ctx["lo"] <= t <= ctx["hi"]]
+        # each mapped start against the nearest annotation start
+        diffs = [min(abs(t - s) for s in ann) / 1e3 for t in mapped if ann]
+        if diffs:
+            out[name] = {"n": len(diffs),
+                         "median_us": sorted(diffs)[len(diffs) // 2]}
+    return out
+
+
+def _dump(ctx: dict, out_dir: str, n_exec: int) -> None:
+    """The stretch of the trace that holds the first whole executions of
+    ``jit_step`` in the window, as plain lists: the device's modules and
+    ops (names uncut, scopes in them), and the host annotations of the
+    program's spans and the window's mark. What a test recording is cut
+    from, and what to look at by hand."""
+    from benchmarks.lib import scope_reduce, trace_reduce
+
+    os.makedirs(out_dir, exist_ok=True)
+    scoped = scope_reduce._scoped(ctx)
+    runs = scope_reduce.executions(scoped, "jit_step",
+                                   ctx["lo"], ctx["hi"])[:n_exec]
+    lo, hi = runs[0][0] - 2e5, runs[-1][1] + 2e5
+    span_names = {e["name"] for e in ctx["run"]["spans"]}
+    planes = []
+    for plane in scoped:
+        planes.append({"name": plane["name"], "lines": [
+            {"name": ln["name"],
+             "events": [e for e in ln["events"]
+                        if e[1] >= lo and e[1] + e[2] <= hi]}
+            for ln in plane["lines"]]})
+    for plane in ctx["planes"]:
+        if trace_reduce.DEVICE_PLANE_RE.match(plane["name"]):
+            continue
+        lines = []
+        for ln in plane["lines"]:
+            events = [e for e in ln["events"]
+                      if e[0] == trace_reduce.WINDOW_MARK
+                      or (e[0] in span_names and e[1] + e[2] >= lo
+                          and e[1] <= hi)]
+            if events:
+                lines.append({"name": ln["name"], "events": events})
+        if lines:
+            planes.append({"name": plane["name"], "lines": lines})
+    with open(os.path.join(out_dir, "scoped.json"), "w") as f:
+        json.dump({"lo": lo, "hi": hi, "window": [ctx["lo"], ctx["hi"]],
+                   "planes": planes}, f)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--dump", default=None,
+                    help="directory for the first executions' events")
+    ap.add_argument("--dump-executions", type=int, default=3)
+    args = ap.parse_args(argv)
+
+    result_out = os.fdopen(os.dup(1), "w")
+    os.dup2(2, 1)
+    sys.stdout = sys.stderr
+
+    from benchmarks.lib import cellrun, lastline, scope_reduce, spec
+    from benchmarks.lib.cellrun import log
+
+    cell = spec.load_cell(args.workload)
+    backend = cellrun.start_backend()
+    from gelly_streaming_tpu.utils.compile_cache import enable_compile_cache
+
+    log(f"compile cache: {enable_compile_cache()}")
+    import jax
+
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    added = add_proposed(cell)
+    extras: dict = {}
+
+    def tolerant(reader):
+        def read(spec, ctx):   # a scope that is not there costs the
+            try:               # metric, not the run's other numbers
+                return reader(spec, ctx)
+            except cellrun.trace_reduce.TraceError as e:
+                log(f"{spec}: {e}")
+                return None
+        return read
+
+    cellrun.READERS.update(
+        {k: tolerant(fn) for k, fn in scope_reduce.READERS.items()})
+
+    def read_extras(_spec: dict, ctx: dict):
+        """Rides the harness's own pass over the readers for its
+        context (the loaded trace, the spans); reports no metric."""
+        for key, fn in (("events", _span_counts), ("clock", _clock_check)):
+            try:
+                extras[key] = fn(ctx)
+            except Exception as e:   # the run's numbers matter more
+                log(f"{key}: {e!r}")
+        if args.dump:
+            try:
+                _dump(ctx, args.dump, args.dump_executions)
+            except Exception as e:
+                log(f"dump: {e!r}")
+        return None
+
+    cellrun.READERS["tool_extras"] = read_extras
+    cell.per_layer["_tool_extras"] = {"name": "_tool_extras", "unit": "-"}
+    cell.readers["_tool_extras"] = {"reader": {"kind": "tool_extras"}}
+    try:
+        doc = cellrun.run_cell(cell, args.seed, args.seconds, True,
+                               t_process=_T_PROCESS, backend=backend,
+                               work_root=ROOT)
+    except Exception:
+        import traceback
+
+        traceback.print_exc()
+        log("no result")
+        return 2
+    del cell.per_layer["_tool_extras"]
+    m = doc["metrics"]
+    step = next((m[k]["value"] for k in m if k.startswith("forest_step_ms")),
+                None)
+    parts = {k: m[k]["value"] for k in m
+             if k.startswith("forest_") and k.endswith("_ms.sat")
+             and k != "forest_step_ms.sat"}
+    if step and parts:
+        extras["phases"] = {"sum_ms": sum(parts.values()), "step_ms": step,
+                            "share": sum(parts.values()) / step}
+    doc.update(extras)
+    doc["proposed"] = added
+    line = json.dumps(doc)
+    problems = lastline.validate(
+        line, required=cell.units("per_layer"),
+        allowed={**cell.units("end_to_end"), **cell.units("per_layer")},
+        trace=True, chips=cell.chips)
+    for p in problems:
+        log(f"contract: {p}")
+    result_out.write(line + "\n")
+    result_out.flush()
+    return 3 if problems else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
