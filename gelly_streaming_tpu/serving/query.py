@@ -6,11 +6,11 @@ or 10k device dispatches. The :class:`QueryEngine` answers a whole batch
 per query class with ONE jitted lookup:
 
 - CC queries gather the batch's endpoints out of the published pointer
-  forest and chase ONLY those lanes to their roots (a batch-sized
-  ``lax.while_loop`` of gathers — the same kernel shape as
-  ``summaries/forest.py:chase_and_group``, sized by the batch, not the
-  vertex capacity). Flat labels are a valid (depth-1) forest, so the one
-  kernel serves every CC carry and restored checkpoints alike.
+  forest and chase ONLY those lanes to their roots
+  (``summaries/forest.py:chase_roots``, the fold's own loop of one
+  gather a round, sized by the batch, not the vertex capacity). Flat
+  labels are a valid (depth-1) forest, so the one kernel serves every
+  CC carry and restored checkpoints alike.
 - Degree / rank queries are one table gather.
 - Component-size queries canonicalize the forest once per snapshot
   version (cached) and bincount, then answer any number of batches from
@@ -54,11 +54,11 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from ..core.edgeblock import bucket_capacity
 from ..obs import trace as _trace
 from ..obs.registry import get_registry
+from ..summaries.forest import chase_roots
 from .snapshot_store import PublishedSnapshot
 
 
@@ -286,15 +286,12 @@ def decode_pull_doc(doc) -> dict:
 # --------------------------------------------------------------------- #
 @jax.jit
 def _batch_roots(canon: jax.Array, ids: jax.Array) -> jax.Array:
-    """Chase a BATCH of start ids to their forest roots. Read-only on
-    ``canon``; terminates by the min-root invariant (chains strictly
-    decrease). Padding lanes chase from 0, always self-rooted. Its ops
-    carry the scope ``query.chase`` in a device trace."""
+    """Chase a BATCH of start ids to their forest roots
+    (``forest.chase_roots``, the fold's own loop). Padding lanes chase
+    from 0, always self-rooted. Its ops carry the scope ``query.chase``
+    in a device trace."""
     with jax.named_scope("query.chase"):
-        r = canon[ids]
-        return lax.while_loop(
-            lambda r: jnp.any(canon[r] != r), lambda r: canon[r], r
-        )
+        return chase_roots(canon, canon[ids])
 
 
 @jax.jit

@@ -19,7 +19,9 @@ Per window, every kernel is sized by the window, not the vertex space:
    novelty-shadow pattern: zero device->host reads in the producer loop)
    and renumbers the window's edges into local indices ``[0, T)``.
 2. The DEVICE chases the touched vertices' pointers to their current
-   roots (``lax.while_loop`` of O(T) gathers; chains only pass through
+   roots (``chase_roots``: a ``lax.while_loop`` that carries each
+   lane's next pointer, so a round costs ONE O(T) gather out of the
+   table and the loop's condition none; chains only pass through
    former roots, and touched vertices are fully path-compressed every
    window).
 3. A min-label fixpoint over the **local** T-sized table joins the
@@ -91,13 +93,34 @@ def _table_combine(tcap: int):
     return combine
 
 
+def chase_roots(canon, r0):
+    """Follow every lane of ``r0`` along ``canon`` to its root: the one
+    pointer chase of the repo (the forest steps and the serving tier's
+    ``_batch_roots`` call it).
+
+    The loop carries ``(r, nxt)`` with ``nxt == canon[r]``, so its
+    condition is an elementwise compare and a reduce over the lanes and
+    its body holds the round's only gather out of the table: a chain of
+    depth ``d`` costs ``1 + d`` gathers where testing ``canon[r] != r``
+    and then stepping ``r = canon[r]`` cost ``1 + 2d`` (a gather costs
+    per lane on the chip, 17 ns, not per byte). Same roots after the
+    same number of trips. Read-only on ``canon``, so chains are static
+    during the chase; roots satisfy ``canon[r] == r`` and chains
+    strictly decrease (min-root invariant), so the loop terminates.
+    """
+    r, _nxt = lax.while_loop(
+        lambda c: jnp.any(c[1] != c[0]),
+        lambda c: (c[1], canon[c[1]]),
+        (r0, canon[r0]),
+    )
+    return r
+
+
 def chase_and_group(canon, tid, tmask, tcap: int, vcap: int):
     """Shared forest-step front half (CC + signed-cover carries).
 
-    1. Chase touched pointers to their current roots. Read-only on
-       canon, so chains are static during the chase; roots satisfy
-       canon[r] == r and chains strictly decrease (min-root invariant)
-       so the loop terminates. Padding lanes chase from 0, which is
+    1. Chase touched pointers to their current roots
+       (:func:`chase_roots`). Padding lanes chase from 0, which is
        always self-rooted (canon[0] <= 0).
     2. "Same current root" constraints WITHOUT a sort (argsort over the
        touched bucket measured 375 ms on the CPU backend): scatter each
@@ -110,10 +133,7 @@ def chase_and_group(canon, tid, tmask, tcap: int, vcap: int):
     edge targets, the root-value keys (+inf on pads), and the lane iota.
     """
     with jax.named_scope("forest.chase"):
-        r0 = jnp.where(tmask, canon[tid], 0)
-        r = lax.while_loop(
-            lambda r: jnp.any(canon[r] != r), lambda r: canon[r], r0
-        )
+        r = chase_roots(canon, jnp.where(tmask, canon[tid], 0))
     with jax.named_scope("forest.group"):
         iota = jnp.arange(tcap, dtype=jnp.int32)
         sid_r = jnp.where(tmask, r, vcap)

@@ -17,7 +17,8 @@ reader kinds are registered here, at run time; no file of the
 benchmark is edited.
 
 The last line of standard output is the result document, with
-``phases`` (the phases' sum against the whole step), ``events`` (span
+``phases`` (the phases' sum against the whole step, and the ms one
+round of the chase and of the fixpoint costs), ``events`` (span
 events per window and per sweep) and ``clock`` (how far a span's ``t0``,
 mapped through the traced slice's bracket, lies from the same span's
 annotation on the profiler's clock) beside the harness's keys.
@@ -84,6 +85,28 @@ def add_proposed(cell) -> list:
             cell.readers[name] = {"reader": reader}
             added.append(name)
     return added
+
+
+def phases_block(m: dict) -> dict:
+    """The result document's ``phases``: the scopes' sum against the
+    whole step, and what one trip of each loop costs (the scope's ms
+    over the scope's rounds; a scope holds a little beside its loop,
+    the chase its first two gathers)."""
+    step = next((m[k]["value"] for k in m if k.startswith("forest_step_ms")),
+                None)
+    parts = {k: m[k]["value"] for k in m
+             if k.startswith("forest_") and k.endswith("_ms.sat")
+             and k != "forest_step_ms.sat"}
+    if not (step and parts):
+        return {}
+    out = {"sum_ms": sum(parts.values()), "step_ms": step,
+           "share": sum(parts.values()) / step}
+    for p in ("chase", "fixpoint"):
+        ms = m.get(f"forest_{p}_ms.sat")
+        rounds = m.get(f"forest_{p}_rounds.sat")
+        if ms and rounds and rounds["value"]:
+            out[f"{p}_ms_per_round"] = ms["value"] / rounds["value"]
+    return out
 
 
 def _span_counts(ctx: dict) -> dict:
@@ -236,15 +259,9 @@ def main(argv=None) -> int:
         log("no result")
         return 2
     del cell.per_layer["_tool_extras"]
-    m = doc["metrics"]
-    step = next((m[k]["value"] for k in m if k.startswith("forest_step_ms")),
-                None)
-    parts = {k: m[k]["value"] for k in m
-             if k.startswith("forest_") and k.endswith("_ms.sat")
-             and k != "forest_step_ms.sat"}
-    if step and parts:
-        extras["phases"] = {"sum_ms": sum(parts.values()), "step_ms": step,
-                            "share": sum(parts.values()) / step}
+    phases = phases_block(doc["metrics"])
+    if phases:
+        extras["phases"] = phases
     doc.update(extras)
     doc["proposed"] = added
     line = json.dumps(doc)
