@@ -46,7 +46,7 @@ from ..core.edgeblock import EdgeBlock, StackedEdgeBlock
 from ..obs import trace as _trace
 from ..obs.registry import get_registry
 from ..parallel import comm
-from ..parallel.mesh import EDGE_AXIS
+from ..parallel.mesh import EDGE_AXIS, vertex_shards
 from ..summaries.groupfold import GroupFoldable, drive_group_folded
 from jax.sharding import PartitionSpec as P
 
@@ -194,9 +194,24 @@ class SummaryAggregation(GroupFoldable, abc.ABC):
         mesh = self.mesh if self.mesh is not None else stream.get_context().mesh
         if mesh is None:
             return None
+        if vertex_shards(mesh) > 1:
+            return self._vertex_sharded_mesh(mesh)
         if EDGE_AXIS not in mesh.shape or mesh.shape[EDGE_AXIS] == 1:
             return None
         return mesh
+
+    def _vertex_sharded_mesh(self, mesh):
+        """The mesh a run takes when its ``vertices`` axis is above 1.
+        Only an aggregation whose carry is laid out over that axis
+        overrides this (the CC forest); every other vertex table is
+        replicated, and running it on one chip of four in silence would
+        be the wrong answer to a mesh that asks for sharded state."""
+        raise NotImplementedError(
+            f"{type(self).__name__} has no vertex-sharded carry: the "
+            "`vertices` mesh axis shards the ConnectedComponents pointer "
+            "forest only (summaries/forest.py TableOps); this "
+            "aggregation's vertex table is replicated"
+        )
 
     def _make_partial_fn(self, vcap: int, mesh) -> Callable:
         """Build the traced one-window fold: per-shard ``update`` from

@@ -18,7 +18,13 @@ option, default ``"auto"``):
   Under a sharded mesh the T-sized local fixpoint runs as the engine's
   fold+combine shape — per-shard folds over the edge columns, label
   tables merged by the bulk stack or the degree-d butterfly — so the
-  vcap-sized carry never crosses the mesh.
+  vcap-sized carry never crosses the mesh. Under a ``vertices`` mesh
+  axis above 1 the forest itself is split by rows, one contiguous block
+  a chip (``summaries/forest.py`` ``TableOps``): the same step, the
+  published ``labels`` is the sharded array, and nothing gathers it.
+  That layout runs the forest carry only (``"auto"`` resolves to it)
+  and refuses the host and dense carries, superbatching and an
+  ``edges`` axis above 1.
 - **Host carry** (auto default on a CPU backend): the native incremental
   union-find (``native/ingest.cpp: cuf_*``) folds each window beside the
   parser and the device keeps a pointer-forest MIRROR updated by one
@@ -61,11 +67,13 @@ from __future__ import annotations
 
 from typing import Any, Iterator, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..aggregate.summary import SummaryBulkAggregation, SummaryTreeReduce
 from ..obs import trace as _trace
+from ..parallel.mesh import vertex_sharding, vertex_shards
 from ..summaries.forest import (
     MirrorReplay,
     TouchLog,
@@ -77,6 +85,7 @@ from ..summaries.forest import (
     mirror_update,
     resolve_flat,
     resolve_flat_host,
+    vertex_layout,
 )
 from ..summaries.labels import (
     Components,
@@ -116,8 +125,6 @@ def _auto_carry() -> str:
     accelerator is attached (its HBM absorbs the table passes, and host
     cycles belong to the parser).
     """
-    import jax
-
     if jax.default_backend() != "cpu":
         return "forest"
     try:
@@ -142,6 +149,7 @@ class _CCMixin:
         self._log = None      # host TouchLog
         self._uf = None       # native CompactUnionFind (host carry)
         self._prep = None     # WindowPrep scratch (forest carry)
+        self._vmesh = None    # the mesh, when its `vertices` axis shards the forest
         self._gf_degree = 2   # resolved tree degree for the group fold
 
     # ---- dense-engine hooks (mesh / device-transformed fallback) ---- #
@@ -161,10 +169,30 @@ class _CCMixin:
         return Components.from_labels(state, vdict)
 
     # ---- windowed-carry run loop ---- #
+    def _vertex_sharded_mesh(self, mesh):
+        """The forest carry is laid out over the ``vertices`` axis; what
+        that layout lacks is refused here, before the first window."""
+        k = int(getattr(self, "superbatch", 1) or 1)
+        vertex_layout(mesh, superbatch=k > 1 or self.superbatch_auto)
+        if self.carry not in ("auto", "forest"):
+            raise NotImplementedError(
+                f"carry={self.carry!r} keeps its table whole (the host "
+                "union-find's device mirror, the dense label table); under "
+                "a `vertices` mesh axis above 1 only the forest carry is "
+                "sharded"
+            )
+        return mesh
+
+    def _pick_carry(self) -> str:
+        if self.carry != "auto":
+            return self.carry
+        return "forest" if self._vmesh is not None else _auto_carry()
+
     def run(self, stream) -> Iterator[Components]:
         mesh = self._resolve_mesh(stream)
+        self._vmesh = mesh if vertex_shards(mesh) > 1 else None
         eff_degree = getattr(self, "degree", 2)
-        if mesh is not None and self._is_tree():
+        if mesh is not None and self._vmesh is None and self._is_tree():
             # resolve the tree degree against the mesh EAGERLY: the host
             # carry never runs the butterfly, so without this a
             # misconfigured degree would pass silently (or warn midway
@@ -220,9 +248,7 @@ class _CCMixin:
             and self._cc_mode != "dense"
         )
         if windowed and self._cc_mode is None:
-            self._cc_mode = (
-                self.carry if self.carry != "auto" else _auto_carry()
-            )
+            self._cc_mode = self._pick_carry()
         if windowed and self._cc_mode in ("forest", "host"):
             if self._cc_mode == "host":
                 yield from self._host_group(group, vdict)
@@ -243,6 +269,12 @@ class _CCMixin:
             or self.carry == "dense"
             or self._cc_mode == "dense"
         ):
+            if self._vmesh is not None:
+                raise NotImplementedError(
+                    "a window without host column views (a "
+                    "device-transformed stream) folds through the dense "
+                    "label table, which is not sharded over `vertices`"
+                )
             if self._cc_mode in ("forest", "host"):
                 self._to_dense()
             self._cc_mode = "dense"
@@ -251,9 +283,7 @@ class _CCMixin:
             yield self.transform(self._summary, vdict)
         else:
             if self._cc_mode is None:
-                self._cc_mode = (
-                    self.carry if self.carry != "auto" else _auto_carry()
-                )
+                self._cc_mode = self._pick_carry()
             self._ensure_windowed(block.n_vertices)
             src_h, dst_h = cache[0], cache[1]
             if self._cc_mode == "host":
@@ -380,13 +410,17 @@ class _CCMixin:
                 # valid forest; rebuild the host touched log from the mask
                 _validate_min_rooted(np.asarray(self._summary["labels"]))
                 self._canon = self._summary["labels"]
+                if self._vmesh is not None:
+                    self._canon = jax.device_put(
+                        self._canon, vertex_sharding(self._vmesh)
+                    )
                 self._log = TouchLog.from_touched_bool(
                     np.asarray(self._summary["touched"])
                 )
                 self._vcap = self._canon.shape[0]
             else:
                 self._vcap = vcap
-                self._canon = init_forest(vcap)
+                self._canon = init_forest(vcap, self._vmesh)
                 self._log = TouchLog(vcap)
             if self._cc_mode == "host":
                 from .. import native
@@ -396,7 +430,7 @@ class _CCMixin:
             else:
                 self._prep = WindowPrep()
         if vcap > self._vcap:
-            self._canon = grow_forest(self._canon, vcap)
+            self._canon = grow_forest(self._canon, vcap, self._vmesh)
             self._vcap = vcap
         self._log.grow(self._vcap)
 
@@ -417,7 +451,7 @@ class _CCMixin:
 
     def _reset_transient(self) -> None:
         if self._cc_mode in ("forest", "host"):
-            self._canon = init_forest(self._vcap)
+            self._canon = init_forest(self._vcap, self._vmesh)
             self._log = TouchLog(self._vcap)
             self._summary = {"labels": self._canon}
             if self._cc_mode == "host":
@@ -478,8 +512,10 @@ class CCServable:
     the CC aggregation. Every carry publishes one ``labels`` array per
     window — the live pointer forest for the forest/host carries (each
     window's functional update allocates a fresh buffer, so the
-    published one is immutable) or the dense flat table — plus the
-    stream's vertex dict for raw-id resolution.
+    published one is immutable; under a ``vertices`` mesh axis it is the
+    sharded array itself, a block of rows a chip, and is never gathered)
+    or the dense flat table — plus the stream's vertex dict for raw-id
+    resolution.
 
     SUPERBATCH GRANULARITY: with ``superbatch=K`` the aggregation
     yields a group's K emissions after its fused fold, so the live

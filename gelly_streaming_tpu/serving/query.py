@@ -45,6 +45,7 @@ from __future__ import annotations
 
 import base64
 import binascii
+import functools
 import pickle
 import threading
 from collections import deque
@@ -58,7 +59,8 @@ import numpy as np
 from ..core.edgeblock import bucket_capacity
 from ..obs import trace as _trace
 from ..obs.registry import get_registry
-from ..summaries.forest import chase_roots
+from ..parallel.mesh import replicated, table_vertex_shards, vertex_shards
+from ..summaries.forest import TableOps, chase_roots, sharded_table_fn
 from .snapshot_store import PublishedSnapshot
 
 
@@ -284,14 +286,30 @@ def decode_pull_doc(doc) -> dict:
 # --------------------------------------------------------------------- #
 # Vectorized kernels (batch-sized, payload-table-gathering)
 # --------------------------------------------------------------------- #
-@jax.jit
-def _batch_roots(canon: jax.Array, ids: jax.Array) -> jax.Array:
-    """Chase a BATCH of start ids to their forest roots
-    (``forest.chase_roots``, the fold's own loop). Padding lanes chase
-    from 0, always self-rooted. Its ops carry the scope ``query.chase``
-    in a device trace."""
-    with jax.named_scope("query.chase"):
-        return chase_roots(canon, canon[ids])
+@functools.lru_cache(maxsize=None)
+def _batch_roots_fn(mesh=None):
+    """The jitted batch chase (program ``jit__batch_roots``) over a
+    whole table (no mesh) or over a table split by rows over ``mesh``'s
+    ``vertices`` axis: ids replicated in, roots whole out, one
+    all-reduce a gather under the scope ``query.exchange``."""
+    shards = vertex_shards(mesh)
+
+    def _batch_roots(canon: jax.Array, ids: jax.Array) -> jax.Array:
+        """Chase a BATCH of start ids to their forest roots
+        (``forest.chase_roots``, the fold's own loop). Padding lanes
+        chase from 0, always self-rooted. Its ops carry the scope
+        ``query.chase`` in a device trace."""
+        # under shard_map ``canon`` is this chip's block of rows
+        tab = TableOps(canon.shape[0] * shards, shards, "query.exchange")
+        with jax.named_scope("query.chase"):
+            return chase_roots(canon, tab.gather(canon, ids), tab)
+
+    if shards > 1:
+        _batch_roots = sharded_table_fn(_batch_roots, mesh, 1, table_out=False)
+    return jax.jit(_batch_roots)
+
+
+_batch_roots = _batch_roots_fn()
 
 
 @jax.jit
@@ -428,11 +446,24 @@ class QueryEngine:
         self._chain_lock = threading.Lock()
 
     # -- table access (per-version host cache on the host path) -------- #
-    def _table(self, snap: PublishedSnapshot, key: str):
+    def _table(self, snap: PublishedSnapshot, key: str,
+               whole: Optional[str] = None):
         """The payload table, as a host array (host path, cached per
         snapshot (epoch, version)) or the device array as-is (device
-        path)."""
+        path). A vertex-sharded table always takes the device path and
+        is never copied to one device or to the host: ``whole`` names a
+        reader that needs it so, which is refused."""
         table = snap.payload[key]
+        if table_vertex_shards(table) > 1:
+            if whole:
+                raise NotImplementedError(
+                    f"{whole} canonicalizes the whole `{key}` table on "
+                    "one device or on the host; over a table sharded by "
+                    "`vertices` that needs a sharded resolve_flat (and a "
+                    "sharded size table), which is not built. "
+                    "ConnectedQuery is served"
+                )
+            return table
         if not self.prefer_host:
             return table
         ck = (snap.epoch, snap.version, key)
@@ -446,6 +477,12 @@ class QueryEngine:
         return cached
 
     def _roots(self, table, ids: np.ndarray) -> np.ndarray:
+        if table_vertex_shards(table) > 1:
+            # the ids go to every chip; the roots come back whole
+            mesh = table.sharding.mesh
+            out = _batch_roots_fn(mesh)(
+                table, jax.device_put(_pad_ids(ids), replicated(mesh)))
+            return _fetch(out, len(ids))
         if self.prefer_host:
             return _host_batch_roots(table, ids)
         return _fetch(
@@ -483,7 +520,7 @@ class QueryEngine:
         snapshot version. Sizes count COMPACT ids sharing the root —
         vertices the stream has actually seen (plus the queried vertex's
         own singleton when it is seen but never merged)."""
-        canon = self._table(snap, "labels")
+        canon = self._table(snap, "labels", whole="ComponentSizeQuery")
         vdict = snap.payload["vdict"]
         cv = _lookup_batch(vdict, vs)
         key = (snap.epoch, snap.version, id(snap.payload["labels"]))
@@ -594,7 +631,8 @@ class QueryEngine:
         a version that went BACKWARD means the diff base is gone."""
         from ..summaries.forest import resolve_flat_host
 
-        canon = np.asarray(self._table(snap, "labels"))
+        canon = np.asarray(
+            self._table(snap, "labels", whole="SummaryPullQuery"))
         vdict = snap.payload["vdict"]
         lab = resolve_flat_host(canon)
         n = min(int(lab.shape[0]), len(vdict))

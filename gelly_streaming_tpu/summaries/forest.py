@@ -49,6 +49,19 @@ programs keep the name ``jit_step``. On the host one window is the span ``forest
 the children ``forest.prep`` (step 1 and the padding) and
 ``forest.dispatch`` (uploads and the jit call: enqueue time).
 
+Vertex-sharded layout (``parallel/mesh.py``'s ``vertices`` axis above
+1): the SAME step over a table of which every chip holds one
+contiguous block of rows. Every access to the ``vcap``-sized table goes
+through one pair of primitives, :class:`TableOps` ``gather`` and
+``scatter``; with no axis they are ``table[idx]`` and
+``table.at[idx].set/min(mode="drop")``, with the axis the whole step
+runs under ``shard_map``, the lanes are replicated, the owner of a row
+answers a gather and one all-reduce (scope ``forest.exchange``) makes
+the lanes whole, and a scatter applies the lanes this chip owns with no
+exchange. The host then also emits ``forest.place`` (child of
+``forest.window``: the window's columns placed on every chip) and the
+attributes ``shards`` and ``owner_max_share`` on ``forest.window``.
+
 Reference parity: this is the ``UpdateCC``/``CombineCC`` pair of
 ``library/ConnectedComponents.java:83-126`` with the DisjointSet's
 pointer forest kept on device and its find-with-path-compression
@@ -63,9 +76,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 from ..core.edgeblock import bucket_capacity
 from ..obs import trace as _trace
+from ..parallel import comm
+from ..parallel.mesh import (
+    EDGE_AXIS,
+    VERTEX_AXIS,
+    replicated,
+    vertex_sharding,
+    vertex_shards,
+)
 from .labels import _propagate
 
 _I32_MAX = jnp.iinfo(jnp.int32).max
@@ -93,10 +115,104 @@ def _table_combine(tcap: int):
     return combine
 
 
-def chase_roots(canon, r0):
+class TableOps:
+    """The one pair of primitives through which a step reads and writes
+    a ``vcap``-sized table: a gather of lanes and a masked scatter of
+    lanes (``set`` or ``min``).
+
+    ``TableOps(vcap)`` is the whole table on one chip: ``table[idx]``
+    and ``table.at[idx].set/min(val, mode="drop")``, the ops the
+    mesh-less step has always compiled to.
+
+    ``TableOps(vcap, shards, exchange)`` is one chip's block of a table
+    split over the ``vertices`` axis, and is used INSIDE ``shard_map``
+    (:func:`sharded_table_fn`): ``table`` is the local block of
+    ``rows = vcap / shards`` rows starting at ``axis_index * rows``,
+    lanes (``idx``, ``val``) are replicated. A gather reads the lanes
+    this chip owns and 0 elsewhere, and ONE all-reduce (a sum: every
+    row has exactly one owner) under the named scope ``exchange`` makes
+    the lanes whole on every chip. A scatter applies the lanes this
+    chip owns and drops the rest at the local sentinel ``rows``; the
+    callers' whole-table sentinel ``vcap`` is nobody's row, so it drops
+    everywhere. No exchange: every chip sees every lane."""
+
+    __slots__ = ("rows", "shards", "exchange")
+
+    def __init__(self, vcap: int, shards: int = 1,
+                 exchange: str = "forest.exchange"):
+        if vcap % shards:
+            raise ValueError(
+                f"a table of {vcap} rows does not split in {shards} "
+                "equal blocks"
+            )
+        self.rows = vcap // shards
+        self.shards = shards
+        self.exchange = exchange
+
+    def _local(self, idx):
+        """``(mine, off)``: which lanes this chip owns and their row in
+        its block."""
+        off = idx - lax.axis_index(VERTEX_AXIS).astype(idx.dtype) * self.rows
+        return (off >= 0) & (off < self.rows), off
+
+    def full(self, fill):
+        """This chip's block of a fresh int32 table."""
+        return jnp.full(self.rows, fill, jnp.int32)
+
+    def gather(self, table, idx):
+        if self.shards == 1:
+            return table[idx]
+        mine, off = self._local(idx)
+        got = jnp.where(mine, table[jnp.where(mine, off, 0)], 0)
+        with jax.named_scope(self.exchange):
+            return lax.psum(got, VERTEX_AXIS)
+
+    def scatter(self, table, idx, val, op: str = "set"):
+        if self.shards > 1:
+            mine, off = self._local(idx)
+            idx = jnp.where(mine, off, self.rows)
+        at = table.at[idx]
+        return (at.min if op == "min" else at.set)(val, mode="drop")
+
+
+def sharded_table_fn(fn, mesh, n_lanes: int, table_out: bool):
+    """``fn(table, *lanes)`` under ``shard_map`` over the ``vertices``
+    axis: the table argument (and the result, if ``table_out``) is split
+    by rows, the ``n_lanes`` lane arguments (and a lane result) are
+    replicated."""
+    return comm.shard_map(
+        fn, mesh, (P(VERTEX_AXIS),) + (P(),) * n_lanes,
+        P(VERTEX_AXIS) if table_out else P(),
+    )
+
+
+def vertex_layout(mesh, superbatch: bool = False) -> int:
+    """Shards of the ``vertices`` axis a forest step is laid out in (1:
+    the whole table on every chip of ``mesh``). Refuses, in one place,
+    what the sharded layout cannot do yet."""
+    shards = vertex_shards(mesh)
+    if shards == 1:
+        return 1
+    if mesh.shape.get(EDGE_AXIS, 1) > 1:
+        raise NotImplementedError(
+            f"mesh {dict(mesh.shape)}: the vertex-sharded forest step runs "
+            "its window-sized fixpoint whole on every chip; splitting the "
+            "edge columns over an `edges` axis above 1 at the same time "
+            "is not built (make_mesh(n_edge_shards=1, n_vertex_shards=...))"
+        )
+    if superbatch:
+        raise NotImplementedError(
+            "the superbatch forest step (_forest_superbatch_fn) and its "
+            "ForestReplay read the whole table on one chip; under a "
+            "`vertices` axis above 1 run superbatch=1"
+        )
+    return shards
+
+
+def chase_roots(canon, r0, tab: TableOps = None):
     """Follow every lane of ``r0`` along ``canon`` to its root: the one
     pointer chase of the repo (the forest steps and the serving tier's
-    ``_batch_roots`` call it).
+    ``_batch_roots`` call it), over ``tab``'s layout of the table.
 
     The loop carries ``(r, nxt)`` with ``nxt == canon[r]``, so its
     condition is an elementwise compare and a reduce over the lanes and
@@ -108,15 +224,17 @@ def chase_roots(canon, r0):
     during the chase; roots satisfy ``canon[r] == r`` and chains
     strictly decrease (min-root invariant), so the loop terminates.
     """
+    tab = tab or TableOps(canon.shape[0])
     r, _nxt = lax.while_loop(
         lambda c: jnp.any(c[1] != c[0]),
-        lambda c: (c[1], canon[c[1]]),
-        (r0, canon[r0]),
+        lambda c: (c[1], tab.gather(canon, c[1])),
+        (r0, tab.gather(canon, r0)),
     )
     return r
 
 
-def chase_and_group(canon, tid, tmask, tcap: int, vcap: int):
+def chase_and_group(canon, tid, tmask, tcap: int, vcap: int,
+                    tab: TableOps = None):
     """Shared forest-step front half (CC + signed-cover carries).
 
     1. Chase touched pointers to their current roots
@@ -131,22 +249,29 @@ def chase_and_group(canon, tid, tmask, tcap: int, vcap: int):
 
     Returns ``(r, v2, key_, iota)``: current roots per lane, the group-
     edge targets, the root-value keys (+inf on pads), and the lane iota.
+    ``tab`` is the table's layout (default: whole, on one chip); the
+    scratch is laid out like the table.
     """
+    tab = tab or TableOps(vcap)
     with jax.named_scope("forest.chase"):
-        r = chase_roots(canon, jnp.where(tmask, canon[tid], 0))
+        r = chase_roots(
+            canon, jnp.where(tmask, tab.gather(canon, tid), 0), tab
+        )
     with jax.named_scope("forest.group"):
         iota = jnp.arange(tcap, dtype=jnp.int32)
         sid_r = jnp.where(tmask, r, vcap)
-        scratch = jnp.full(vcap, _I32_MAX, jnp.int32).at[sid_r].min(
-            jnp.where(tmask, iota, _I32_MAX), mode="drop"
+        scratch = tab.scatter(
+            tab.full(_I32_MAX), sid_r,
+            jnp.where(tmask, iota, _I32_MAX), "min",
         )
-        rep = scratch[jnp.where(tmask, r, 0)]
+        rep = tab.gather(scratch, jnp.where(tmask, r, 0))
         v2 = jnp.where(tmask, rep, iota)
         key_ = jnp.where(tmask, r, _I32_MAX)
     return r, v2, key_, iota
 
 
-def commit_roots(canon, local, key_, r, tid, tmask, tcap: int, vcap: int):
+def commit_roots(canon, local, key_, r, tid, tmask, tcap: int, vcap: int,
+                 tab: TableOps = None):
     """Shared forest-step back half: the merged component's new root is
     the min of its members' old roots (each old root is the min id of
     its old component, so the min over merged roots is the min id of the
@@ -156,7 +281,7 @@ def commit_roots(canon, local, key_, r, tid, tmask, tcap: int, vcap: int):
     it)."""
     with jax.named_scope("forest.commit"):
         nr = new_roots(local, key_, tcap)
-        canon = reroot(canon, nr, r, tid, tmask, vcap)
+        canon = reroot(canon, nr, r, tid, tmask, vcap, tab)
     return canon, nr
 
 
@@ -167,13 +292,14 @@ def new_roots(local, key_, tcap: int):
     return minr[local]
 
 
-def reroot(canon, nr, r, tid, tmask, vcap: int):
+def reroot(canon, nr, r, tid, tmask, vcap: int, tab: TableOps = None):
     """The masked scatter pair of the commit: old roots, then the
     touched lanes (path compression); pads drop at index ``vcap``."""
+    tab = tab or TableOps(vcap)
     sid_r = jnp.where(tmask, r, vcap)
-    canon = canon.at[sid_r].set(nr, mode="drop")
+    canon = tab.scatter(canon, sid_r, nr)
     tid_s = jnp.where(tmask, tid, vcap)
-    return canon.at[tid_s].set(nr, mode="drop")
+    return tab.scatter(canon, tid_s, nr)
 
 
 def _make_local_fixpoint(tcap: int, mesh=None, tree: bool = False,
@@ -194,11 +320,6 @@ def _make_local_fixpoint(tcap: int, mesh=None, tree: bool = False,
     bulk stack or the ppermute butterfly. The vcap-sized carry never
     crosses the mesh."""
     if mesh is not None:
-        from jax.sharding import PartitionSpec as P
-
-        from ..parallel import comm
-        from ..parallel.mesh import EDGE_AXIS
-
         p = mesh.shape[EDGE_AXIS]
         combine = _table_combine(tcap)
 
@@ -236,15 +357,27 @@ def _forest_step_fn(tcap: int, wcap: int, vcap: int, mesh=None,
     if fn is not None:
         return fn
 
-    fixpoint = _make_local_fixpoint(tcap, mesh, tree, degree)
+    shards = vertex_layout(mesh)
+    tab = TableOps(vcap, shards)
+    # under the vertices layout the edges axis is 1 and every chip runs
+    # the window-sized fixpoint whole, as one chip does
+    fixpoint = _make_local_fixpoint(
+        tcap, mesh if shards == 1 else None, tree, degree
+    )
 
     def step(canon, tid, tmask, lu, lv):
-        r, v2, key_, iota = chase_and_group(canon, tid, tmask, tcap, vcap)
+        r, v2, key_, iota = chase_and_group(
+            canon, tid, tmask, tcap, vcap, tab
+        )
         with jax.named_scope("forest.fixpoint"):
             local = fixpoint(iota, lu, lv, v2)
-        canon, _nr = commit_roots(canon, local, key_, r, tid, tmask, tcap, vcap)
+        canon, _nr = commit_roots(
+            canon, local, key_, r, tid, tmask, tcap, vcap, tab
+        )
         return canon
 
+    if shards > 1:
+        step = sharded_table_fn(step, mesh, 4, table_out=True)
     fn = jax.jit(step)
     if len(_FOREST_STEP_CACHE) >= _FOREST_STEP_CACHE_MAX:
         _FOREST_STEP_CACHE.pop(next(iter(_FOREST_STEP_CACHE)))
@@ -292,6 +425,7 @@ def _forest_superbatch_fn(tcap: int, wcap: int, vcap: int, k: int,
     if fn is not None:
         return fn
 
+    vertex_layout(mesh, superbatch=True)
     fixpoint = _make_local_fixpoint(tcap, mesh, tree, degree)
 
     def step(canon, tid, tmask, lu, lv):
@@ -320,18 +454,54 @@ def _forest_superbatch_fn(tcap: int, wcap: int, vcap: int, k: int,
     return fn
 
 
-def init_forest(vcap: int) -> jax.Array:
-    """Fresh forest: every vertex self-rooted."""
-    return jnp.arange(vcap, dtype=jnp.int32)
+def _own_rows(rows: int):
+    """Global ids of this chip's block (inside ``shard_map``)."""
+    return lax.axis_index(VERTEX_AXIS).astype(jnp.int32) * rows + jnp.arange(
+        rows, dtype=jnp.int32
+    )
 
 
-def grow_forest(canon: jax.Array, new_vcap: int) -> jax.Array:
+def init_forest(vcap: int, mesh=None) -> jax.Array:
+    """Fresh forest: every vertex self-rooted. Under a ``vertices`` axis
+    every chip builds its own block in place."""
+    shards = vertex_shards(mesh)
+    if shards == 1:
+        return jnp.arange(vcap, dtype=jnp.int32)
+    return jax.jit(comm.shard_map(
+        lambda: _own_rows(vcap // shards), mesh, (), P(VERTEX_AXIS)
+    ))()
+
+
+def grow_forest(canon: jax.Array, new_vcap: int, mesh=None) -> jax.Array:
+    """The forest at ``new_vcap`` rows, the new ones self-rooted. Under
+    a ``vertices`` axis the blocks grow by ``g = new / old``, so the new
+    block ``k`` holds the old blocks ``k*g .. k*g + g - 1``: each old
+    block travels once, chip to chip, and no chip ever holds more than
+    its new block and its old one."""
     old = canon.shape[0]
     if new_vcap <= old:
         return canon
-    return jnp.concatenate(
-        [canon, jnp.arange(old, new_vcap, dtype=jnp.int32)]
-    )
+    shards = vertex_shards(mesh)
+    if shards == 1:
+        return jnp.concatenate(
+            [canon, jnp.arange(old, new_vcap, dtype=jnp.int32)]
+        )
+    g, rows = new_vcap // old, old // shards
+    slots = min(g, shards)
+
+    def grow(local):
+        parts = [
+            lax.ppermute(
+                local, VERTEX_AXIS,
+                [(j, j // g) for j in range(shards) if j % g == t],
+            )
+            for t in range(slots)
+        ]
+        parts.append(jnp.zeros(g * rows - slots * rows, jnp.int32))
+        own = _own_rows(g * rows)
+        return jnp.where(own < old, jnp.concatenate(parts), own)
+
+    return jax.jit(sharded_table_fn(grow, mesh, 0, table_out=True))(canon)
 
 
 class WindowPrep:
@@ -408,6 +578,17 @@ def note_buckets(sp, tids, tcap: int, wcap: int) -> None:
         sp.set(touched=len(tids), tcap=tcap, wcap=wcap)
 
 
+def note_owners(sp, tids, vcap: int, shards: int) -> None:
+    """On ``forest.window`` under a ``vertices`` axis: ``shards`` and
+    ``owner_max_share``, the largest owner's share of the window's
+    touched ids (what an exchange that routes each lane to its owner
+    alone would have to live with; 1/shards when balanced)."""
+    if sp.recording and len(tids):
+        owners = np.bincount(tids // (vcap // shards), minlength=shards)
+        sp.set(shards=shards,
+               owner_max_share=float(owners.max()) / len(tids))
+
+
 def forest_window(
     canon: jax.Array,
     src_h: np.ndarray,
@@ -443,26 +624,26 @@ def forest_window(
         return canon, np.zeros(0, np.int32)
     wmin = 8
     if mesh is not None:
-        from ..parallel.mesh import EDGE_AXIS
-
         # the sharded columns must divide by the axis size; passing it as
         # the bucket minimum keeps every bucket divisible for ANY axis
         # width (the edgeblock.py convention), not just powers of two
         wmin = max(wmin, mesh.shape[EDGE_AXIS])
+    shards = vertex_shards(mesh)
     with window_span(n) as sp:
         tids, tcap, wcap, tid, tmask, lu, lv = pad_window(
             prep, src_h, dst_h, vcap, wmin
         )
         note_buckets(sp, tids, tcap, wcap)
+        cols = (tid, tmask, lu, lv)
+        if shards > 1:
+            note_owners(sp, tids, vcap, shards)
+            with _trace.span("forest.place"):
+                # every chip sees every lane: one copy of the window's
+                # columns a chip
+                cols = jax.device_put(cols, replicated(mesh))
         with _trace.span("forest.dispatch"):
             step = _forest_step_fn(tcap, wcap, vcap, mesh, tree, degree)
-            canon = step(
-                canon,
-                jnp.asarray(tid),
-                jnp.asarray(tmask),
-                jnp.asarray(lu),
-                jnp.asarray(lv),
-            )
+            canon = step(canon, *(jnp.asarray(c) for c in cols))
     return canon, tids
 
 

@@ -157,9 +157,9 @@ def test_the_chase_gathers_once_a_round_and_never_in_its_condition(caller):
 # --------------------------------------------------------------------- #
 # (c) the steps against the former loop: the same table, bit for bit
 # --------------------------------------------------------------------- #
-def _former_chase(canon, r0):
+def _former_chase(canon, r0, tab=None):
     """The loop as it was: the condition gathers ``canon[r]``, the body
-    gathers it again."""
+    gathers it again (whole tables only: ``tab`` is not looked at)."""
     return lax.while_loop(
         lambda r: jnp.any(canon[r] != r), lambda r: canon[r], r0)
 

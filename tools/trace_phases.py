@@ -17,8 +17,11 @@ reader kinds are registered here, at run time; no file of the
 benchmark is edited.
 
 The last line of standard output is the result document, with
-``phases`` (the phases' sum against the whole step, and the ms one
-round of the chase and of the fixpoint costs), ``events`` (span
+``phases`` (the phases' sum against the whole step, the ms one
+round of the chase and of the fixpoint costs and, where the table is
+sharded over chips, the collectives of a step under ``forest.exchange``:
+their ms, their count and the span attributes ``shards`` and
+``owner_max_share``), ``events`` (span
 events per window and per sweep) and ``clock`` (how far a span's ``t0``,
 mapped through the traced slice's bracket, lies from the same span's
 annotation on the profiler's clock) beside the harness's keys.
@@ -44,6 +47,7 @@ if ROOT not in sys.path:
 
 SAT = ["cc-g500-s28.ingest-saturated", "bip-g500-s27.ingest-saturated-poll"]
 CC = ["cc-g500-s28.ingest-saturated", "cc-g500-s28.paced-query-heavy"]
+V4 = ["cc-g500-s30-v4.ingest-saturated"]
 
 
 def _scope(kind: str, scope: str) -> dict:
@@ -73,6 +77,23 @@ PROPOSED = {
     "answer_wait_ms": ("ms", "serving", "query_p95_ms", CC,
                        {"kind": "span_mean_ms",
                         "span": "serving.device_wait"}),
+    # the vertex-sharded cell: the same phases on chip 0's line, and the
+    # collectives that make the lanes whole (inside chase and group, so
+    # NOT a phase to add to their sum)
+    **{f"forest_{p}_ms.v4": ("ms", "forest step", "edges_per_s", V4,
+                             _scope("scope_mean_ms", f"forest.{p}"))
+       for p in ("chase", "group", "fixpoint", "commit", "exchange")},
+    **{f"forest_{p}_rounds.v4": ("count", "forest step", "edges_per_s", V4,
+                                 _scope("scope_rounds_mean", f"forest.{p}"))
+       for p in ("chase", "fixpoint")},
+    "fold_host_ms.v4": ("ms", "window host step", "edges_per_s", V4,
+                        {"kind": "span_mean_ms", "span": "forest.window"}),
+    "fold_dispatch_ms.v4": ("ms", "window host step", "edges_per_s", V4,
+                            {"kind": "span_mean_ms",
+                             "span": "forest.dispatch"}),
+    "answer_wait_ms.v4": ("ms", "serving", "query_p95_ms", V4,
+                          {"kind": "span_mean_ms",
+                           "span": "serving.device_wait"}),
 }
 
 
@@ -94,18 +115,54 @@ def phases_block(m: dict) -> dict:
     the chase its first two gathers)."""
     step = next((m[k]["value"] for k in m if k.startswith("forest_step_ms")),
                 None)
+    tag = next((t for t in (".sat", ".v4") if f"forest_chase_ms{t}" in m),
+               ".sat")
+    # the exchanges run inside chase and group: beside the sum, not in it
     parts = {k: m[k]["value"] for k in m
-             if k.startswith("forest_") and k.endswith("_ms.sat")
-             and k != "forest_step_ms.sat"}
+             if k.startswith("forest_") and k.endswith("_ms" + tag)
+             and k not in ("forest_step_ms" + tag,
+                           "forest_exchange_ms" + tag)}
     if not (step and parts):
         return {}
     out = {"sum_ms": sum(parts.values()), "step_ms": step,
            "share": sum(parts.values()) / step}
     for p in ("chase", "fixpoint"):
-        ms = m.get(f"forest_{p}_ms.sat")
-        rounds = m.get(f"forest_{p}_rounds.sat")
+        ms = m.get(f"forest_{p}_ms{tag}")
+        rounds = m.get(f"forest_{p}_rounds{tag}")
         if ms and rounds and rounds["value"]:
             out[f"{p}_ms_per_round"] = ms["value"] / rounds["value"]
+    exchange = m.get("forest_exchange_ms" + tag)
+    if exchange:
+        out["exchange_ms"] = exchange["value"]
+        out["exchange_share"] = exchange["value"] / step
+    return out
+
+
+def _exchanges(ctx: dict) -> dict:
+    """The collectives of a step, counted: per execution of ``jit_step``
+    the ops events under ``forest.exchange`` on chip 0's line (one
+    ``%psum`` event an all-reduce on a v5e), and from the
+    ``forest.window`` spans the shard count and the largest owner's
+    share of a window's touched ids."""
+    from statistics import fmean
+
+    from benchmarks.lib import scope_reduce
+
+    runs = scope_reduce.scope_events(
+        scope_reduce._scoped(ctx), "jit_step", "forest.exchange",
+        ctx["lo"], ctx["hi"])
+    ids = [[scope_reduce.op_id(e[0]) for e in events] for _r, events in runs]
+    out = {"exchanges_per_step": fmean(len(x) for x in ids),
+           "exchange_instructions": sorted({i for x in ids for i in x})[:12]}
+    shares = [e["attrs"]["owner_max_share"] for e in ctx["spans"]
+              if e["name"] == "forest.window"
+              and "owner_max_share" in e.get("attrs", {})]
+    if shares:
+        out["owner_max_share_mean"] = fmean(shares)
+        out["owner_max_share_max"] = max(shares)
+        out["shards"] = next(
+            e["attrs"]["shards"] for e in ctx["spans"]
+            if e["name"] == "forest.window" and "shards" in e.get("attrs", {}))
     return out
 
 
@@ -118,7 +175,7 @@ def _span_counts(ctx: dict) -> dict:
     sweeps = by_name.get("serving.answer", 0)
     ingest = sum(by_name.get(n, 0) for n in (
         "ingest.wait_source", "window.pack", "forest.window",
-        "forest.prep", "forest.dispatch"))
+        "forest.prep", "forest.place", "forest.dispatch"))
     serve = sum(by_name.get(n, 0) for n in (
         "serving.queue_wait", "serving.answer", "serving.device_wait"))
     return {"by_name": by_name,
@@ -233,7 +290,10 @@ def main(argv=None) -> int:
     def read_extras(_spec: dict, ctx: dict):
         """Rides the harness's own pass over the readers for its
         context (the loaded trace, the spans); reports no metric."""
-        for key, fn in (("events", _span_counts), ("clock", _clock_check)):
+        readers = [("events", _span_counts), ("clock", _clock_check)]
+        if cell.name in V4:
+            readers.append(("exchanges", _exchanges))
+        for key, fn in readers:
             try:
                 extras[key] = fn(ctx)
             except Exception as e:   # the run's numbers matter more
@@ -261,6 +321,7 @@ def main(argv=None) -> int:
     del cell.per_layer["_tool_extras"]
     phases = phases_block(doc["metrics"])
     if phases:
+        phases.update(extras.pop("exchanges", {}))
         extras["phases"] = phases
     doc.update(extras)
     doc["proposed"] = added
