@@ -34,6 +34,7 @@ _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 TRACE_DELAY_S = 1.0      # the traced slice starts this long after t0
 TRACE_SLICE_S = 6.0      # and lasts this long (or to a second before the end)
 ANSWER_WAIT_S = 60.0     # how long a late answer is waited for
+STREAM_WARN_SHARE = 0.6  # of the stream handed out: time to lengthen it
 
 
 class RunError(RuntimeError):
@@ -235,11 +236,29 @@ def memory_peak_bytes() -> int:
 
 
 def stream_length(cell, seconds: float) -> int:
+    """Edges of the pre-generated stream: what ``stream_edges_per_s``
+    of the traffic file would fold in the window, the warm-up's windows
+    and four to spare. No run reads a rate above it (traffic.py)."""
     t = cell.traffic
     w = int(cell.config["window_edges"])
     warm = int(t.get("warm_windows", 8))
     rate = float(t["stream_edges_per_s"])
     return w * (warm + 4 + math.ceil(rate * seconds / w))
+
+
+def stream_headroom_warning(handed: int, stream: int, traffic_name: str):
+    """The line to log when a run has handed out more than
+    ``STREAM_WARN_SHARE`` of its stream's windows (None otherwise): the
+    program is within reach of the end of the stream, past which a run
+    gives no result, and a ``benchmark`` PR has to raise
+    ``stream_edges_per_s`` before a faster program gets there."""
+    if handed <= STREAM_WARN_SHARE * stream:
+        return None
+    return (f"WARNING: {handed} of the stream's {stream} windows were handed "
+            f"out ({100.0 * handed / stream:.0f}%, over "
+            f"{100.0 * STREAM_WARN_SHARE:.0f}%): raise stream_edges_per_s in "
+            f"benchmarks/traffic/{traffic_name}.json before a faster program "
+            "reaches the end of the stream")
 
 
 def default_stream(config: dict, source: WindowSource, context=None):
@@ -286,14 +305,17 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
     warm_windows = int(traffic.get("warm_windows", 8))
     lookback = int(cfg["guarantees"]["max_staleness_windows"])
 
-    # ---- set-up: the stream, from the seed -------------------------- #
+    # ---- the stream, from the seed: the benchmark's own work, as the
+    # runtime's start-up is, so its seconds are taken apart (stream_s)
+    # and are no part of setup_s, however long the stream is
     n_edges = stream_length(cell, seconds)
     t = time.perf_counter()
     gen = cell.generator()
     src, dst = gen.edges(cfg, n_edges, seed, warm_windows * w_edges)
     closing = (gen.closing_edges(cfg, seed)
                if hasattr(gen, "closing_edges") else None)
-    log(f"stream: {n_edges} edges in {time.perf_counter() - t:.2f}s")
+    stream_s = time.perf_counter() - t
+    log(f"stream: {n_edges} edges in {stream_s:.2f}s")
 
     source = WindowSource(src, dst, w_edges, traffic["ingest"],
                           closing=closing)
@@ -353,8 +375,9 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
             t0 = time.perf_counter()
             wall0 = time.time()
             # process start to here, less the accelerator runtime's
-            # own start-up (start_backend: logged and given beside it)
-            setup_s = t0 - t_process - runtime_init_s
+            # own start-up (start_backend) and the generation of the
+            # stream (above): both logged and given beside it
+            setup_s = t0 - t_process - runtime_init_s - stream_s
             t_end = t0 + seconds
             source.start_measuring(t0)
             load = QueryLoad(server.submit_many, draw,
@@ -418,9 +441,17 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
             obs_trace.disable()
     host_s = list(servable.host_s)
     del table_dev, final, server, agg, servable, stream
-    log(f"window closed: {n_handed} windows handed out, {in_window} "
+    n_main = source.n_main or 0
+    log(f"window closed: handed {n_main} of {source.n_windows} windows of "
+        f"the stream and {n_handed - n_main} closing, {in_window} "
         f"compilations inside, peak {peak / 2**30:.2f} GiB, "
         f"set-up {setup_s:.1f}s")
+    # an open loop hands out what its own file paces, whatever the
+    # program does: only a closed loop can run into the end of the stream
+    warning = (source.mode == "closed" and stream_headroom_warning(
+        n_main, source.n_windows, cell.traffic_name))
+    if warning:
+        log(warning)
     if require_tpu and off_chip:
         raise RunError(f"the chip's paths did not run: {off_chip}")
 
@@ -452,12 +483,13 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
            "failed": values["_failed"], "metrics": metrics, "device": device}
     if breakdown is not None:
         out["breakdown"] = breakdown
-    out["windows"] = {"handed": n_handed,
+    out["windows"] = {"handed": n_handed, "stream": source.n_windows,
                       "ready_in_window": values["_windows_in_window"],
-                      "closing": n_handed - (source.n_main or 0),
+                      "closing": n_handed - n_main,
                       "max_outstanding": source.max_outstanding,
                       "compiles_in_window": in_window}
     out["runtime_init_s"] = runtime_init_s
+    out["stream_s"] = stream_s
     out["compared"] = compared    # last: each number beside its limit
     return out
 

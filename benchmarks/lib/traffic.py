@@ -16,8 +16,19 @@ out last (a client asks about what it has just written).
 ``warm_sweeps`` lists, in batches, the sizes of coalesced sweep that
 set-up sends once each (the server answers everything pending in one
 sweep, and its kernels are jitted per power-of-two bucket).
-``stream_edges_per_s`` sizes the pre-generated stream (it has to outrun
-the system; a stream that ends inside the window fails the run).
+``stream_edges_per_s`` sizes the pre-generated stream
+(``cellrun.stream_length``: the windows that rate would fold in the
+run's seconds, the warm-up's and four to spare). It is no rate at which
+anything is sent: it is the ceiling of what a run can read, since a
+stream that ends inside the window gives no result (exit 2). The rule:
+at least twice the fastest ``edges_per_s`` on record in any cell of the
+mix (an open loop: above its own ``edges_per_s``, which it cannot
+outrun); a closed-loop run that hands out over 60% of its stream logs
+a warning that names the file, and a ``benchmark`` PR raises the number
+then. A longer stream is the shorter one and a tail (the generator keys
+every chunk by its index), so raising it changes no window that was
+timed before. Generating the stream is the benchmark's own work: its seconds
+are given as ``stream_s`` and are no part of ``setup_s``.
 ``closing_batches`` batches are sent once the measured window has
 closed and the generator's closing windows (below) are ready; their
 answers are compared and not timed.
