@@ -26,10 +26,10 @@ Per window, every kernel is sized by the window, not the vertex space:
    window).
 3. A min-label fixpoint over the **local** T-sized table joins the
    window's edges with "same current root" chain constraints (from one
-   T-sort), exactly the dense kernel's hook+shortcut but on a table the
-   size of the window.
-4. One masked scatter re-roots the old roots (and the touched vertices,
-   for path compression) to the merged component's min root.
+   scatter-min into a vcap-sized scratch), exactly the dense kernel's
+   hook+shortcut but on a table the size of the window.
+4. A pair of masked scatters re-roots the old roots (and the touched
+   vertices, for path compression) to the merged component's min root.
 
 The remaining vcap-sized costs are bandwidth-only: the functional
 scatter's buffer copy (which is also what keeps per-window emissions
@@ -42,8 +42,10 @@ Tracing (``obs/trace.py``): the device phases are ``jax.named_scope``s
 inside the jitted step, shared by the per-window and the superbatch
 steps of both carries (CC and the signed cover) — ``forest.chase``
 (step 2's pointer chase), ``forest.group`` (its vcap-sized same-root
-scratch), ``forest.fixpoint`` (step 3), ``forest.commit`` (step 4) and,
-on the cover, ``forest.latch``. A device trace carries the scope in
+scratch), ``forest.fixpoint`` (step 3), ``forest.commit`` (step 4),
+inside group and commit ``forest.sort`` (the sort ahead of each
+table-sized scatter) and, on the cover, ``forest.latch``. A device
+trace carries the scope in
 the ``tf_op`` stat of each ``XLA Ops`` event's metadata; the jitted
 programs keep the name ``jit_step``. On the host one window is the span ``forest.window`` with
 the children ``forest.prep`` (step 1 and the padding) and
@@ -53,12 +55,14 @@ Vertex-sharded layout (``parallel/mesh.py``'s ``vertices`` axis above
 1): the SAME step over a table of which every chip holds one
 contiguous block of rows. Every access to the ``vcap``-sized table goes
 through one pair of primitives, :class:`TableOps` ``gather`` and
-``scatter``; with no axis they are ``table[idx]`` and
-``table.at[idx].set/min(mode="drop")``, with the axis the whole step
-runs under ``shard_map``, the lanes are replicated, the owner of a row
-answers a gather and one all-reduce (scope ``forest.exchange``) makes
-the lanes whole, and a scatter applies the lanes this chip owns with no
-exchange. The host then also emits ``forest.place`` (child of
+``scatter``; with no axis they are ``table[idx]`` and, behind a sort
+of the (row, value) lanes by row (scope ``forest.sort``),
+``table.at[idx].set/min(mode="drop", indices_are_sorted=True)``; with
+the axis the whole step runs under ``shard_map``, the lanes are
+replicated, the owner of a row answers a gather and one all-reduce
+(scope ``forest.exchange``) makes the lanes whole, and a scatter sorts
+and applies the lanes this chip owns with no exchange. The host then
+also emits ``forest.place`` (child of
 ``forest.window``: the window's columns placed on every chip) and the
 attributes ``shards`` and ``owner_max_share`` on ``forest.window``.
 
@@ -121,8 +125,19 @@ class TableOps:
     lanes (``set`` or ``min``).
 
     ``TableOps(vcap)`` is the whole table on one chip: ``table[idx]``
-    and ``table.at[idx].set/min(val, mode="drop")``, the ops the
-    mesh-less step has always compiled to.
+    and ``table.at[idx].set/min(val, mode="drop")`` over lanes sorted by
+    row.
+
+    Every scatter goes out sorted: the (row, value) pairs are sorted by
+    row, then value (``lax.sort``, under the named scope
+    ``forest.sort``), and the scatter says ``indices_are_sorted``. On
+    the chip a table-sized scatter of unsorted lanes costs 91 ns a lane
+    and one of sorted lanes 17 ns; the sort, in fast memory, under a
+    microsecond a thousand lanes. The table that comes out is the same
+    on every row: ``min`` does not care for order, and the callers'
+    ``set`` writes one value to a row however often the row repeats.
+    ``unique_indices`` is NOT said: an old root repeats once per touched
+    member, and the pads all sit on one sentinel.
 
     ``TableOps(vcap, shards, exchange)`` is one chip's block of a table
     split over the ``vertices`` axis, and is used INSIDE ``shard_map``
@@ -132,9 +147,10 @@ class TableOps:
     this chip owns and 0 elsewhere, and ONE all-reduce (a sum: every
     row has exactly one owner) under the named scope ``exchange`` makes
     the lanes whole on every chip. A scatter applies the lanes this
-    chip owns and drops the rest at the local sentinel ``rows``; the
-    callers' whole-table sentinel ``vcap`` is nobody's row, so it drops
-    everywhere. No exchange: every chip sees every lane."""
+    chip owns and drops the rest at the local sentinel ``rows``, where
+    they sort to the end; the callers' whole-table sentinel ``vcap`` is
+    nobody's row, so it drops everywhere. No exchange: every chip sees,
+    and sorts, every lane."""
 
     __slots__ = ("rows", "shards", "exchange")
 
@@ -171,8 +187,15 @@ class TableOps:
         if self.shards > 1:
             mine, off = self._local(idx)
             idx = jnp.where(mine, off, self.rows)
+        # dropped lanes carry a sentinel at or past ``rows`` and sort to
+        # the end; the second key makes the sorted lanes independent of
+        # the order they came in
+        with jax.named_scope("forest.sort"):
+            idx, val = lax.sort((idx, val), num_keys=2)
         at = table.at[idx]
-        return (at.min if op == "min" else at.set)(val, mode="drop")
+        return (at.min if op == "min" else at.set)(
+            val, mode="drop", indices_are_sorted=True
+        )
 
 
 def sharded_table_fn(fn, mesh, n_lanes: int, table_out: bool):
@@ -240,12 +263,12 @@ def chase_and_group(canon, tid, tmask, tcap: int, vcap: int,
     1. Chase touched pointers to their current roots
        (:func:`chase_roots`). Padding lanes chase from 0, which is
        always self-rooted (canon[0] <= 0).
-    2. "Same current root" constraints WITHOUT a sort (argsort over the
-       touched bucket measured 375 ms on the CPU backend): scatter each
-       lane's local index into a vcap scratch keyed by root, so every
-       lane learns its group's representative lane — one bandwidth-bound
-       memset+scatter+gather instead of a comparison sort. Edge
-       (i, rep_i) unifies the group; pads self-loop.
+    2. "Same current root" constraints: scatter-min each lane's local
+       index into a vcap scratch keyed by root, so every lane learns
+       its group's representative lane — a memset, a scatter and a
+       gather. The scatter's lanes are sorted by root first
+       (:meth:`TableOps.scatter`: 17 against 91 ns a lane on the chip).
+       Edge (i, rep_i) unifies the group; pads self-loop.
 
     Returns ``(r, v2, key_, iota)``: current roots per lane, the group-
     edge targets, the root-value keys (+inf on pads), and the lane iota.

@@ -1,0 +1,118 @@
+"""The table's scatter as it was before its lanes were sorted (ISSUE 31),
+kept as the plain reference the sorted one is held to, with what the
+tests of the one-chip and of the vertex-sharded steps share: Kronecker
+windows, and a reader of a lowered step's scatters and sorts."""
+
+import re
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from gelly_streaming_tpu.datasets import rmat_edges
+from gelly_streaming_tpu.summaries import candidates, forest
+
+
+def plain_scatter(self, table, idx, val, op: str = "set"):
+    """``TableOps.scatter`` with the lanes in the order they come."""
+    if self.shards > 1:
+        mine, off = self._local(idx)
+        idx = jnp.where(mine, off, self.rows)
+    at = table.at[idx]
+    return (at.min if op == "min" else at.set)(val, mode="drop")
+
+
+def _clear_steps():
+    forest._FOREST_STEP_CACHE.clear()
+    candidates._COVER_STEP_CACHE.clear()
+
+
+@pytest.fixture
+def unsorted_steps(monkeypatch):
+    """-> a call that rebuilds every step over :func:`plain_scatter`."""
+    def swap():
+        monkeypatch.setattr(forest.TableOps, "scatter", plain_scatter)
+        _clear_steps()
+
+    yield swap
+    monkeypatch.undo()
+    _clear_steps()
+
+
+def kronecker_windows(seed: int, scale: int, n: int, size: int,
+                      bipartite: bool = False):
+    """``n`` windows of ``size`` edges: a prefix of a Graph500 Kronecker
+    stream (hubs at the low ids, so roots move and rows repeat among a
+    window's old roots); sources even and targets odd if ``bipartite``."""
+    src, dst = rmat_edges(n * size, scale, seed=seed)
+    if bipartite:
+        src, dst = src & ~1, dst | 1
+    for k in range(n):
+        cut = slice(k * size, (k + 1) * size)
+        yield src[cut].astype(np.int32), dst[cut].astype(np.int32)
+
+
+def cc_tables(seed: int, scale: int = 12, mesh=None) -> list:
+    """The CC forest after each of ten Kronecker windows, every row."""
+    vcap = 1 << scale
+    canon, prep, out = forest.init_forest(vcap, mesh), forest.WindowPrep(), []
+    for s, d in kronecker_windows(seed, scale, 10, 512):
+        canon, _tids = forest.forest_window(canon, s, d, vcap, prep,
+                                            mesh=mesh)
+        out.append(np.asarray(canon))
+    return out
+
+
+def cover_tables(seed: int, scale: int = 11) -> list:
+    """The cover forest after each of ten bipartite Kronecker windows."""
+    vcap = 1 << scale
+    canon, failed = forest.init_forest(2 * vcap), jnp.bool_(False)
+    prep, out = forest.WindowPrep(), []
+    for s, d in kronecker_windows(seed, scale, 10, 512, bipartite=True):
+        canon, failed, _tids = candidates.cover_forest_window(
+            canon, failed, s, d, vcap, prep)
+        out.append(np.asarray(canon))
+    assert not bool(failed)
+    return out
+
+
+_LOC_DEF = re.compile(r'^(#loc\d+) = loc\("([^"]*)"')
+_LOC_USE = re.compile(r"loc\((#loc\d+)\)\s*$")
+
+
+def scoped_ops(text: str, name: str) -> list:
+    """``(op_name path, the op's own line)`` of every ``stablehlo.<name>``
+    in ``lowered.as_text(debug_info=True)``. An op with a region carries
+    its location on the line that closes the region."""
+    lines = text.splitlines()
+    paths = dict(m.groups() for m in map(_LOC_DEF.match, lines) if m)
+    out = []
+    for i, ln in enumerate(lines):
+        if f'"stablehlo.{name}"' not in ln:
+            continue
+        end = next(x for x in lines[i:] if _LOC_USE.search(x)
+                   and (x is ln or x.lstrip().startswith("})")))
+        out.append((paths[_LOC_USE.search(end).group(1)], ln))
+    return out
+
+
+def _from_scope(path: str) -> str:
+    """``jit(step)/[shard_map/]forest.group/...`` from its phase on."""
+    return path[path.index("forest."):]
+
+
+def assert_table_scatters_go_out_sorted(text: str) -> None:
+    """The group's scatter-min and the commit's two sets say
+    ``indices_are_sorted``, each behind a sort under ``forest.sort``;
+    the window-sized scatters stay as they were."""
+    scatters = scoped_ops(text, "scatter")
+    said = [_from_scope(path) for path, ln in scatters
+            if "indices_are_sorted = true" in ln]
+    assert said == ["forest.group/scatter-min", "forest.commit/scatter",
+                    "forest.commit/scatter"]
+    assert len(scatters) == 6
+    assert all("unique_indices = false" in ln for _p, ln in scatters)
+    sorts = [_from_scope(path) for path, _ln in scoped_ops(text, "sort")]
+    assert sorts == ["forest.group/forest.sort/sort",
+                     "forest.commit/forest.sort/sort",
+                     "forest.commit/forest.sort/sort"]

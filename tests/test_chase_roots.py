@@ -272,5 +272,15 @@ def test_phases_block_gives_ms_per_round_of_each_loop(rounds, want):
     assert per_round == pytest.approx(want)
 
 
+@pytest.mark.parametrize("nested", ["sort", "exchange"])
+def test_phases_block_keeps_a_nested_scope_beside_the_sum(nested):
+    """``forest.sort`` runs inside group and commit, ``forest.exchange``
+    inside chase and group: read, and not added a second time."""
+    got = _phases({**_STEP, f"forest_{nested}_ms.sat": 0.24})
+    assert got["sum_ms"] == pytest.approx(93.0)
+    assert got[f"{nested}_ms"] == pytest.approx(0.24)
+    assert got[f"{nested}_share"] == pytest.approx(0.24 / 96.0)
+
+
 def test_phases_block_is_empty_without_a_step():
     assert _phases({"answer_ms": 25.0}) == {}

@@ -11,6 +11,12 @@ from gelly_streaming_tpu.core.stream import SimpleEdgeStream
 from gelly_streaming_tpu.core.window import CountWindow
 from gelly_streaming_tpu.library import ConnectedComponents
 
+from _scatter_ref import (  # noqa: F401  (unsorted_steps is a fixture)
+    assert_table_scatters_go_out_sorted,
+    cc_tables,
+    cover_tables,
+    unsorted_steps,
+)
 from _uf import union_find_components as _union_find_components
 
 
@@ -439,3 +445,49 @@ def test_apply_forest_delta_reports_touched_roots():
     with pytest.raises(ValueError):
         apply_forest_delta_host(lab, sizes,
                                 np.asarray([1]), np.asarray([], np.int64))
+
+
+# --------------------------------------------------------------------- #
+# Every scatter into the table goes out sorted (ISSUE 31): the steps
+# against the scatter as it was, kept in ``_scatter_ref`` as the plain
+# reference; the same table on EVERY row, pointer shape included
+# --------------------------------------------------------------------- #
+@pytest.mark.parametrize("seed", [1, 2**31 + 5])
+@pytest.mark.parametrize("fold", [cc_tables, cover_tables],
+                         ids=["cc-step", "cover-step"])
+def test_sorted_scatters_give_the_unsorted_steps_table_on_every_row(
+        fold, seed, unsorted_steps):
+    got = fold(seed)
+    unsorted_steps()
+    want = fold(seed)
+    for w, (a, b) in enumerate(zip(got, want)):
+        np.testing.assert_array_equal(a, b, err_msg=f"window {w}")
+    # the stream did re-root: rows other than the touched ids' moved, and
+    # some old root was written once per touched member
+    moved = np.flatnonzero(got[-1] != np.arange(len(got[-1])))
+    assert len(moved) > 100 and len(np.unique(got[-1][moved])) < len(moved)
+
+
+def _lowered_step(which: str) -> str:
+    import jax
+    import jax.numpy as jnp
+
+    from gelly_streaming_tpu.summaries import candidates, forest
+
+    S = jax.ShapeDtypeStruct
+    tcap, wcap, vcap = 1 << 10, 1 << 9, 1 << 14
+    lanes = (S((tcap,), jnp.int32), S((tcap,), jnp.bool_),
+             S((wcap,), jnp.int32), S((wcap,), jnp.int32))
+    if which == "cc-step":
+        lowered = forest._forest_step_fn(tcap, wcap, vcap).lower(
+            S((vcap,), jnp.int32), *lanes)
+    else:
+        lowered = candidates._cover_step_fn(tcap, wcap, vcap).lower(
+            S((2 * vcap,), jnp.int32), S((), jnp.bool_), *lanes,
+            S((wcap,), jnp.bool_))
+    return lowered.as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("which", ["cc-step", "cover-step"])
+def test_the_lowered_step_says_its_table_scatters_are_sorted(which):
+    assert_table_scatters_go_out_sorted(_lowered_step(which))

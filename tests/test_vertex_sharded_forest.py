@@ -41,6 +41,13 @@ from gelly_streaming_tpu.serving import (
 from gelly_streaming_tpu.serving import query as squery
 from gelly_streaming_tpu.summaries import forest
 
+from _scatter_ref import (  # noqa: F401  (unsorted_steps is a fixture)
+    assert_table_scatters_go_out_sorted,
+    cc_tables,
+    plain_scatter,
+    unsorted_steps,
+)
+
 SHARDS = 4
 
 
@@ -365,15 +372,17 @@ def _ops(text: str, name: str) -> int:
     return len(re.findall(r'(?<!#)"?stablehlo\.%s"?[ (]' % name, text))
 
 
-def _lowered(which: str, mesh=None) -> str:
+def _lowered(which: str, mesh=None, debug_info: bool = False) -> str:
     S = jax.ShapeDtypeStruct
     if which == "batch_roots":
         return squery._batch_roots_fn(mesh).lower(
-            S((1 << 14,), jnp.int32), S((256,), jnp.int32)).as_text()
+            S((1 << 14,), jnp.int32), S((256,), jnp.int32)).as_text(
+                debug_info=debug_info)
     tcap, wcap, vcap = 1 << 10, 1 << 9, 1 << 14
     return forest._forest_step_fn(tcap, wcap, vcap, mesh).lower(
         S((vcap,), jnp.int32), S((tcap,), jnp.int32), S((tcap,), jnp.bool_),
-        S((wcap,), jnp.int32), S((wcap,), jnp.int32)).as_text()
+        S((wcap,), jnp.int32), S((wcap,), jnp.int32)).as_text(
+            debug_info=debug_info)
 
 
 @pytest.mark.parametrize("which", ["step", "batch_roots"])
@@ -399,19 +408,82 @@ def test_the_sharded_program_keeps_its_name_and_scopes_its_collectives(
     assert _ops(text, "all_reduce") == n
     for name in COLLECTIVES[1:]:
         assert _ops(text, name) == 0, name
-    debug = (squery._batch_roots_fn(mesh) if which == "batch_roots"
-             else forest._forest_step_fn(1 << 10, 1 << 9, 1 << 14, mesh))
-    S = jax.ShapeDtypeStruct
-    args = ((S((1 << 14,), jnp.int32), S((256,), jnp.int32))
-            if which == "batch_roots" else
-            (S((1 << 14,), jnp.int32), S((1 << 10,), jnp.int32),
-             S((1 << 10,), jnp.bool_), S((1 << 9,), jnp.int32),
-             S((1 << 9,), jnp.int32)))
-    with_locs = debug.lower(*args).as_text(debug_info=True)
+    with_locs = _lowered(which, mesh, debug_info=True)
     scoped = [ln for ln in with_locs.splitlines()
               if "all_reduce" in ln and "stablehlo" in ln]
     assert len(scoped) == n
     assert with_locs.count(exchange) >= n
+
+
+# --------------------------------------------------------------------- #
+# every scatter into the table goes out sorted (ISSUE 31)
+# --------------------------------------------------------------------- #
+def _scatter_lanes(seed: int, vcap: int, lanes: int, op: str):
+    """Lanes out of order, a third of them pads at the sentinel
+    ``vcap``, rows that repeat: with one value a row for ``set`` (what
+    the commit writes), with unequal ones for ``min``."""
+    rng = np.random.default_rng(seed)
+    idx = rng.integers(0, vcap, lanes // 8).astype(np.int32)
+    idx = rng.choice(idx, lanes).astype(np.int32)
+    val = (idx * 7 + 3 if op == "set"
+           else rng.integers(0, 1 << 20, lanes)).astype(np.int32)
+    idx[rng.random(lanes) < 1 / 3] = vcap
+    assert len(np.unique(idx)) < lanes // 4 and (np.diff(idx) < 0).any()
+    return idx, val
+
+
+@pytest.mark.parametrize("seed", [7, 2**31 + 9])
+@pytest.mark.parametrize("op", ["set", "min"])
+@pytest.mark.parametrize("shards", [1, SHARDS])
+def test_the_tables_scatter_is_the_plain_one_lane_order_and_all(
+        mesh, shards, op, seed):
+    vcap, lanes = 1 << 10, 512
+    idx, val = _scatter_lanes(seed, vcap, lanes, op)
+    table = np.random.default_rng(seed + 1).integers(
+        1 << 19, 1 << 21, vcap).astype(np.int32)
+    want = table.copy()
+    keep = idx < vcap
+    if op == "set":
+        want[idx[keep]] = val[keep]
+    else:
+        np.minimum.at(want, idx[keep], val[keep])
+    tab = forest.TableOps(vcap, shards)
+
+    def run(scatter, order):
+        def fn(t, i, v):
+            return scatter(tab, t, i, v, op)
+
+        if shards > 1:
+            fn = forest.sharded_table_fn(fn, mesh, 2, table_out=True)
+        return np.asarray(jax.jit(fn)(
+            jnp.asarray(table), jnp.asarray(idx[order]),
+            jnp.asarray(val[order])))
+
+    order = np.arange(lanes)
+    got = run(forest.TableOps.scatter, order)
+    assert np.array_equal(got, want)
+    assert np.array_equal(got, run(plain_scatter, order))
+    assert np.array_equal(got, np.asarray(getattr(
+        jnp.asarray(table).at[jnp.asarray(idx)], op)(
+            jnp.asarray(val), mode="drop")))
+    assert np.array_equal(
+        got, run(forest.TableOps.scatter, order[::-1].copy()))
+
+
+@pytest.mark.parametrize("seed", [1, 2**31 + 5])
+def test_sorted_scatters_give_the_unsorted_sharded_steps_table(
+        mesh, seed, unsorted_steps):
+    got = cc_tables(seed, mesh=mesh)
+    unsorted_steps()
+    want = cc_tables(seed, mesh=mesh)
+    for w, (a, b) in enumerate(zip(got, want)):
+        np.testing.assert_array_equal(a, b, err_msg=f"window {w}")
+    assert (got[-1] != np.arange(len(got[-1]))).sum() > 100
+
+
+def test_the_lowered_sharded_step_says_its_table_scatters_are_sorted(mesh):
+    assert_table_scatters_go_out_sorted(
+        _lowered("step", mesh, debug_info=True))
 
 
 # --------------------------------------------------------------------- #

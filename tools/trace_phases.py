@@ -18,7 +18,8 @@ benchmark is edited.
 
 The last line of standard output is the result document, with
 ``phases`` (the phases' sum against the whole step, the ms one
-round of the chase and of the fixpoint costs and, where the table is
+round of the chase and of the fixpoint costs, the sorts ahead of the
+table's scatters under ``forest.sort`` and, where the table is
 sharded over chips, the collectives of a step under ``forest.exchange``:
 their ms, their count and the span attributes ``shards`` and
 ``owner_max_share``), ``events`` (span
@@ -62,6 +63,10 @@ PROPOSED = {
        for p in ("chase", "group", "fixpoint", "commit")},
     "forest_latch_ms.sat": ("ms", "forest step", "edges_per_s", SAT[1:],
                             _scope("scope_mean_ms", "forest.latch")),
+    # the sorts ahead of the three table-sized scatters: inside group
+    # and commit, so NOT a phase to add to their sum
+    "forest_sort_ms.sat": ("ms", "forest step", "edges_per_s", SAT,
+                           _scope("scope_mean_ms", "forest.sort")),
     **{f"forest_{p}_rounds.sat": ("count", "forest step", "edges_per_s", SAT,
                                   _scope("scope_rounds_mean", f"forest.{p}"))
        for p in ("chase", "fixpoint")},
@@ -77,12 +82,13 @@ PROPOSED = {
     "answer_wait_ms": ("ms", "serving", "query_p95_ms", CC,
                        {"kind": "span_mean_ms",
                         "span": "serving.device_wait"}),
-    # the vertex-sharded cell: the same phases on chip 0's line, and the
-    # collectives that make the lanes whole (inside chase and group, so
-    # NOT a phase to add to their sum)
+    # the vertex-sharded cell: the same phases on chip 0's line, the
+    # sorts, and the collectives that make the lanes whole (inside chase
+    # and group, so NOT a phase to add to their sum)
     **{f"forest_{p}_ms.v4": ("ms", "forest step", "edges_per_s", V4,
                              _scope("scope_mean_ms", f"forest.{p}"))
-       for p in ("chase", "group", "fixpoint", "commit", "exchange")},
+       for p in ("chase", "group", "fixpoint", "commit", "sort",
+                 "exchange")},
     **{f"forest_{p}_rounds.v4": ("count", "forest step", "edges_per_s", V4,
                                  _scope("scope_rounds_mean", f"forest.{p}"))
        for p in ("chase", "fixpoint")},
@@ -117,11 +123,12 @@ def phases_block(m: dict) -> dict:
                 None)
     tag = next((t for t in (".sat", ".v4") if f"forest_chase_ms{t}" in m),
                ".sat")
-    # the exchanges run inside chase and group: beside the sum, not in it
+    # the exchanges run inside chase and group, the sorts inside group
+    # and commit: beside the sum, not in it
+    nested = {f"forest_{p}_ms{tag}": p for p in ("exchange", "sort")}
     parts = {k: m[k]["value"] for k in m
              if k.startswith("forest_") and k.endswith("_ms" + tag)
-             and k not in ("forest_step_ms" + tag,
-                           "forest_exchange_ms" + tag)}
+             and k != "forest_step_ms" + tag and k not in nested}
     if not (step and parts):
         return {}
     out = {"sum_ms": sum(parts.values()), "step_ms": step,
@@ -131,10 +138,10 @@ def phases_block(m: dict) -> dict:
         rounds = m.get(f"forest_{p}_rounds{tag}")
         if ms and rounds and rounds["value"]:
             out[f"{p}_ms_per_round"] = ms["value"] / rounds["value"]
-    exchange = m.get("forest_exchange_ms" + tag)
-    if exchange:
-        out["exchange_ms"] = exchange["value"]
-        out["exchange_share"] = exchange["value"] / step
+    for key, p in nested.items():
+        if m.get(key):
+            out[f"{p}_ms"] = m[key]["value"]
+            out[f"{p}_share"] = m[key]["value"] / step
     return out
 
 
