@@ -1,4 +1,4 @@
-"""Bipartiteness state: signed double cover over dense labels.
+"""Bipartiteness state: connected components on the signed double cover.
 
 The reference tracks 2-colored candidate components in a nested
 TreeMap structure with sign-flipping merges and a global failure latch
@@ -7,11 +7,20 @@ structure with a classic reduction: run connected components on the *signed
 double cover* — every vertex v becomes two cover nodes (v,+) and (v,-), and
 every edge (u,v) becomes cover edges (u,+)-(v,-) and (u,-)-(v,+). The graph
 is bipartite iff no vertex's two cover nodes land in the same component.
-That turns all of ``Candidates``' pointer logic into the same dense label
-kernels CC uses (``summaries/labels.py``), sharing its collectives.
+That turns all of ``Candidates``' pointer logic into the folds CC runs:
 
-Layout: cover node (v,+) = index v, (v,-) = index v + vcap, in a label table
-of size 2*vcap.
+- the dense carry (:func:`cover_fold`) is ``labels._propagate`` over the
+  cover edges, on a label table of 2*vcap rows;
+- the forest carry (:func:`cover_forest_window`, and
+  :func:`cover_forest_superbatch` for K windows fused) is the forest fold
+  of ``summaries/forest.py``, the same ``window_body`` and ``group_body``
+  CC's programs are, over the 2*vcap cover id space, plus three things
+  written here: the lanes doubled (:func:`_cover_lanes`), an edge mask
+  over the pad rows, and the conflict latch (:func:`_conflict`). What the
+  step costs on the chip is in ``PERF.md`` section 5.
+
+Layout: cover node (v,+) = index v, (v,-) = index v + vcap, in a table of
+size 2*vcap.
 
 :class:`Candidates` is the host-side emission object, reproducing the
 reference's output format byte-for-byte: ``(true,{1={1=(1,true), ...}})`` /
@@ -28,15 +37,17 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..core.edgeblock import bucket_capacity
 from ..obs import trace as _trace
 from .forest import (
-    chase_and_group,
-    commit_roots,
-    new_roots,
+    ForestReplay,
+    TableOps,
+    _make_local_fixpoint,
+    cached_step,
+    group_body,
     note_buckets,
+    pad_group,
     pad_window,
-    reroot,
+    window_body,
     window_span,
 )
 from .labels import _propagate, init_labels
@@ -93,65 +104,60 @@ def cover_grow(state: Dict[str, jax.Array], old_vcap: int, new_vcap: int) -> Dic
     return {"labels": jnp.asarray(new_lab), "touched": jnp.asarray(new_tch)}
 
 
-#: jitted cover window steps, keyed (tcap, wcap, vcap); bounded FIFO
-_COVER_STEP_CACHE: dict = {}
-_COVER_STEP_CACHE_MAX = 32
+def _cover_lanes(tid, tmask, lu, lv, emask, tcap: int, vcap: int):
+    """The cover's lanes, derived in-graph from the base prep (no extra
+    host pass): ``(tid2, tmask2, lu2, lv2, emask2)``. The touched bucket
+    holds the base touched set twice — lane i is cover node (t_i, +) =
+    t_i and lane i + tcap is (t_i, -) = t_i + vcap — so a lane's sibling
+    is at a fixed offset; row (u, v) becomes the cover edges (u,+)~(v,-)
+    and (u,-)~(v,+). ``lu, lv, emask`` are ``[wcap]`` (one window) or
+    ``[k, wcap]`` (a group). UNLIKE the plain CC fold, pad rows need a
+    real mask: a pad (0,0) is a harmless self-loop in base space but
+    maps to (0,+)~(0,-) in the cover — a fabricated odd cycle."""
+    return (
+        jnp.concatenate([tid, tid + vcap]),
+        jnp.concatenate([tmask, tmask]),
+        jnp.concatenate([lu, lu + tcap], axis=-1),
+        jnp.concatenate([lv + tcap, lv], axis=-1),
+        jnp.concatenate([emask, emask], axis=-1),
+    )
+
+
+def _conflict(nr, tmask, tcap: int):
+    """Sibling conflict over the touched lanes of ``nr`` (``[2*tcap]``,
+    or ``[k, 2*tcap]``: one verdict a window). CONFLICT COMPLETENESS: a
+    new odd cycle means some vertex's two cover nodes connect THIS
+    window; the merged cover component is then sign-symmetric, so every
+    touched member's sibling lies in the same component — checking
+    ``final_root[i] == final_root[i + tcap]`` over the touched lanes
+    alone misses nothing."""
+    return jnp.any(tmask & (nr[..., :tcap] == nr[..., tcap:]), axis=-1)
 
 
 def _cover_step_fn(tcap: int, wcap: int, vcap: int):
-    """Window-local signed-cover step (round 5): the forest CC step
-    (``summaries/forest.py``) over the 2*vcap cover id space, plus the
-    bipartiteness conflict latch.
+    """Window-local signed-cover step: the forest fold of one window
+    (``forest.window_body``) over the 2*vcap cover id space — the lanes
+    doubled and the pad rows masked (:func:`_cover_lanes`) — plus the
+    bipartiteness conflict latch (:func:`_conflict`). The latch carries
+    on device (monotone OR), so the producer loop stays zero-D2H."""
 
-    Layout: the touched bucket holds the window's base touched set twice
-    — lane i is cover node (t_i, +) = t_i and lane i + tcap is
-    (t_i, -) = t_i + vcap — so a lane's sibling is at a fixed offset.
-    CONFLICT COMPLETENESS: a new odd cycle means some vertex's two cover
-    nodes connect THIS window; the merged cover component is then
-    sign-symmetric, so every touched member's sibling lies in the same
-    component — checking ``final_root[i] == final_root[i + tcap]`` over
-    the touched lanes alone misses nothing. The latch carries on device
-    (monotone OR), so the producer loop stays zero-D2H.
-    """
-    key = (tcap, wcap, vcap)
-    fn = _COVER_STEP_CACHE.get(key)
-    if fn is not None:
-        return fn
-
-    tcap2, vcap2 = 2 * tcap, 2 * vcap
-
-    def step(canon, failed, tid, tmask, lu, lv, emask):
-        # cover touched bucket + cover edges, derived in-graph from the
-        # base prep (no extra host pass): (u,+)~(v,-) and (u,-)~(v,+).
-        # UNLIKE the plain CC forest step, pad rows need a real mask: a
-        # pad (0,0) is a harmless self-loop in base space but maps to
-        # (0,+)~(0,-) in the cover — a fabricated odd cycle.
-        tid2 = jnp.concatenate([tid, tid + vcap])
-        tmask2 = jnp.concatenate([tmask, tmask])
-        lu2 = jnp.concatenate([lu, lu + tcap])
-        lv2 = jnp.concatenate([lv + tcap, lv])
-        emask2 = jnp.concatenate([emask, emask])
-        r, v2, key_, iota = chase_and_group(canon, tid2, tmask2, tcap2, vcap2)
-        with jax.named_scope("forest.fixpoint"):
-            u = jnp.concatenate([lu2, iota])
-            w = jnp.concatenate([lv2, v2])
-            m = jnp.concatenate([emask2, jnp.ones(tcap2, bool)])
-            local = _propagate(iota, u, w, m)
-        canon, nr = commit_roots(
-            canon, local, key_, r, tid2, tmask2, tcap2, vcap2
+    def build():
+        body = window_body(
+            2 * tcap, 2 * vcap, TableOps(2 * vcap),
+            _make_local_fixpoint(2 * tcap),
         )
-        # sibling conflict over the touched lanes (see docstring)
-        with jax.named_scope("forest.latch"):
-            failed = failed | jnp.any(
-                tmask & (nr[:tcap] == nr[tcap:])
-            )
-        return canon, failed
 
-    fn = jax.jit(step)
-    if len(_COVER_STEP_CACHE) >= _COVER_STEP_CACHE_MAX:
-        _COVER_STEP_CACHE.pop(next(iter(_COVER_STEP_CACHE)))
-    _COVER_STEP_CACHE[key] = fn
-    return fn
+        def step(canon, failed, tid, tmask, lu, lv, emask):
+            canon, nr = body(
+                canon, *_cover_lanes(tid, tmask, lu, lv, emask, tcap, vcap)
+            )
+            with jax.named_scope("forest.latch"):
+                failed = failed | _conflict(nr, tmask, tcap)
+            return canon, failed
+
+        return jax.jit(step)
+
+    return cached_step(("cover", tcap, wcap, vcap), build)
 
 
 def cover_forest_window(canon, failed, src_h, dst_h, vcap: int, prep):
@@ -178,140 +184,70 @@ def cover_forest_window(canon, failed, src_h, dst_h, vcap: int, prep):
 
 
 def _cover_superbatch_fn(tcap: int, wcap: int, vcap: int, k: int):
-    """K cover window-steps fused into one jitted dispatch, GROUP-LOCAL —
-    the signed-cover analog of ``forest._forest_superbatch_fn`` (the
-    bipartiteness carry's ``GroupFoldable`` kernel):
+    """K cover window-steps fused into one jitted dispatch, GROUP-LOCAL
+    (the bipartiteness carry's ``GroupFoldable`` kernel): the forest
+    fold of a group (``forest.group_body``) over the cover's lanes
+    (:func:`_cover_lanes`), plus the latch AFTER EACH window, read off
+    the scan's per-window assignments ``nr_s``: window k's is ``failed``
+    OR a conflict in any of ``nr_s[:k+1]`` over the GROUP's touched
+    lanes — sound, because ``nr_k`` equality means "same cover component
+    as of window k" for every group-touched lane, and complete, because
+    a conflict arising at window k lives in a sign-symmetric component
+    whose touched members witness it (:func:`_conflict`).
 
-    1. ONE root chase + same-root grouping over the group's union
-       touched set, expanded to BOTH cover halves (lane i = (t_i, +),
-       lane i + tcap = (t_i, -)) — one 2*vcap scratch memset per GROUP;
-    2. a ``lax.scan`` over the K windows whose carry is the 2*tcap-sized
-       local label table plus the failure latch: window k folds its
-       cover edges ((u,+)~(v,-), (u,-)~(v,+); pad rows carry a real edge
-       mask, the ``_cover_step_fn`` caveat) into the carried table and
-       emits its new-root assignment ``nr_k`` PLUS the latch after the
-       window (the per-window sibling-conflict check runs over the
-       GROUP's touched lanes — sound, because ``nr_k`` equality means
-       "same cover component as of window k" for every group-touched
-       lane, and complete, because a conflict arising at window k lives
-       in a sign-symmetric component whose touched members witness it);
-    3. ONE masked scatter pair commits the final assignment.
-
+    Returns ``(canon, failed after the group, r, nr_s, fail_s[k])``.
     Mid-group canons reconstruct lazily from ``(r, nr_k)`` via
     :class:`~gelly_streaming_tpu.summaries.forest.ForestReplay` (the
     cover id space is just a forest of 2*vcap nodes, so the CC replay
     applies verbatim); the input canon is NOT donated — the pre-group
     buffer backs the group's lazy emissions."""
-    key = ("superbatch", tcap, wcap, vcap, k)
-    fn = _COVER_STEP_CACHE.get(key)
-    if fn is not None:
-        return fn
 
-    tcap2, vcap2 = 2 * tcap, 2 * vcap
-
-    def step(canon, failed, tid, tmask, lu, lv, emask):
-        # cover touched bucket + per-window cover edges, derived
-        # in-graph from the base prep (lu/lv/emask are [k, wcap])
-        tid2 = jnp.concatenate([tid, tid + vcap])
-        tmask2 = jnp.concatenate([tmask, tmask])
-        lu2 = jnp.concatenate([lu, lu + tcap], axis=1)
-        lv2 = jnp.concatenate([lv + tcap, lv], axis=1)
-        emask2 = jnp.concatenate([emask, emask], axis=1)
-        r, v2, key_, iota = chase_and_group(canon, tid2, tmask2, tcap2, vcap2)
-        # v2 is a depth-1 min-rooted forest encoding the pre-group
-        # same-root constraints — already a valid label table seed
-        lab0 = v2
-
-        def body(c, xs):
-            lab, fail = c
-            lu_k, lv_k, em_k = xs
-            with jax.named_scope("forest.fixpoint"):
-                u = jnp.concatenate([lu_k, iota])
-                w = jnp.concatenate([lv_k, lab])
-                m = jnp.concatenate([em_k, jnp.ones(tcap2, bool)])
-                lab = _propagate(lab, u, w, m)
-            with jax.named_scope("forest.commit"):
-                nr = new_roots(lab, key_, tcap2)
-            with jax.named_scope("forest.latch"):
-                fail = fail | jnp.any(tmask & (nr[:tcap] == nr[tcap:]))
-            return (lab, fail), (nr, fail)
-
-        (_lab_end, fail_end), (nr_s, fail_s) = lax.scan(
-            body, (lab0, failed), (lu2, lv2, emask2)
+    def build():
+        body = group_body(
+            2 * tcap, 2 * vcap, _make_local_fixpoint(2 * tcap)
         )
-        with jax.named_scope("forest.commit"):
-            canon = reroot(canon, nr_s[-1], r, tid2, tmask2, vcap2)
-        return canon, fail_end, r, nr_s, fail_s
 
-    fn = jax.jit(step)
-    if len(_COVER_STEP_CACHE) >= _COVER_STEP_CACHE_MAX:
-        _COVER_STEP_CACHE.pop(next(iter(_COVER_STEP_CACHE)))
-    _COVER_STEP_CACHE[key] = fn
-    return fn
+        def step(canon, failed, tid, tmask, lu, lv, emask):
+            canon, r, nr_s = body(
+                canon, *_cover_lanes(tid, tmask, lu, lv, emask, tcap, vcap)
+            )
+            with jax.named_scope("forest.latch"):
+                fail_s = failed | lax.associative_scan(
+                    jnp.logical_or, _conflict(nr_s, tmask, tcap)
+                )
+            return canon, fail_s[-1], r, nr_s, fail_s
+
+        return jax.jit(step)
+
+    return cached_step(("cover-group", tcap, wcap, vcap, k), build)
 
 
 def cover_forest_superbatch(canon, failed, windows, vcap: int, prep):
     """Fold K windows (list of host base ``(src_h, dst_h)`` column
     pairs) into the cover forest as ONE fused group-local dispatch —
     the cover analog of :func:`~gelly_streaming_tpu.summaries.forest.forest_superbatch`,
-    sharing its host prep shape: one prep per window for the per-window
-    touched ids (the first-seen log advances in window order), one prep
-    over the concatenated columns for the group touched set + the
-    group-local renumbering.
+    over the same host prep (``forest.pad_group``).
 
     Returns ``(new_canon, new_failed, [touched_ids per window], replay,
     fail_stack)`` — ``replay`` is a cover-space
     :class:`~gelly_streaming_tpu.summaries.forest.ForestReplay` for lazy
     mid-group canon reconstruction, ``fail_stack`` the device ``[k]``
     per-window failure latches."""
-    from .forest import ForestReplay
-
-    if prep is None:
-        raise ValueError(
-            "cover_forest_superbatch requires a per-stream WindowPrep "
-            "(see forest_window)"
-        )
-    k = len(windows)
-    _e = np.zeros(0, np.int32)
-    win_tids = [
-        prep.prep(s, d, vcap)[0] if len(s) else _e for s, d in windows
-    ]
-    src_g = np.concatenate([s for s, _ in windows]) if k else _e
-    dst_g = np.concatenate([d for _, d in windows]) if k else _e
-    if len(src_g):
-        tids_g, lu_all, lv_all = prep.prep(src_g, dst_g, vcap)
-    else:
-        tids_g, lu_all, lv_all = _e, _e, _e
-    n_max = max((len(s) for s, _ in windows), default=0)
-    tcap = bucket_capacity(len(tids_g), minimum=8)
-    wcap = bucket_capacity(n_max, minimum=8)
-    t = len(tids_g)
-    tid = np.zeros(tcap, np.int32)
-    tid[:t] = tids_g
-    tmask = np.zeros(tcap, bool)
-    tmask[:t] = True
-    lu = np.zeros((k, wcap), np.int32)
-    lv = np.zeros((k, wcap), np.int32)
-    emask = np.zeros((k, wcap), bool)
-    off = 0
-    for i, (s, _) in enumerate(windows):
-        n = len(s)
-        lu[i, :n] = lu_all[off:off + n]
-        lv[i, :n] = lv_all[off:off + n]
-        emask[i, :n] = True
-        off += n
-    step = _cover_superbatch_fn(tcap, wcap, vcap, k)
+    win_tids, tcap, wcap, tid, tmask, lu, lv, lens = pad_group(
+        prep, windows, vcap
+    )
+    emask = np.arange(wcap) < lens[:, None]
+    step = _cover_superbatch_fn(tcap, wcap, vcap, len(windows))
     new_canon, new_failed, r_dev, nr_s, fail_s = step(
-        canon, failed,
-        jnp.asarray(tid), jnp.asarray(tmask),
-        jnp.asarray(lu), jnp.asarray(lv), jnp.asarray(emask),
+        canon, failed, *(jnp.asarray(c) for c in (tid, tmask, lu, lv, emask))
     )
     # the replay works in the 2*vcap cover id space: both cover halves
     # of the touched bucket, the chased old roots, the per-window
     # assignments — exactly the CC replay's contract
-    tid2 = np.concatenate([tid, tid + vcap])
-    tmask2 = np.concatenate([tmask, tmask])
-    replay = ForestReplay(canon, tid2, tmask2, r_dev, nr_s)
+    replay = ForestReplay(
+        canon, np.concatenate([tid, tid + vcap]),
+        np.concatenate([tmask, tmask]), r_dev, nr_s,
+    )
     return new_canon, new_failed, win_tids, replay, fail_s
 
 

@@ -1,14 +1,11 @@
-"""Window-local CC fold over a lazily-canonicalized forest carry.
+"""Window-local fold over a lazily-canonicalized forest carry: the one
+fold under connected components and the signed cover.
 
 The dense-label engine (``summaries/labels.py``) pays O(vcap) work per
 window — init_labels + full-table fixpoint + combine — even when the
 window touches <=2W vertices. That is the wrong cost shape vs the
 reference, whose per-partition fold touches only the window's edges
-(``SummaryBulkAggregation.java:76-80``); the honest CPU bracket measured
-it directly (BENCH_CPU r4: 0.45x the compiled baseline at 1M-edge
-windows, V-bound at scale 23).
-
-This module is the round-5 redesign: the carried summary becomes a
+(``SummaryBulkAggregation.java:76-80``). Here the carried summary is a
 **pointer forest** ``canon[vcap]`` (int32, ``canon[v] <= v``, acyclic by
 the strictly-decreasing min-root invariant) that is only *canonicalized*
 — chains collapsed to flat labels — at emission or checkpoint time.
@@ -17,7 +14,8 @@ Per window, every kernel is sized by the window, not the vertex space:
 1. The HOST computes the window's touched set beside the stream (unique
    endpoints of the cached pre-padding columns, order unspecified — the
    novelty-shadow pattern: zero device->host reads in the producer loop)
-   and renumbers the window's edges into local indices ``[0, T)``.
+   and renumbers the window's edges into local indices ``[0, T)``
+   (:func:`pad_window`; :func:`pad_group` for K windows in one dispatch).
 2. The DEVICE chases the touched vertices' pointers to their current
    roots (``chase_roots``: a ``lax.while_loop`` that carries each
    lane's next pointer, so a round costs ONE O(T) gather out of the
@@ -31,12 +29,22 @@ Per window, every kernel is sized by the window, not the vertex space:
 4. A pair of masked scatters re-roots the old roots (and the touched
    vertices, for path compression) to the merged component's min root.
 
-The remaining vcap-sized costs are bandwidth-only: the functional
-scatter's buffer copy (which is also what keeps per-window emissions
-valid snapshots — the pre-scatter buffer stays alive for any lazy
-emission holding it) and the step-2 scratch memset — two linear HBM
-passes per window instead of the dense path's ~10-20 full-table
-gather/scatter fixpoint passes.
+Steps 2 to 4 are written ONCE: :func:`window_body` (``chase_and_group``,
+the local fixpoint of :func:`_make_local_fixpoint`, ``commit_roots``)
+and, for K windows fused, :func:`group_body` (one chase, a scan of
+fixpoints, one re-rooting). CC's jitted programs are those bodies as
+they are. The signed cover's (``summaries/candidates.py``) are the same
+bodies over the ``2*vcap`` cover id space: the lanes doubled, an edge
+mask over its pad rows, and a conflict latch read off the new roots.
+All four programs live in one bounded cache (:func:`cached_step`).
+
+The remaining vcap-sized costs are the functional scatter's buffer copy
+(which is also what keeps per-window emissions valid snapshots — the
+pre-scatter buffer stays alive for any lazy emission holding it), the
+step-2 scratch and the three table-sized scatters. What each phase
+costs on the chip, per cell, is measured and kept in ``PERF.md``
+section 5 (the rates in ``PERF_LEDGER.jsonl``); no CPU timing says
+anything about it.
 
 Tracing (``obs/trace.py``): the device phases are ``jax.named_scope``s
 inside the jitted step, shared by the per-window and the superbatch
@@ -96,11 +104,24 @@ from .labels import _propagate
 
 _I32_MAX = jnp.iinfo(jnp.int32).max
 
-#: jitted per-(Tcap, Wcap, vcap, mesh, tree, degree) window steps;
-#: bounded FIFO like the engine's step cache (each signature costs
-#: seconds of compilation).
-_FOREST_STEP_CACHE: dict = {}
-_FOREST_STEP_CACHE_MAX = 32
+#: every jitted forest program of the process, CC's and the cover's, per
+#: window and per group: keyed ``(kind, tcap, wcap, vcap, ...)``; bounded
+#: FIFO like the engine's step cache (each signature costs seconds of
+#: compilation).
+_STEP_CACHE: dict = {}
+_STEP_CACHE_MAX = 32
+
+
+def cached_step(key, build):
+    """The jitted program under ``key``; built by ``build()`` and kept,
+    the oldest entry making room, if it is not there yet."""
+    fn = _STEP_CACHE.get(key)
+    if fn is None:
+        fn = build()
+        if len(_STEP_CACHE) >= _STEP_CACHE_MAX:
+            _STEP_CACHE.pop(next(iter(_STEP_CACHE)))
+        _STEP_CACHE[key] = fn
+    return fn
 
 
 def _table_combine(tcap: int):
@@ -327,37 +348,48 @@ def reroot(canon, nr, r, tid, tmask, vcap: int, tab: TableOps = None):
 
 def _make_local_fixpoint(tcap: int, mesh=None, tree: bool = False,
                          degree: int = 2):
-    """The T-sized local min-label fixpoint, shared by the per-window
-    step and the superbatch scan body: ``fixpoint(seed, lu, lv,
-    targets)`` folds the window's edge columns PLUS the pointer edges
-    ``(i, targets[i])`` (lu/lv pads are (0,0) self-loops, no mask
-    needed; the pointer edges must ride along as EDGES because
-    ``_propagate`` hooks only edge endpoints — the label_combine
-    correctness argument, labels.py). The per-window step seeds from
-    iota with the same-root group edges as targets; the superbatch scan
-    body seeds from (and targets) the carried group label table. Under
-    a mesh this is the engine's per-shard-fold + cross-shard-combine
-    shape on WINDOW-SIZED tables: each shard folds its slice of the
-    edge columns (the T-sized pointer edges replicate — same
-    constraints everywhere), then the label tables merge through the
-    bulk stack or the ppermute butterfly. The vcap-sized carry never
+    """The T-sized local min-label fixpoint of every forest program, CC's
+    and the cover's, per window and per group: ``fixpoint(seed, lu, lv,
+    targets, iota, emask=None)`` folds the window's edge columns PLUS
+    the pointer edges ``(iota[i], targets[i])`` (the pointer edges must
+    ride along as EDGES because ``_propagate`` hooks only edge endpoints
+    — the label_combine correctness argument, labels.py). With no
+    ``emask`` every row counts: lu/lv pads are (0,0) self-loops. A carry
+    whose id space gives those a meaning (the cover) masks its rows; the
+    pointer edges always count. The per-window body seeds from the lane
+    iota with the same-root group edges as targets; the group body seeds
+    from (and targets) the carried group label table.
+
+    ``iota`` is the lanes' own indices and is HANDED IN, because the two
+    carries' programs differ in it and a program whose text (and size)
+    changes moves where the table lies (ROADMAP S9, S14): CC's builders
+    pass one built on the host, a ``tcap``-sized literal of the program;
+    the cover's pass the traced one ``chase_and_group`` returns.
+
+    Under a mesh this is the engine's per-shard-fold +
+    cross-shard-combine shape on WINDOW-SIZED tables: each shard folds
+    its slice of the edge columns (the T-sized pointer edges replicate —
+    same constraints everywhere), then the label tables merge through
+    the bulk stack or the ppermute butterfly. The vcap-sized carry never
     crosses the mesh."""
     if mesh is not None:
         p = mesh.shape[EDGE_AXIS]
         combine = _table_combine(tcap)
 
-    iota = jnp.arange(tcap, dtype=jnp.int32)
-
-    def fixpoint(seed, lu, lv, targets):
-        if mesh is None:
-            u = jnp.concatenate([lu, iota])
-            w = jnp.concatenate([lv, targets])
-            return _propagate(seed, u, w, jnp.ones(u.shape[0], bool))
-
-        def shard_fn(lu_s, lv_s):
+    def fixpoint(seed, lu, lv, targets, iota, emask=None):
+        def fold(lu_s, lv_s, em_s=None):
             u = jnp.concatenate([lu_s, iota])
             w = jnp.concatenate([lv_s, targets])
-            lab = _propagate(seed, u, w, jnp.ones(u.shape[0], bool))
+            m = (jnp.ones(u.shape[0], bool) if em_s is None
+                 else jnp.concatenate([em_s, jnp.ones(tcap, bool)]))
+            return _propagate(seed, u, w, m)
+
+        cols = (lu, lv) if emask is None else (lu, lv, emask)
+        if mesh is None:
+            return fold(*cols)
+
+        def shard_fn(*cols_s):
+            lab = fold(*cols_s)
             if tree:
                 return comm.tree_all_reduce(
                     lab, EDGE_AXIS, combine, p, degree=degree
@@ -365,66 +397,60 @@ def _make_local_fixpoint(tcap: int, mesh=None, tree: bool = False,
             return lab[None]
 
         out = comm.shard_map(
-            shard_fn, mesh, (P(EDGE_AXIS), P(EDGE_AXIS)),
+            shard_fn, mesh, (P(EDGE_AXIS),) * len(cols),
             P() if tree else P(EDGE_AXIS),
-        )(lu, lv)
+        )(*cols)
         return out if tree else comm.stacked_reduce(out, p, combine)
 
     return fixpoint
 
 
-def _forest_step_fn(tcap: int, wcap: int, vcap: int, mesh=None,
-                    tree: bool = False, degree: int = 2):
-    key = (tcap, wcap, vcap, mesh, tree, degree)
-    fn = _FOREST_STEP_CACHE.get(key)
-    if fn is not None:
-        return fn
+def window_body(tcap: int, vcap: int, tab: TableOps, fixpoint, iota=None):
+    """THE forest fold of one window, over ``tab``'s layout of a
+    ``vcap``-row table: ``body(canon, tid, tmask, lu, lv, emask=None) ->
+    (canon, nr)`` is :func:`chase_and_group`, the local ``fixpoint``
+    (:func:`_make_local_fixpoint`; scope ``forest.fixpoint``) seeded
+    from the lane iota with the same-root group edges as targets, and
+    :func:`commit_roots`. CC's step is this body and returns ``canon``;
+    the cover's doubles the lanes, masks its pad rows and reads its
+    latch off ``nr`` (``candidates.py``). ``iota``: the pointer edges'
+    sources where they are not the traced lane iota (CC's host-built
+    literal: see :func:`_make_local_fixpoint`)."""
 
-    shards = vertex_layout(mesh)
-    tab = TableOps(vcap, shards)
-    # under the vertices layout the edges axis is 1 and every chip runs
-    # the window-sized fixpoint whole, as one chip does
-    fixpoint = _make_local_fixpoint(
-        tcap, mesh if shards == 1 else None, tree, degree
-    )
-
-    def step(canon, tid, tmask, lu, lv):
-        r, v2, key_, iota = chase_and_group(
+    def body(canon, tid, tmask, lu, lv, emask=None):
+        r, v2, key_, lanes = chase_and_group(
             canon, tid, tmask, tcap, vcap, tab
         )
         with jax.named_scope("forest.fixpoint"):
-            local = fixpoint(iota, lu, lv, v2)
-        canon, _nr = commit_roots(
+            local = fixpoint(
+                lanes, lu, lv, v2, lanes if iota is None else iota, emask
+            )
+        return commit_roots(
             canon, local, key_, r, tid, tmask, tcap, vcap, tab
         )
-        return canon
 
-    if shards > 1:
-        step = sharded_table_fn(step, mesh, 4, table_out=True)
-    fn = jax.jit(step)
-    if len(_FOREST_STEP_CACHE) >= _FOREST_STEP_CACHE_MAX:
-        _FOREST_STEP_CACHE.pop(next(iter(_FOREST_STEP_CACHE)))
-    _FOREST_STEP_CACHE[key] = fn
-    return fn
+    return body
 
 
-def _forest_superbatch_fn(tcap: int, wcap: int, vcap: int, k: int,
-                          mesh=None, tree: bool = False, degree: int = 2):
-    """K forest window-steps fused into one jitted dispatch, GROUP-LOCAL.
+def group_body(tcap: int, vcap: int, fixpoint, iota=None):
+    """THE forest fold of K windows in one dispatch, GROUP-LOCAL:
+    ``body(canon, tid, tmask, lu, lv, emask=None) -> (canon, r, nr_s)``
+    with ``lu, lv`` (and ``emask``) ``[k, wcap]`` over the GROUP's
+    touched lanes.
 
-    The naive fusion — scanning the per-window step with the vcap-sized
+    The naive fusion — scanning the per-window body with the vcap-sized
     canon as the carry — still pays vcap-sized work per window (XLA
     materializes carry updates, and the group-rep scratch memset is
     vcap-wide), which is exactly the cost shape the forest carry exists
-    to avoid. This kernel instead hoists ALL vcap-sized work to the
-    group boundary:
+    to avoid. This body instead hoists ALL vcap-sized work to the group
+    boundary:
 
     1. ONE root chase + same-root grouping over the group's union
        touched set (``chase_and_group`` — one vcap scratch memset per
        GROUP, not per window);
     2. a ``lax.scan`` over the K windows whose carry is only the
        T-sized local label table: window k folds its edge columns into
-       the carried table (seeded ``_propagate``) and emits
+       the carried table (the seeded ``fixpoint``) and emits
        ``nr_k[lane] = min pre-group root value of lane's merged group``
        — the per-window new-root assignment, [k, tcap];
     3. ONE masked scatter pair re-roots the old roots and
@@ -437,44 +463,85 @@ def _forest_superbatch_fn(tcap: int, wcap: int, vcap: int, k: int,
     :class:`ForestReplay` — value-identical under resolution to the
     per-window path's canon (pointer SHAPE may differ: the fused commit
     path-compresses the group's touched set once at the end, which
-    changes no root assignment).
+    changes no root assignment). The cover reads its per-window latches
+    off ``nr_s`` (``candidates.py``).
 
-    The input canon is NOT donated: the pre-group buffer backs the
-    group's lazy emissions — the one vcap-copy per GROUP replaces the
-    per-window path's copy per WINDOW.
-    """
-    key = ("superbatch", tcap, wcap, vcap, k, mesh, tree, degree)
-    fn = _FOREST_STEP_CACHE.get(key)
-    if fn is not None:
-        return fn
+    The callers do NOT donate the input canon: the pre-group buffer
+    backs the group's lazy emissions — the one vcap-copy per GROUP
+    replaces the per-window path's copy per WINDOW."""
 
-    vertex_layout(mesh, superbatch=True)
-    fixpoint = _make_local_fixpoint(tcap, mesh, tree, degree)
+    def body(canon, tid, tmask, lu, lv, emask=None):
+        r, v2, key_, lanes = chase_and_group(canon, tid, tmask, tcap, vcap)
+        src = lanes if iota is None else iota
 
-    def step(canon, tid, tmask, lu, lv):
-        r, v2, key_, iota = chase_and_group(canon, tid, tmask, tcap, vcap)
-        # v2 maps each lane to the MIN lane of its pre-group root group:
-        # a depth-1 min-rooted pointer forest, i.e. already a valid
-        # label table encoding the group constraints — no fixpoint needed
-        lab0 = v2
-
-        def body(lab, xs):
-            lu_k, lv_k = xs
+        def fold(lab, cols):
+            lu_k, lv_k, *em_k = cols
             with jax.named_scope("forest.fixpoint"):
-                lab = fixpoint(lab, lu_k, lv_k, lab)
+                lab = fixpoint(lab, lu_k, lv_k, lab, src, *em_k)
             with jax.named_scope("forest.commit"):
                 return lab, new_roots(lab, key_, tcap)
 
-        lab_end, nr_s = lax.scan(body, lab0, (lu, lv))
+        # v2 maps each lane to the MIN lane of its pre-group root group:
+        # a depth-1 min-rooted pointer forest, i.e. already a valid
+        # label table encoding the group constraints — the scan's seed
+        _lab_end, nr_s = lax.scan(
+            fold, v2, (lu, lv) if emask is None else (lu, lv, emask)
+        )
         with jax.named_scope("forest.commit"):
             canon = reroot(canon, nr_s[-1], r, tid, tmask, vcap)
         return canon, r, nr_s
 
-    fn = jax.jit(step)
-    if len(_FOREST_STEP_CACHE) >= _FOREST_STEP_CACHE_MAX:
-        _FOREST_STEP_CACHE.pop(next(iter(_FOREST_STEP_CACHE)))
-    _FOREST_STEP_CACHE[key] = fn
-    return fn
+    return body
+
+
+def _forest_step_fn(tcap: int, wcap: int, vcap: int, mesh=None,
+                    tree: bool = False, degree: int = 2):
+    """CC's jitted per-window program: :func:`window_body`, returning
+    the table (under ``shard_map`` when the table is split by rows)."""
+
+    def build():
+        shards = vertex_layout(mesh)
+        # under the vertices layout the edges axis is 1 and every chip
+        # runs the window-sized fixpoint whole, as one chip does
+        body = window_body(
+            tcap, vcap, TableOps(vcap, shards),
+            _make_local_fixpoint(
+                tcap, mesh if shards == 1 else None, tree, degree
+            ),
+            jnp.arange(tcap, dtype=jnp.int32),
+        )
+
+        def step(canon, tid, tmask, lu, lv):
+            return body(canon, tid, tmask, lu, lv)[0]
+
+        if shards > 1:
+            step = sharded_table_fn(step, mesh, 4, table_out=True)
+        return jax.jit(step)
+
+    return cached_step(("cc", tcap, wcap, vcap, mesh, tree, degree), build)
+
+
+def _forest_superbatch_fn(tcap: int, wcap: int, vcap: int, k: int,
+                          mesh=None, tree: bool = False, degree: int = 2):
+    """CC's jitted group program: :func:`group_body` as it is."""
+
+    def build():
+        vertex_layout(mesh, superbatch=True)
+        body = group_body(
+            tcap, vcap, _make_local_fixpoint(tcap, mesh, tree, degree),
+            jnp.arange(tcap, dtype=jnp.int32),
+        )
+
+        # a program is named after its callable, and every forest
+        # program is ``jit_step`` (the device trace's readers look for it)
+        def step(canon, tid, tmask, lu, lv):
+            return body(canon, tid, tmask, lu, lv)
+
+        return jax.jit(step)
+
+    return cached_step(
+        ("cc-group", tcap, wcap, vcap, k, mesh, tree, degree), build
+    )
 
 
 def _own_rows(rows: int):
@@ -567,6 +634,18 @@ class WindowPrep:
         return tids, self.lut[src_h], self.lut[dst_h]
 
 
+def _touched_bucket(tids):
+    """``(tcap, tid, tmask)``: touched ids in their pow2 bucket, the
+    pad lanes masked."""
+    t = len(tids)
+    tcap = bucket_capacity(t, minimum=8)
+    tid = np.zeros(tcap, np.int32)
+    tid[:t] = tids
+    tmask = np.zeros(tcap, bool)
+    tmask[:t] = True
+    return tcap, tid, tmask
+
+
 def pad_window(prep, src_h, dst_h, vcap: int, wmin: int = 8):
     """Shared host prep + pow2 bucket padding for the window-local steps
     (CC forest + signed-cover): returns ``(tids, tcap, wcap, tid, tmask,
@@ -576,18 +655,55 @@ def pad_window(prep, src_h, dst_h, vcap: int, wmin: int = 8):
     n = len(src_h)
     with _trace.span("forest.prep"):
         tids, lu_r, lv_r = prep.prep(src_h, dst_h, vcap)
-        t = len(tids)
-        tcap = bucket_capacity(t, minimum=8)
+        tcap, tid, tmask = _touched_bucket(tids)
         wcap = bucket_capacity(n, minimum=wmin)
-        tid = np.zeros(tcap, np.int32)
-        tid[:t] = tids
-        tmask = np.zeros(tcap, bool)
-        tmask[:t] = True
         lu = np.zeros(wcap, np.int32)
         lv = np.zeros(wcap, np.int32)
         lu[:n] = lu_r
         lv[:n] = lv_r
     return tids, tcap, wcap, tid, tmask, lu, lv
+
+
+def pad_group(prep, windows, vcap: int, wmin: int = 8):
+    """:func:`pad_window` for a GROUP of K windows (``(src_h, dst_h)``
+    host column pairs) folded in one dispatch, shared by CC's and the
+    cover's group drivers: returns ``(win_tids, tcap, wcap, tid, tmask,
+    lu, lv, lens)``. Two prep passes through the same per-stream
+    :class:`WindowPrep` scratch: (a) one per window for the PER-WINDOW
+    touched ids ``win_tids`` (the first-seen log advances in window
+    order), (b) one over the group's concatenated columns for the GROUP
+    touched set ``tid, tmask`` and the group-local edge renumbering
+    ``lu, lv`` ``[k, wcap]`` — the lane space the device scan's carried
+    label table lives in. All K windows pad to the group's bucketed
+    caps, so a stream hits O(log^2 x distinct-k) jit signatures;
+    padding lanes are inert in every kernel (pads chase from 0 and
+    scatter-drop) and pad rows are (0,0) self-loops, which the cover
+    masks by the windows' lengths ``lens``."""
+    if prep is None:
+        raise ValueError(
+            "a group fold requires a per-stream WindowPrep (see "
+            "forest_window)"
+        )
+    _e = np.zeros(0, np.int32)
+    win_tids = [
+        prep.prep(s, d, vcap)[0] if len(s) else _e for s, d in windows
+    ]
+    lens = np.asarray([len(s) for s, _ in windows], np.int64)
+    tids_g, lu_all, lv_all = _e, _e, _e
+    if lens.sum():
+        tids_g, lu_all, lv_all = prep.prep(
+            np.concatenate([s for s, _ in windows]),
+            np.concatenate([d for _, d in windows]), vcap,
+        )
+    tcap, tid, tmask = _touched_bucket(tids_g)
+    wcap = bucket_capacity(int(lens.max(initial=0)), minimum=wmin)
+    # the concatenated columns back into their windows' rows
+    rows = np.arange(wcap) < lens[:, None]
+    lu = np.zeros(rows.shape, np.int32)
+    lv = np.zeros(rows.shape, np.int32)
+    lu[rows] = lu_all
+    lv[rows] = lv_all
+    return win_tids, tcap, wcap, tid, tmask, lu, lv, lens
 
 
 def window_span(n: int):
@@ -722,71 +838,25 @@ def forest_superbatch(
     degree: int = 2,
 ) -> Tuple[jax.Array, list, "ForestReplay"]:
     """Fold K windows (list of host ``(src_h, dst_h)`` column pairs)
-    into the forest as ONE fused group-local dispatch.
-
-    Host side, two prep passes through the same per-stream
-    :class:`WindowPrep` scratch: (a) one prep per window for the
-    PER-WINDOW touched ids (the first-seen log advances in window
-    order), (b) one prep over the group's concatenated columns for the
-    GROUP touched set and the group-local edge renumbering — the lane
-    space the device scan's carried label table lives in. All K windows
-    pad to the group's bucketed caps, so a stream hits
-    O(log^2 x distinct-k) jit signatures; padding lanes are inert in
-    every kernel (pads chase from 0 and scatter-drop).
+    into the forest as ONE fused group-local dispatch
+    (:func:`pad_group`, then :func:`group_body`).
 
     Returns ``(new_canon, [touched_ids per window], replay)`` — the
     caller feeds ``touched_ids`` to its first-seen log in window order
     and hands ``replay`` to the group's lazy emissions.
     """
-    if prep is None:
-        raise ValueError(
-            "forest_superbatch requires a per-stream WindowPrep (see "
-            "forest_window)"
-        )
-    k = len(windows)
-    _e = np.zeros(0, np.int32)
-    # (a) per-window touched ids, in window order, for the TouchLog
-    win_tids = [
-        prep.prep(s, d, vcap)[0] if len(s) else _e for s, d in windows
-    ]
-    # (b) group touched set + group-local renumbering in ONE pass
-    src_g = np.concatenate([s for s, _ in windows]) if k else _e
-    dst_g = np.concatenate([d for _, d in windows]) if k else _e
-    if len(src_g):
-        tids_g, lu_all, lv_all = prep.prep(src_g, dst_g, vcap)
-    else:
-        tids_g, lu_all, lv_all = _e, _e, _e
-    n_max = max((len(s) for s, _ in windows), default=0)
-    wmin = 8
-    if mesh is not None:
-        from ..parallel.mesh import EDGE_AXIS
-
-        wmin = max(wmin, mesh.shape[EDGE_AXIS])
-    tcap = bucket_capacity(len(tids_g), minimum=8)
-    wcap = bucket_capacity(n_max, minimum=wmin)
-    t = len(tids_g)
-    tid = np.zeros(tcap, np.int32)
-    tid[:t] = tids_g
-    tmask = np.zeros(tcap, bool)
-    tmask[:t] = True
-    lu = np.zeros((k, wcap), np.int32)
-    lv = np.zeros((k, wcap), np.int32)
-    off = 0
-    for i, (s, _) in enumerate(windows):
-        n = len(s)
-        lu[i, :n] = lu_all[off:off + n]
-        lv[i, :n] = lv_all[off:off + n]
-        off += n
-    step = _forest_superbatch_fn(tcap, wcap, vcap, k, mesh, tree, degree)
-    new_canon, r_dev, nr_s = step(
-        canon,
-        jnp.asarray(tid),
-        jnp.asarray(tmask),
-        jnp.asarray(lu),
-        jnp.asarray(lv),
+    # the sharded columns must divide by the axis size (forest_window)
+    wmin = 8 if mesh is None else max(8, mesh.shape[EDGE_AXIS])
+    win_tids, tcap, wcap, tid, tmask, lu, lv, _lens = pad_group(
+        prep, windows, vcap, wmin
     )
-    replay = ForestReplay(canon, tid, tmask, r_dev, nr_s)
-    return new_canon, win_tids, replay
+    step = _forest_superbatch_fn(
+        tcap, wcap, vcap, len(windows), mesh, tree, degree
+    )
+    new_canon, r_dev, nr_s = step(
+        canon, *(jnp.asarray(c) for c in (tid, tmask, lu, lv))
+    )
+    return new_canon, win_tids, ForestReplay(canon, tid, tmask, r_dev, nr_s)
 
 
 class MirrorReplay:

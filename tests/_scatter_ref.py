@@ -23,8 +23,7 @@ def plain_scatter(self, table, idx, val, op: str = "set"):
 
 
 def _clear_steps():
-    forest._FOREST_STEP_CACHE.clear()
-    candidates._COVER_STEP_CACHE.clear()
+    forest._STEP_CACHE.clear()
 
 
 @pytest.fixture

@@ -169,13 +169,11 @@ def former_steps(monkeypatch):
     """-> a context that builds the steps over the former loop."""
     def swap():
         monkeypatch.setattr(forest, "chase_roots", _former_chase)
-        forest._FOREST_STEP_CACHE.clear()
-        candidates._COVER_STEP_CACHE.clear()
+        forest._STEP_CACHE.clear()
 
     yield swap
     monkeypatch.undo()
-    forest._FOREST_STEP_CACHE.clear()
-    candidates._COVER_STEP_CACHE.clear()
+    forest._STEP_CACHE.clear()
 
 
 def _windows(seed: int, vcap: int, n: int, size: int, bipartite: bool):

@@ -491,3 +491,116 @@ def _lowered_step(which: str) -> str:
 @pytest.mark.parametrize("which", ["cc-step", "cover-step"])
 def test_the_lowered_step_says_its_table_scatters_are_sorted(which):
     assert_table_scatters_go_out_sorted(_lowered_step(which))
+
+
+# --------------------------------------------------------------------- #
+# The forest fold is written once (ISSUE 32): one group prep under CC's
+# and the cover's group drivers, one cache under all four programs
+# --------------------------------------------------------------------- #
+def _ragged_windows(seed: int, sizes, vcap: int = 1 << 9):
+    """Windows of the given lengths (an empty one among them is legal);
+    sources even and targets odd, so the cover stays bipartite."""
+    rng = np.random.default_rng(seed)
+    return [((rng.integers(0, vcap, n) & ~1).astype(np.int32),
+             (rng.integers(0, vcap, n) | 1).astype(np.int32))
+            for n in sizes], vcap
+
+
+@pytest.mark.parametrize("n", [1, 7, 64, 200])
+def test_pad_group_of_one_window_gives_pad_windows_lanes(n):
+    from gelly_streaming_tpu.summaries import forest
+
+    ((s, d),), vcap = _ragged_windows(n, [n])
+    prep = forest.WindowPrep()
+    tids, tcap, wcap, tid, tmask, lu, lv = forest.pad_window(prep, s, d, vcap)
+    (win_tids, g_tcap, g_wcap, g_tid, g_tmask, g_lu, g_lv,
+     lens) = forest.pad_group(prep, [(s, d)], vcap)
+    assert (g_tcap, g_wcap, lens.tolist()) == (tcap, wcap, [n])
+    np.testing.assert_array_equal(win_tids[0], tids)
+    for got, want in ((g_tid, tid), (g_tmask, tmask),
+                      (g_lu, lu[None]), (g_lv, lv[None])):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("sizes", [(64, 64, 64), (5, 0, 33, 64, 1), (0, 0)])
+def test_cc_and_cover_group_drivers_hand_the_device_the_same_lanes(
+        sizes, monkeypatch):
+    """Both drivers go through ``pad_group``: the same ``tid, tmask, lu,
+    lv`` for the same windows (the cover adds its mask of the pad rows),
+    and the lanes say the windows' own edges."""
+    import jax.numpy as jnp
+
+    from gelly_streaming_tpu.summaries import candidates, forest
+
+    windows, vcap = _ragged_windows(len(sizes), sizes)
+    seen = {}
+
+    def recording(name, n_lead, builder):
+        def build(*shape, **kw):
+            step = builder(*shape, **kw)
+
+            def run(*args):
+                seen[name] = (shape[:4],
+                              [np.asarray(a) for a in args[n_lead:]])
+                return step(*args)
+            return run
+        return build
+
+    monkeypatch.setattr(forest, "_forest_superbatch_fn", recording(
+        "cc", 1, forest._forest_superbatch_fn))
+    monkeypatch.setattr(candidates, "_cover_superbatch_fn", recording(
+        "cover", 2, candidates._cover_superbatch_fn))
+    prep = forest.WindowPrep()
+    _c, cc_tids, _r = forest.forest_superbatch(
+        forest.init_forest(vcap), windows, vcap, prep)
+    _c, failed, cover_tids, _r, fail_s = candidates.cover_forest_superbatch(
+        forest.init_forest(2 * vcap), jnp.bool_(False), windows, vcap, prep)
+    assert not bool(failed) and not np.asarray(fail_s).any()
+
+    (cc_shape, cc_lanes), (cover_shape, cover_lanes) = seen["cc"], seen["cover"]
+    assert cc_shape == cover_shape == (*cc_shape[:3], len(sizes))
+    for a, b in zip(cc_lanes, cover_lanes[:4]):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(a, b)
+    tid, tmask, lu, lv = cc_lanes
+    emask = cover_lanes[4]
+    assert emask.sum(axis=1).tolist() == list(sizes)
+    for k, (s, d) in enumerate(windows):
+        n = len(s)
+        assert emask[k, :n].all() and not lu[k, n:].any() and not lv[k, n:].any()
+        assert tmask[lu[k, :n]].all() and tmask[lv[k, :n]].all()
+        np.testing.assert_array_equal(tid[lu[k, :n]], s)
+        np.testing.assert_array_equal(tid[lv[k, :n]], d)
+        for tids in (cc_tids[k], cover_tids[k]):
+            assert sorted(tids.tolist()) == sorted(set(s) | set(d))
+    touched = np.unique(np.concatenate([np.concatenate(w) for w in windows]))
+    assert sorted(tid[tmask].tolist()) == touched.tolist()
+
+
+def test_the_one_step_cache_stays_bounded_under_cc_and_cover_together(
+        monkeypatch):
+    """All four programs live in ``forest._STEP_CACHE``: FIFO, bounded,
+    and a CC and a cover program of one shape are two entries."""
+    from gelly_streaming_tpu.summaries import candidates, forest
+
+    monkeypatch.setattr(forest, "_STEP_CACHE", {})
+    monkeypatch.setattr(forest, "_STEP_CACHE_MAX", 6)
+    builders = [
+        lambda t: forest._forest_step_fn(t, 8, 64),
+        lambda t: candidates._cover_step_fn(t, 8, 64),
+        lambda t: forest._forest_superbatch_fn(t, 8, 64, 2),
+        lambda t: candidates._cover_superbatch_fn(t, 8, 64, 2),
+    ]
+    first = [b(8) for b in builders]
+    assert len({id(f) for f in first}) == 4 and len(forest._STEP_CACHE) == 4
+    assert [b(8) for b in builders] == first  # found again, not rebuilt
+    for tcap in (16, 32, 64):
+        for b in builders:
+            b(tcap)
+            assert len(forest._STEP_CACHE) <= 6
+    kinds = {key[0] for key in forest._STEP_CACHE}
+    assert kinds == {"cc", "cover", "cc-group", "cover-group"}
+    assert [key[1] for key in forest._STEP_CACHE] == [32, 32] + [64] * 4
+    assert builders[0](8) is not first[0]  # evicted long ago: built anew
+    assert not hasattr(candidates, "_COVER_STEP_CACHE")

@@ -297,8 +297,8 @@ def test_n_seen_per_window_matches_live_dict():
 # --------------------------------------------------------------------- #
 # Bipartiteness: cover group fold
 # --------------------------------------------------------------------- #
-def _bp_run(edges, k):
-    agg = BipartitenessCheck(superbatch=k)
+def _bp_run(edges, k, carry="auto"):
+    agg = BipartitenessCheck(superbatch=k, carry=carry)
     out = [str(c) for c in agg.run(_stream(edges))]
     return out, agg
 
@@ -313,15 +313,20 @@ def test_bipartiteness_out_of_order_reads():
         assert str(ems[i]) == base[i], f"window {i}"
 
 
-def test_bipartiteness_verdict_flip_mid_group():
+@pytest.mark.parametrize("carry", ["forest", "host"])
+def test_bipartiteness_verdict_flip_mid_group(carry):
     """The per-window failure latch must flip at the SAME window the
-    per-window path flips, even when the odd cycle lands mid-group."""
+    per-window path flips, even when the odd cycle lands mid-group: on
+    the forest carry (the group step reads window k's latch off the
+    scan's assignments up to k) and on the host carry alike."""
+    if carry == "host" and not _have_native():
+        pytest.skip("native toolchain unavailable")
     edges = _bip_edges(11, n=200)
     # inject an odd triangle late, mid-way through a k=8 group
     edges = edges[:130] + [(0, 1, 0.0), (1, 2, 0.0), (2, 0, 0.0)] + edges[130:]
-    base, _ = _bp_run(edges, 1)
-    got, agg = _bp_run(edges, 8)
-    assert agg._bp_mode in ("forest", "host")
+    base, _ = _bp_run(edges, 1, carry)
+    got, agg = _bp_run(edges, 8, carry)
+    assert agg._bp_mode == carry
     assert got == base
     flips = [i for i, s in enumerate(base) if s == "(false,{})"]
     assert flips and flips[0] > 0  # the stream really was bipartite first
