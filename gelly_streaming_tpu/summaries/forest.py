@@ -22,10 +22,14 @@ Per window, every kernel is sized by the window, not the vertex space:
    table and the loop's condition none; chains only pass through
    former roots, and touched vertices are fully path-compressed every
    window).
-3. A min-label fixpoint over the **local** T-sized table joins the
-   window's edges with "same current root" chain constraints (from one
-   scatter-min into a vcap-sized scratch), exactly the dense kernel's
-   hook+shortcut but on a table the size of the window.
+3. One scatter-min into a vcap-sized scratch gives every touched lane
+   the representative (min lane) of its "same current root" group. The
+   window's edges are relabelled through it, once, and a min-label
+   fixpoint over the **local** T-sized table (exactly the dense
+   kernel's hook+shortcut, on a table the size of the window) joins the
+   representatives: connected components of the window's quotient
+   graph, W lanes a round. Every lane reads its label back through its
+   representative (:func:`_make_local_fixpoint`).
 4. A pair of masked scatters re-roots the old roots (and the touched
    vertices, for path compression) to the merged component's min root.
 
@@ -50,9 +54,11 @@ Tracing (``obs/trace.py``): the device phases are ``jax.named_scope``s
 inside the jitted step, shared by the per-window and the superbatch
 steps of both carries (CC and the signed cover) — ``forest.chase``
 (step 2's pointer chase), ``forest.group`` (its vcap-sized same-root
-scratch), ``forest.fixpoint`` (step 3), ``forest.commit`` (step 4),
-inside group and commit ``forest.sort`` (the sort ahead of each
-table-sized scatter) and, on the cover, ``forest.latch``. A device
+scratch), ``forest.fixpoint`` (step 3) and inside it
+``forest.contract`` (its once-a-step part: the endpoints relabelled,
+the labels read back), ``forest.commit`` (step 4), inside group and
+commit ``forest.sort`` (the sort ahead of each table-sized scatter)
+and, on the cover, ``forest.latch``. A device
 trace carries the scope in
 the ``tf_op`` stat of each ``XLA Ops`` event's metadata; the jitted
 programs keep the name ``jit_step``. On the host one window is the span ``forest.window`` with
@@ -289,10 +295,11 @@ def chase_and_group(canon, tid, tmask, tcap: int, vcap: int,
        its group's representative lane — a memset, a scatter and a
        gather. The scatter's lanes are sorted by root first
        (:meth:`TableOps.scatter`: 17 against 91 ns a lane on the chip).
-       Edge (i, rep_i) unifies the group; pads self-loop.
+       ``rep_i`` stands for lane i's whole group; pads self-loop.
 
-    Returns ``(r, v2, key_, iota)``: current roots per lane, the group-
-    edge targets, the root-value keys (+inf on pads), and the lane iota.
+    Returns ``(r, v2, key_, iota)``: current roots per lane, each
+    lane's group's representative (a depth-1 forest: ``v2[v2] == v2``),
+    the root-value keys (+inf on pads), and the lane iota.
     ``tab`` is the table's layout (default: whole, on one chip); the
     scratch is laid out like the table.
     """
@@ -350,39 +357,48 @@ def _make_local_fixpoint(tcap: int, mesh=None, tree: bool = False,
                          degree: int = 2):
     """The T-sized local min-label fixpoint of every forest program, CC's
     and the cover's, per window and per group: ``fixpoint(seed, lu, lv,
-    targets, iota, emask=None)`` folds the window's edge columns PLUS
-    the pointer edges ``(iota[i], targets[i])`` (the pointer edges must
-    ride along as EDGES because ``_propagate`` hooks only edge endpoints
-    — the label_combine correctness argument, labels.py). With no
-    ``emask`` every row counts: lu/lv pads are (0,0) self-loops. A carry
-    whose id space gives those a meaning (the cover) masks its rows; the
-    pointer edges always count. The per-window body seeds from the lane
-    iota with the same-root group edges as targets; the group body seeds
-    from (and targets) the carried group label table.
+    targets, emask=None)`` is connected components of the window's
+    QUOTIENT graph. ``targets`` maps every lane to the representative of
+    its same-root group, so the groups are folded into their
+    representatives once, before the rounds (scope ``forest.contract``):
+    the edge rows are relabelled ``(targets[lu], targets[lv])``,
+    ``_propagate`` runs over those ``wcap`` rows alone, and every lane
+    reads its label back through its representative, ``lab[targets]``.
+    The same partition and the same label on every lane as with the
+    pointer edges ``(i, targets[i])`` carried through every round as
+    edges, at ``wcap`` lanes a round and not ``wcap + tcap``, and in no
+    more rounds (group mates are one node from the start).
 
-    ``iota`` is the lanes' own indices and is HANDED IN, because the two
-    carries' programs differ in it and a program whose text (and size)
-    changes moves where the table lies (ROADMAP S9, S14): CC's builders
-    pass one built on the host, a ``tcap``-sized literal of the program;
-    the cover's pass the traced one ``chase_and_group`` returns.
+    PRECONDITION, met by both callers and not checked in the jitted
+    step: ``targets[targets] == targets`` (a depth-1 forest), and
+    ``seed[targets[i]]`` is the label lane ``i`` enters with. The
+    per-window body seeds from the lane iota and targets each group's
+    min lane (pads self-loop); the group body seeds from, and targets,
+    the carried label table, flat at convergence.
+
+    With no ``emask`` every row counts: lu/lv pads are (0,0) self-loops.
+    A carry whose id space gives those a meaning (the cover) masks its
+    rows, and a masked row stays masked whatever its relabelled
+    endpoints are.
 
     Under a mesh this is the engine's per-shard-fold +
     cross-shard-combine shape on WINDOW-SIZED tables: each shard folds
-    its slice of the edge columns (the T-sized pointer edges replicate —
-    same constraints everywhere), then the label tables merge through
-    the bulk stack or the ppermute butterfly. The vcap-sized carry never
+    its slice of the edge columns (``targets`` replicates) and expands
+    to a whole label table, then the label tables merge through the
+    bulk stack or the ppermute butterfly. The vcap-sized carry never
     crosses the mesh."""
     if mesh is not None:
         p = mesh.shape[EDGE_AXIS]
         combine = _table_combine(tcap)
 
-    def fixpoint(seed, lu, lv, targets, iota, emask=None):
+    def fixpoint(seed, lu, lv, targets, emask=None):
         def fold(lu_s, lv_s, em_s=None):
-            u = jnp.concatenate([lu_s, iota])
-            w = jnp.concatenate([lv_s, targets])
-            m = (jnp.ones(u.shape[0], bool) if em_s is None
-                 else jnp.concatenate([em_s, jnp.ones(tcap, bool)]))
-            return _propagate(seed, u, w, m)
+            with jax.named_scope("forest.contract"):
+                u, w = targets[lu_s], targets[lv_s]
+            m = jnp.ones(u.shape[0], bool) if em_s is None else em_s
+            lab = _propagate(seed, u, w, m)
+            with jax.named_scope("forest.contract"):
+                return lab[targets]
 
         cols = (lu, lv) if emask is None else (lu, lv, emask)
         if mesh is None:
@@ -405,26 +421,22 @@ def _make_local_fixpoint(tcap: int, mesh=None, tree: bool = False,
     return fixpoint
 
 
-def window_body(tcap: int, vcap: int, tab: TableOps, fixpoint, iota=None):
+def window_body(tcap: int, vcap: int, tab: TableOps, fixpoint):
     """THE forest fold of one window, over ``tab``'s layout of a
     ``vcap``-row table: ``body(canon, tid, tmask, lu, lv, emask=None) ->
     (canon, nr)`` is :func:`chase_and_group`, the local ``fixpoint``
     (:func:`_make_local_fixpoint`; scope ``forest.fixpoint``) seeded
-    from the lane iota with the same-root group edges as targets, and
-    :func:`commit_roots`. CC's step is this body and returns ``canon``;
-    the cover's doubles the lanes, masks its pad rows and reads its
-    latch off ``nr`` (``candidates.py``). ``iota``: the pointer edges'
-    sources where they are not the traced lane iota (CC's host-built
-    literal: see :func:`_make_local_fixpoint`)."""
+    from the lane iota with each lane's same-root group's min lane as
+    targets, and :func:`commit_roots`. CC's step is this body and
+    returns ``canon``; the cover's doubles the lanes, masks its pad rows
+    and reads its latch off ``nr`` (``candidates.py``)."""
 
     def body(canon, tid, tmask, lu, lv, emask=None):
         r, v2, key_, lanes = chase_and_group(
             canon, tid, tmask, tcap, vcap, tab
         )
         with jax.named_scope("forest.fixpoint"):
-            local = fixpoint(
-                lanes, lu, lv, v2, lanes if iota is None else iota, emask
-            )
+            local = fixpoint(lanes, lu, lv, v2, emask)
         return commit_roots(
             canon, local, key_, r, tid, tmask, tcap, vcap, tab
         )
@@ -432,7 +444,7 @@ def window_body(tcap: int, vcap: int, tab: TableOps, fixpoint, iota=None):
     return body
 
 
-def group_body(tcap: int, vcap: int, fixpoint, iota=None):
+def group_body(tcap: int, vcap: int, fixpoint):
     """THE forest fold of K windows in one dispatch, GROUP-LOCAL:
     ``body(canon, tid, tmask, lu, lv, emask=None) -> (canon, r, nr_s)``
     with ``lu, lv`` (and ``emask``) ``[k, wcap]`` over the GROUP's
@@ -471,13 +483,12 @@ def group_body(tcap: int, vcap: int, fixpoint, iota=None):
     replaces the per-window path's copy per WINDOW."""
 
     def body(canon, tid, tmask, lu, lv, emask=None):
-        r, v2, key_, lanes = chase_and_group(canon, tid, tmask, tcap, vcap)
-        src = lanes if iota is None else iota
+        r, v2, key_, _lanes = chase_and_group(canon, tid, tmask, tcap, vcap)
 
         def fold(lab, cols):
             lu_k, lv_k, *em_k = cols
             with jax.named_scope("forest.fixpoint"):
-                lab = fixpoint(lab, lu_k, lv_k, lab, src, *em_k)
+                lab = fixpoint(lab, lu_k, lv_k, lab, *em_k)
             with jax.named_scope("forest.commit"):
                 return lab, new_roots(lab, key_, tcap)
 
@@ -508,7 +519,6 @@ def _forest_step_fn(tcap: int, wcap: int, vcap: int, mesh=None,
             _make_local_fixpoint(
                 tcap, mesh if shards == 1 else None, tree, degree
             ),
-            jnp.arange(tcap, dtype=jnp.int32),
         )
 
         def step(canon, tid, tmask, lu, lv):
@@ -528,8 +538,7 @@ def _forest_superbatch_fn(tcap: int, wcap: int, vcap: int, k: int,
     def build():
         vertex_layout(mesh, superbatch=True)
         body = group_body(
-            tcap, vcap, _make_local_fixpoint(tcap, mesh, tree, degree),
-            jnp.arange(tcap, dtype=jnp.int32),
+            tcap, vcap, _make_local_fixpoint(tcap, mesh, tree, degree)
         )
 
         # a program is named after its callable, and every forest

@@ -51,13 +51,14 @@ def kronecker_windows(seed: int, scale: int, n: int, size: int,
         yield src[cut].astype(np.int32), dst[cut].astype(np.int32)
 
 
-def cc_tables(seed: int, scale: int = 12, mesh=None) -> list:
-    """The CC forest after each of ten Kronecker windows, every row."""
+def cc_tables(seed: int, scale: int = 12, mesh=None, **fold) -> list:
+    """The CC forest after each of ten Kronecker windows, every row
+    (``fold``: ``forest_window``'s ``tree`` and ``degree``)."""
     vcap = 1 << scale
     canon, prep, out = forest.init_forest(vcap, mesh), forest.WindowPrep(), []
     for s, d in kronecker_windows(seed, scale, 10, 512):
         canon, _tids = forest.forest_window(canon, s, d, vcap, prep,
-                                            mesh=mesh)
+                                            mesh=mesh, **fold)
         out.append(np.asarray(canon))
     return out
 
@@ -79,19 +80,34 @@ _LOC_DEF = re.compile(r'^(#loc\d+) = loc\("([^"]*)"')
 _LOC_USE = re.compile(r"loc\((#loc\d+)\)\s*$")
 
 
-def scoped_ops(text: str, name: str) -> list:
-    """``(op_name path, the op's own line)`` of every ``stablehlo.<name>``
-    in ``lowered.as_text(debug_info=True)``. An op with a region carries
-    its location on the line that closes the region."""
+def _scoped(text: str, name: str):
+    """``(op_name path, the op's own line, the line with its types)`` of
+    every ``stablehlo.<name>`` in ``lowered.as_text(debug_info=True)``.
+    An op with a region carries its types and its location on the line
+    that closes the region."""
     lines = text.splitlines()
     paths = dict(m.groups() for m in map(_LOC_DEF.match, lines) if m)
-    out = []
     for i, ln in enumerate(lines):
         if f'"stablehlo.{name}"' not in ln:
             continue
         end = next(x for x in lines[i:] if _LOC_USE.search(x)
                    and (x is ln or x.lstrip().startswith("})")))
-        out.append((paths[_LOC_USE.search(end).group(1)], ln))
+        yield paths[_LOC_USE.search(end).group(1)], ln, end
+
+
+def scoped_ops(text: str, name: str) -> list:
+    """``(op_name path, the op's own line)`` of every ``stablehlo.<name>``."""
+    return [(path, ln) for path, ln, _end in _scoped(text, name)]
+
+
+def scoped_lanes(text: str, name: str) -> list:
+    """``(op_name path, lanes)`` of every ``stablehlo.<name>``: the
+    leading dimension of its last operand, which is a gather's indices
+    and a scatter's updates."""
+    out = []
+    for path, _ln, end in _scoped(text, name):
+        operands = end[end.rindex(" : (") + 4:end.rindex(") -> ")]
+        out.append((path, int(re.findall(r"tensor<(\d+)", operands)[-1])))
     return out
 
 

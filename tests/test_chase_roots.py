@@ -261,7 +261,11 @@ _STEP = {"forest_step_ms.sat": 96.0, "forest_chase_ms.sat": 17.0,
     ({"forest_chase_rounds.sat": 0.0, "forest_fixpoint_rounds.sat": 7.0},
      {"fixpoint_ms_per_round": 5.0}),       # every lane met its root
     ({}, {}),                                # a program without the scopes
-], ids=["both-loops", "no-chase-trip", "no-rounds-read"])
+    # the fixpoint's contraction runs once a step: out before dividing
+    ({"forest_chase_rounds.sat": 5.0, "forest_fixpoint_rounds.sat": 7.0,
+      "forest_contract_ms.sat": 2.1},
+     {"chase_ms_per_round": 3.4, "fixpoint_ms_per_round": 4.7}),
+], ids=["both-loops", "no-chase-trip", "no-rounds-read", "contraction"])
 def test_phases_block_gives_ms_per_round_of_each_loop(rounds, want):
     got = _phases({**_STEP, **rounds})
     assert got["sum_ms"] == pytest.approx(93.0)
@@ -270,10 +274,11 @@ def test_phases_block_gives_ms_per_round_of_each_loop(rounds, want):
     assert per_round == pytest.approx(want)
 
 
-@pytest.mark.parametrize("nested", ["sort", "exchange"])
+@pytest.mark.parametrize("nested", ["sort", "exchange", "contract"])
 def test_phases_block_keeps_a_nested_scope_beside_the_sum(nested):
     """``forest.sort`` runs inside group and commit, ``forest.exchange``
-    inside chase and group: read, and not added a second time."""
+    inside chase and group, ``forest.contract`` inside the fixpoint:
+    read, and not added a second time."""
     got = _phases({**_STEP, f"forest_{nested}_ms.sat": 0.24})
     assert got["sum_ms"] == pytest.approx(93.0)
     assert got[f"{nested}_ms"] == pytest.approx(0.24)

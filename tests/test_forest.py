@@ -4,6 +4,8 @@ correctness, snapshot isolation, and adversarial chain growth. Every
 test runs against BOTH windowed carries — the device forest kernels and
 the native host union-find with its device mirror."""
 
+import re
+
 import numpy as np
 import pytest
 
@@ -15,6 +17,7 @@ from _scatter_ref import (  # noqa: F401  (unsorted_steps is a fixture)
     assert_table_scatters_go_out_sorted,
     cc_tables,
     cover_tables,
+    scoped_lanes,
     unsorted_steps,
 )
 from _uf import union_find_components as _union_find_components
@@ -468,14 +471,14 @@ def test_sorted_scatters_give_the_unsorted_steps_table_on_every_row(
     assert len(moved) > 100 and len(np.unique(got[-1][moved])) < len(moved)
 
 
-def _lowered_step(which: str) -> str:
+def _lowered_step(which: str, tcap: int = 1 << 10, wcap: int = 1 << 9,
+                  vcap: int = 1 << 14) -> str:
     import jax
     import jax.numpy as jnp
 
     from gelly_streaming_tpu.summaries import candidates, forest
 
     S = jax.ShapeDtypeStruct
-    tcap, wcap, vcap = 1 << 10, 1 << 9, 1 << 14
     lanes = (S((tcap,), jnp.int32), S((tcap,), jnp.bool_),
              S((wcap,), jnp.int32), S((wcap,), jnp.int32))
     if which == "cc-step":
@@ -491,6 +494,39 @@ def _lowered_step(which: str) -> str:
 @pytest.mark.parametrize("which", ["cc-step", "cover-step"])
 def test_the_lowered_step_says_its_table_scatters_are_sorted(which):
     assert_table_scatters_go_out_sorted(_lowered_step(which))
+
+
+@pytest.mark.parametrize("which,lanes", [("cc-step", 1), ("cover-step", 2)])
+def test_the_lowered_step_says_a_round_handles_the_windows_rows_alone(
+        which, lanes):
+    """ISSUE 33: inside the fixpoint's loop the hook's two gathers and
+    two scatter-mins carry ``wcap`` lanes (``2 * wcap`` on the cover)
+    and the shortcut ``tcap``; none carries ``wcap + tcap``. The
+    contraction's three gathers sit outside the loop, under
+    ``forest.fixpoint/forest.contract``."""
+    tcap, wcap = lanes << 10, lanes << 9
+    text = _lowered_step(which)
+    inside = {name: sorted(
+        n for path, n in scoped_lanes(text, name)
+        if "forest.fixpoint/while/body" in path)
+        for name in ("gather", "scatter")}
+    assert inside == {"gather": [wcap, wcap, tcap], "scatter": [wcap, wcap]}
+    contract = [(path[path.index("forest."):], n)
+                for path, n in scoped_lanes(text, "gather")
+                if "forest.contract" in path]
+    assert contract == [("forest.fixpoint/forest.contract/gather", wcap)] * 2 \
+        + [("forest.fixpoint/forest.contract/gather", tcap)]
+    assert f"tensor<{wcap + tcap}x" not in text
+
+
+def test_ccs_lowered_step_holds_no_constant_the_size_of_its_lanes():
+    """ROADMAP S14, closed by ISSUE 33: the pointer edges' sources were
+    a host-built ``arange(tcap)``, a literal of CC's program (1.07 MB
+    of lowered text at the cells' shapes, growing with ``tcap``)."""
+    small = _lowered_step("cc-step")
+    cells = _lowered_step("cc-step", 1 << 17, 1 << 16, 1 << 28)
+    assert len(cells) < 100_000 and abs(len(cells) - len(small)) < 1_000
+    assert not re.search(r"stablehlo\.constant dense<[^>]{64,}>", cells)
 
 
 # --------------------------------------------------------------------- #

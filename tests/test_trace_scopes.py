@@ -99,6 +99,11 @@ def test_scopes_are_in_the_lowered_program_under_its_old_name(
     if "forest.chase" in scopes:
         assert "forest.chase/while/body" in text
         assert "forest.fixpoint/while/body" in text
+        # the fixpoint's once-a-step part, nested in it and not in its
+        # loop (ISSUE 33)
+        assert "forest.fixpoint/forest.contract/gather" in text
+        assert "forest.contract/while" not in text
+        assert "while/body/forest.contract" not in text
     else:
         assert "query.chase/while/body" in text
     # the benchmark finds the programs by these names
