@@ -4,17 +4,29 @@ TPU-native re-design of ``example/DegreeDistribution.java:42-131``, the
 reference's only fully-dynamic (addition + deletion) workload. Its pipeline —
 flatMap to (vertex, ±1), keyed degree counts, keyed histogram counts — runs
 one boxed record at a time with two HashMap states. Here each window of
-events is ONE compiled step:
+events is ONE compiled step (``degree_step``, program ``jit_degree_step``)
+whose cost follows the WINDOW, not the id space: nothing in it but the
+degree table and its copy has a row per vertex.
 
 - Per-vertex ordered degree folds are batched with a segmented associative
-  scan: the reference's clamped sequential update ``deg' = max(0, deg + d)``
-  (degree ≤ 0 removes the vertex, ``DegreeDistribution.java:93-100``)
-  composes as ``g(x) = max(m, x + s)``; two such updates fuse to
-  ``(s1+s2, max(m2, m1+s2))`` — associative, so in-window event order per
-  vertex is preserved exactly while all vertices fold in parallel.
+  scan over the window's ``2 * W`` (vertex, ±1) lanes, stable-sorted by
+  vertex: the reference's clamped sequential update
+  ``deg' = max(0, deg + d)`` (degree ≤ 0 removes the vertex,
+  ``DegreeDistribution.java:93-100``) composes as ``g(x) = max(m, x + s)``;
+  two such updates fuse to ``(s1+s2, max(m2, m1+s2))`` — associative, so
+  in-window event order per vertex is preserved exactly while all vertices
+  fold in parallel. The last lane of a vertex's run holds its whole
+  update; the old degrees of those lanes are ONE gather out of the table
+  and the new ones ONE sorted scatter into it (``TableOps``, the pointer
+  forest's pair of table primitives).
 - The histogram is derived state: subtract old-degree counts of touched
   vertices, add new-degree counts (degree 0 never tracked, matching the
-  reference's remove-on-zero).
+  reference's remove-on-zero): two window-sized scatter-adds.
+
+Two ways in, one step: :meth:`DegreeDistribution.run` takes records
+``(src, dst, change)``; :meth:`DegreeDistribution.run_stream` takes a
+column stream (``SimpleEdgeStream`` over ``(src, dst, ±1)`` columns) and
+does no per-record Python work.
 
 Emission semantics (documented delta, SURVEY.md §7): the reference emits
 (degree, count) per record update; here per window, change-only. Final
@@ -23,7 +35,6 @@ histograms are identical for any windowing.
 
 from __future__ import annotations
 
-import functools
 from typing import Iterable, Iterator, Optional, Tuple
 
 import jax
@@ -34,7 +45,9 @@ from ..core.edgeblock import bucket_capacity
 from ..core.emission import LazyListBatch
 from ..core.types import EventType
 from ..core.window import CountWindow, WindowPolicy, Windower
-from ..ops.segment import segmented_reduce_generic
+from ..obs import trace as _trace
+from ..ops.segment import segmented_reduce_lanes
+from ..summaries.forest import TableOps
 
 
 def _combine(a, b):
@@ -44,21 +57,39 @@ def _combine(a, b):
     return s1 + s2, jnp.maximum(m2, m1 + s2)
 
 
-@functools.partial(jax.jit, static_argnums=(5,))
-def _degree_step(deg, hist, verts, deltas, mask, vcap: int):
-    s0 = deltas.astype(jnp.int32)
-    m0 = jnp.zeros_like(s0)
-    (s, m), nonempty = segmented_reduce_generic(
-        (s0, m0), verts, mask, vcap, _combine
+@jax.jit
+def degree_step(deg, hist, src, dst, val, mask):
+    """Fold one window of ±events into the carried tables; returns fresh
+    ``(deg, hist)`` buffers (nothing is donated: published snapshots are
+    immutable). ``src``/``dst``/``val``/``mask`` are an ``EdgeBlock``'s
+    padded columns, ``val`` the event's ±1. Every operand but ``deg``
+    and its copy is window-sized (``2 * W`` lanes) or the histogram."""
+    vcap, hcap = deg.shape[0], hist.shape[0]
+    tab = TableOps(vcap)
+    # interleave [s0, d0, s1, d1, ...] — the reference emits (src, ±1)
+    # then (dst, ±1) PER EVENT (``DegreeDistribution.java:73-77``), and
+    # per-vertex clamp order matters when a degree crosses zero; a plain
+    # [all srcs, all dsts] concat would reorder a vertex's src-role vs
+    # dst-role updates
+    verts = jnp.stack([src, dst], axis=1).ravel()
+    s0 = jnp.stack([val, val], axis=1).ravel().astype(jnp.int32)
+    lanes = jnp.stack([mask, mask], axis=1).ravel()
+    ids, (s, m), last = segmented_reduce_lanes(
+        (s0, jnp.zeros_like(s0)), verts, lanes, _combine, scope="degrees"
     )
-    old = deg
-    new = jnp.where(nonempty, jnp.maximum(m, old + s), old)
-    hcap = hist.shape[0]
-    dec = (nonempty & (old > 0)).astype(jnp.int32)
-    inc = (nonempty & (new > 0)).astype(jnp.int32)
-    hist = hist.at[jnp.clip(old, 0, hcap - 1)].add(-dec)
-    hist = hist.at[jnp.clip(new, 0, hcap - 1)].add(inc)
-    return new, hist
+    with jax.named_scope("degrees.gather"):
+        old = tab.gather(deg, jnp.where(last, ids, 0))
+    new = jnp.maximum(m, old + s)
+    with jax.named_scope("degrees.scatter"):
+        # one lane a touched vertex (rows unique); the others drop at
+        # the sentinel
+        deg = tab.scatter(deg, jnp.where(last, ids, vcap), new)
+    with jax.named_scope("degrees.hist"):
+        # degrees at or past the capacity count in the last bin
+        dec = jnp.where(last & (old > 0), jnp.minimum(old, hcap - 1), hcap)
+        inc = jnp.where(last & (new > 0), jnp.minimum(new, hcap - 1), hcap)
+        hist = hist.at[dec].add(-1, mode="drop").at[inc].add(1, mode="drop")
+    return deg, hist
 
 
 class DegreeDistribution:
@@ -67,14 +98,28 @@ class DegreeDistribution:
     ``run(events)`` consumes ``(src, dst, change)`` records — ``change`` an
     :class:`EventType`, ``"+"``/``"-"``, or ±1 — and yields, per window, the
     change-only list of ``(degree, count)`` histogram entries.
+    ``run_stream(stream)`` folds a column stream's windows (the ``val``
+    column is the ±1) through the same step.
+
+    ``hist_capacity=n`` fixes the histogram at ``n`` bins when the
+    aggregation is built: degrees at or past ``n - 1`` count in the last
+    bin, and no shape of the step changes after its first window (a
+    served deployment compiles nothing while it runs). Without it the
+    histogram grows with the stream, bounded by a host count over every
+    window's ids.
     """
 
-    def __init__(self, window: Optional[WindowPolicy] = None, vertex_dict=None):
+    def __init__(self, window: Optional[WindowPolicy] = None,
+                 vertex_dict=None, hist_capacity: Optional[int] = None):
         self.window = window or CountWindow(1 << 16)
+        if hist_capacity is not None and hist_capacity < 2:
+            raise ValueError("hist_capacity must hold degree 1: at least 2")
+        self.hist_capacity = hist_capacity
         # the windower (and its VertexDict) persists across run() calls so
         # a resumed stream keeps the same compact-id space as the carried
         # degree vector
         self._windower = Windower(self.window, vertex_dict, val_dtype=np.int32)
+        self._vdict = self._windower.vertex_dict  # a column stream brings its own
         self._deg = None  # device int32[vcap]
         self._hist = None  # device int32[hcap]; index = degree, [0] unused
         # host shadow for histogram-capacity growth (zero device reads in
@@ -116,18 +161,53 @@ class DegreeDistribution:
         Materializing batches in stream order reproduces per-window
         change-only emission exactly; skipping windows folds their
         changes into the next batch read."""
-        windower = self._windower
         rows = ((s, d, _delta(c), *rest) for s, d, c, *rest in events)
-        for block in windower.blocks(rows):
-            vcap = block.n_vertices
+        return self._fold(self._windower.blocks(rows))
+
+    def run_stream(self, stream) -> Iterator["HistogramBatch"]:
+        """The column path: ``stream`` is a graph stream whose blocks
+        carry the event's ±1 in their ``val`` column (a
+        ``SimpleEdgeStream`` over ``(src, dst, ±1)`` columns or column
+        chunks); compact ids are its vertex dictionary's. Yields what
+        :meth:`run` yields; no record is touched in Python."""
+        self._vdict = stream.vertex_dict
+        return self._fold(stream.blocks())
+
+    def _fold(self, blocks) -> Iterator["HistogramBatch"]:
+        for block in blocks:
             cache = getattr(block, "_host_cache", None)
-            if cache is not None:
-                s_h, d_h = cache[0], cache[1]
-            else:  # non-windower block (rare): one download
-                mask_h = np.asarray(block.mask)
-                s_h = np.asarray(block.src)[mask_h]
-                d_h = np.asarray(block.dst)[mask_h]
-            n_events = len(s_h)
+            with _trace.span("degrees.window") as sp:
+                with _trace.span("degrees.prep"):
+                    n_events = self._size_tables(block, cache)
+                if sp.recording and cache is not None:
+                    sp.set(events=n_events, lanes=2 * block.src.shape[0],
+                           deletions=int(np.count_nonzero(cache[2] < 0)))
+                with _trace.span("degrees.dispatch"):
+                    self._deg, self._hist = degree_step(
+                        self._deg, self._hist,
+                        block.src, block.dst, block.val, block.mask,
+                    )
+            self._events_total += n_events
+            yield HistogramBatch(
+                self, self._hist, self._events_total, self._inc_total
+            )
+
+    def _size_tables(self, block, cache) -> int:
+        """Allocate or grow the two carried tables for ``block``; returns
+        its event count. With ``hist_capacity`` this is two allocations
+        at the first window and nothing after; without, the histogram's
+        capacity follows a host bound on the largest degree."""
+        vcap = block.n_vertices
+        if cache is not None:
+            s_h, d_h = cache[0], cache[1]
+        else:  # non-windower block (rare): one download
+            mask_h = np.asarray(block.mask)
+            s_h = np.asarray(block.src)[mask_h]
+            d_h = np.asarray(block.dst)[mask_h]
+        n_events = len(s_h)
+        if self.hist_capacity is not None:
+            hcap = self.hist_capacity
+        else:
             if n_events:
                 # max per-vertex event count this window bounds how far
                 # any degree (hence the histogram support) can rise
@@ -135,36 +215,10 @@ class DegreeDistribution:
                 inc = int(np.unique(both, return_counts=True)[1].max())
                 self._max_deg_ub += inc
                 self._inc_total += inc
-            if self._deg is None:
-                self._deg = jnp.zeros(vcap, jnp.int32)
-            elif vcap > self._deg.shape[0]:
-                self._deg = jnp.concatenate(
-                    [self._deg,
-                     jnp.zeros(vcap - self._deg.shape[0], jnp.int32)]
-                )
             hcap = bucket_capacity(self._max_deg_ub + 1)
-            if self._hist is None:
-                self._hist = jnp.zeros(hcap, jnp.int32)
-            elif hcap > self._hist.shape[0]:
-                self._hist = jnp.concatenate(
-                    [self._hist,
-                     jnp.zeros(hcap - self._hist.shape[0], jnp.int32)]
-                )
-            # interleave [s0, d0, s1, d1, ...] — the reference emits
-            # (src, ±1) then (dst, ±1) PER EVENT (``DegreeDistribution.
-            # java:73-77``), and per-vertex clamp order matters when a
-            # degree crosses zero; a plain [all srcs, all dsts] concat
-            # would reorder a vertex's src-role vs dst-role updates
-            verts = jnp.stack([block.src, block.dst], axis=1).ravel()
-            deltas = jnp.stack([block.val, block.val], axis=1).ravel()
-            mask = jnp.stack([block.mask, block.mask], axis=1).ravel()
-            self._deg, self._hist = _degree_step(
-                self._deg, self._hist, verts, deltas, mask, vcap
-            )
-            self._events_total += n_events
-            yield HistogramBatch(
-                self, self._hist, self._events_total, self._inc_total
-            )
+        self._deg = _grown(self._deg, vcap)
+        self._hist = _grown(self._hist, hcap)
+        return n_events
 
     def state_dict(self) -> dict:
         """Checkpoint surface (``aggregate/checkpoint.py:save_workload``);
@@ -181,7 +235,7 @@ class DegreeDistribution:
             "deg": None if self._deg is None else np.asarray(self._deg),
             "hist": hist,
             "max_deg": max_deg,
-            "vdict_raw": self._windower.vertex_dict.raw_ids(),
+            "vdict_raw": self._vdict.raw_ids(),
         }
 
     def load_state_dict(self, d: dict) -> None:
@@ -195,7 +249,7 @@ class DegreeDistribution:
         self._events_total = 0
         self._emit_base = 0
         self._emit_prev = None if d["hist"] is None else np.asarray(d["hist"]).copy()
-        vd = self._windower.vertex_dict
+        vd = self._vdict
         if len(vd) == 0:
             vd.encode(d["vdict_raw"])
         elif vd.raw_ids().tolist() != d["vdict_raw"].tolist():
@@ -206,10 +260,10 @@ class DegreeDistribution:
 
     # ---- serving surface (serving/server.py Servable contract) ------- #
     def servable(self, vdict=None) -> "DegreeServable":
-        """Adapter publishing the carried degree vector per window for
-        ``DegreeQuery`` point lookups (``vdict`` is only consulted for
-        the checkpoint boot payload; live windows use the windower's
-        dict)."""
+        """Adapter publishing the carried degree table and histogram per
+        window for ``DegreeQuery`` and ``DegreeCountQuery`` lookups
+        (``vdict`` is only consulted for the checkpoint boot payload;
+        live windows use the stream's dict)."""
         return DegreeServable(self, vdict)
 
     def histogram(self) -> dict:
@@ -284,30 +338,50 @@ class HistogramBatch(LazyListBatch):
 
 class DegreeServable:
     """:class:`~gelly_streaming_tpu.serving.server.Servable` adapter for
-    :class:`DegreeDistribution`: one ``deg`` table per window (the
-    jitted step returns fresh buffers, so published tables are
-    immutable), watermark = cumulative events folded."""
+    :class:`DegreeDistribution`. Every window publishes ``deg`` (the
+    degree table, for ``DegreeQuery``), ``hist`` (the degree -> vertex
+    count histogram, for ``DegreeCountQuery``) and ``vdict``; both
+    tables are the step's fresh output buffers, so published snapshots
+    are immutable. Watermark = cumulative events folded."""
 
     def __init__(self, workload: DegreeDistribution, vdict=None):
-        from ..serving import DegreeQuery
+        from ..serving import DegreeCountQuery, DegreeQuery
 
-        self.query_classes = (DegreeQuery,)
+        self.query_classes = (DegreeQuery, DegreeCountQuery)
         self._workload = workload
         self._vdict = vdict
 
-    def payloads(self, events):
+    def payloads(self, stream):
+        """``stream`` is a graph stream (the column path, as the other
+        servables take one) or an iterable of ``(src, dst, change)``
+        records (the record path)."""
         w = self._workload
-        vdict = w._windower.vertex_dict
-        self._vdict = vdict
-        for _ in w.run(events):
-            yield {"deg": w._deg, "vdict": vdict}, w._events_total
+        columns = callable(getattr(stream, "blocks", None))
+        batches = w.run_stream(stream) if columns else w.run(stream)
+        vdict = self._vdict = w._vdict
+        for _ in batches:
+            yield ({"deg": w._deg, "hist": w._hist, "vdict": vdict},
+                   w._events_total)
 
     def boot_payload(self):
         w = self._workload
         if w._deg is None:
             return None
-        vdict = self._vdict or w._windower.vertex_dict
-        return {"deg": w._deg, "vdict": vdict}, w._events_total
+        vdict = self._vdict or w._vdict
+        return ({"deg": w._deg, "hist": w._hist, "vdict": vdict},
+                w._events_total)
+
+
+def _grown(table, rows: int):
+    """``table`` with zero rows appended up to ``rows`` (a fresh table
+    where there is none); the table itself where it is large enough."""
+    if table is None:
+        return jnp.zeros(rows, jnp.int32)
+    if rows <= table.shape[0]:
+        return table
+    return jnp.concatenate(
+        [table, jnp.zeros(rows - table.shape[0], jnp.int32)]
+    )
 
 
 def _delta(change) -> int:

@@ -11,6 +11,8 @@ edge value = element. Three tiers, fastest first:
 2. :func:`segmented_reduce_generic` — arbitrary *associative* combine, via a
    segmented ``lax.associative_scan`` over edges sorted by segment (the
    classic (flag, value) trick). Parallel depth O(log E).
+   :func:`segmented_reduce_lanes` is its window-sized form: the result
+   stays on the sorted lanes, so nothing has ``num_segments`` rows.
 3. :func:`segmented_fold` — arbitrary (possibly non-associative) fold in
    arrival order, via ``lax.scan`` over the sorted edges. Sequential in E but
    fully compiled; mirrors the reference's per-record ``EdgesFold`` exactly
@@ -77,6 +79,16 @@ def sort_by_segment(
     Returns ``(sorted_ids, sorted_mask, *sorted_arrays)``.
     """
     ids = jnp.where(mask, segment_ids, _INT_MAX)
+    leaves, treedef = jax.tree.flatten(arrays)
+    if all(leaf.shape == ids.shape for leaf in leaves):
+        # lanes of one rank ride the sort as operands: one variadic
+        # ``lax.sort`` where an argsort is followed by a gather a column
+        # (on the v5e, 2^17 lanes and two columns: 0.23 ms against 3.95,
+        # a gather costs 7 ns a lane there; PERF.md section 6, PR 34)
+        out = lax.sort((ids, *leaves), num_keys=1, is_stable=True)
+        # the padding is what sorted under the sentinel
+        return (out[0], out[0] != _INT_MAX) + tuple(
+            jax.tree.unflatten(treedef, out[1:]))
     order = jnp.argsort(ids, stable=True)
     return (ids[order], mask[order]) + tuple(
         jax.tree.map(lambda a: a[order], arr) for arr in arrays
@@ -93,6 +105,54 @@ def _segment_last_index(sorted_ids: jax.Array, num_segments: int) -> Tuple[jax.A
     return last, nonempty
 
 
+def segmented_reduce_lanes(
+    values: Any,
+    segment_ids: jax.Array,
+    mask: jax.Array,
+    combine: Callable[[Any, Any], Any],
+    scope: str = "segment",
+) -> Tuple[jax.Array, Any, jax.Array]:
+    """The window-sized form of :func:`segmented_reduce_generic`: no
+    operand or result has a row per segment, so the cost follows the
+    lanes and not the id space.
+
+    Returns ``(sorted_ids, scanned, last)`` over the lanes stable-sorted
+    by segment: ``scanned[i]`` is the reduction of lane ``i``'s segment
+    up to and including lane ``i`` (arrival order kept), and ``last``
+    marks the one lane of every non-padding segment that holds the
+    segment's whole reduction. Padding lanes sort to the end under the
+    id ``INT_MAX`` and are never ``last``.
+
+    Mechanism: sort by segment (named scope ``<scope>.sort``), then the
+    standard segmented-scan construction (``<scope>.scan``) — carry
+    (start_flag, value) pairs through ``lax.associative_scan`` where a
+    start flag blocks combination across the boundary. This keeps
+    arbitrary ``EdgesReduce`` UDFs (``EdgesReduce.java:31-44``) fully
+    parallel on the VPU.
+    """
+    with jax.named_scope(f"{scope}.sort"):
+        sorted_ids, sorted_mask, sorted_vals = sort_by_segment(
+            segment_ids, mask, values
+        )
+    with jax.named_scope(f"{scope}.scan"):
+        change = sorted_ids[1:] != sorted_ids[:-1]
+        edge = jnp.ones(1, bool)
+        starts = jnp.concatenate([edge, change])
+
+        def scan_op(a, b):
+            fa, va = a
+            fb, vb = b
+            merged = combine(va, vb)
+            v = jax.tree.map(
+                lambda m, y: jnp.where(_bcast(fb, y), y, m), merged, vb
+            )
+            return fa | fb, v
+
+        _, scanned = lax.associative_scan(scan_op, (starts, sorted_vals))
+        last = jnp.concatenate([change, edge]) & sorted_mask
+    return sorted_ids, scanned, last
+
+
 def segmented_reduce_generic(
     values: Any,
     segment_ids: jax.Array,
@@ -103,30 +163,15 @@ def segmented_reduce_generic(
     """Arbitrary associative segmented reduction (tier 2).
 
     ``combine(a, b) -> c`` must be associative over the value pytree.
-    Returns ``(per_segment_result, nonempty_mask)``; rows of empty segments
-    are whatever the scan produced and must be gated by ``nonempty_mask``.
-
-    Mechanism: sort by segment, then run the standard segmented-scan
-    construction — carry (start_flag, value) pairs through
-    ``lax.associative_scan`` where a start flag blocks combination across the
-    boundary. This keeps arbitrary ``EdgesReduce`` UDFs
-    (``EdgesReduce.java:31-44``) fully parallel on the VPU.
+    Returns ``(per_segment_result, nonempty_mask)``, one row a segment;
+    rows of empty segments are whatever the scan produced and must be
+    gated by ``nonempty_mask``. The sort and the scan are
+    :func:`segmented_reduce_lanes`'; a caller whose segments are an id
+    space far larger than its lanes takes that form.
     """
-    sorted_ids, sorted_mask, sorted_vals = sort_by_segment(segment_ids, mask, values)
-    starts = jnp.concatenate(
-        [jnp.ones(1, bool), sorted_ids[1:] != sorted_ids[:-1]]
+    sorted_ids, scanned, _ = segmented_reduce_lanes(
+        values, segment_ids, mask, combine
     )
-
-    def scan_op(a, b):
-        fa, va = a
-        fb, vb = b
-        merged = combine(va, vb)
-        v = jax.tree.map(
-            lambda m, y: jnp.where(_bcast(fb, y), y, m), merged, vb
-        )
-        return fa | fb, v
-
-    _, scanned = lax.associative_scan(scan_op, (starts, sorted_vals))
     last, nonempty = _segment_last_index(sorted_ids, num_segments)
     result = jax.tree.map(lambda a: a[last], scanned)
     return result, nonempty

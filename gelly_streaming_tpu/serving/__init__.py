@@ -59,6 +59,7 @@ from .query import (
     BipartiteQuery,
     ComponentSizeQuery,
     ConnectedQuery,
+    DegreeCountQuery,
     DegreeQuery,
     Query,
     QueryEngine,
@@ -109,6 +110,7 @@ __all__ = [
     "ComponentSizeQuery",
     "ConnectedQuery",
     "DeadlineExceeded",
+    "DegreeCountQuery",
     "DegreeQuery",
     "FailoverServer",
     "HeartbeatLease",

@@ -11,7 +11,9 @@ per query class with ONE jitted lookup:
   gather a round, sized by the batch, not the vertex capacity). Flat
   labels are a valid (depth-1) forest, so the one kernel serves every
   CC carry and restored checkpoints alike.
-- Degree / rank queries are one table gather.
+- Degree / rank queries are one table gather; a degree-count query is
+  the same gather out of the degree histogram published beside the
+  degree table.
 - Component-size queries canonicalize the forest once per snapshot
   version (cached) and bincount, then answer any number of batches from
   the cached size table.
@@ -86,6 +88,19 @@ class DegreeQuery(Query):
     """Current degree of ``v``."""
 
     v: int
+
+
+@dataclass(frozen=True)
+class DegreeCountQuery(Query):
+    """How many vertices have degree ``d`` right now? One bin of the
+    degree histogram a :class:`~gelly_streaming_tpu.library.degrees.
+    DegreeDistribution` publishes beside its degree table (payload key
+    ``hist``). Degree 0 is never tracked (a vertex at 0 is removed) and
+    answers 0, as does a degree past the histogram's capacity; where
+    the capacity is fixed, its last bin counts every degree at or past
+    it."""
+
+    d: int
 
 
 @dataclass(frozen=True)
@@ -410,6 +425,7 @@ class QueryEngine:
         ComponentSizeQuery: "labels",
         SummaryPullQuery: "labels",
         DegreeQuery: "deg",
+        DegreeCountQuery: "hist",
         RankQuery: "ranks",
         BipartiteQuery: "cover",
     }
@@ -870,21 +886,35 @@ class QueryEngine:
     def rank(self, snap: PublishedSnapshot, vs: np.ndarray) -> np.ndarray:
         return self._table_gather(snap, "ranks", vs, fill=0.0)
 
+    def degree_count(
+        self, snap: PublishedSnapshot, ds: np.ndarray
+    ) -> np.ndarray:
+        """int[n]: the histogram's bin of every degree in ``ds`` (no
+        vertex dictionary: a degree is its own row)."""
+        hist = self._table(snap, "hist")
+        ds = np.asarray(ds, np.int64)
+        return self._gather_rows(
+            hist, ds, (ds >= 1) & (ds < int(hist.shape[0])), fill=0)
+
     def _table_gather(
         self, snap: PublishedSnapshot, key: str, vs: np.ndarray, fill
     ) -> np.ndarray:
         table = self._table(snap, key)
-        vdict = snap.payload["vdict"]
-        cv = _lookup_batch(vdict, vs)
-        vcap = int(table.shape[0])
-        valid = (cv >= 0) & (cv < vcap)
-        safe = np.where(valid, cv, 0)
+        cv = _lookup_batch(snap.payload["vdict"], vs)
+        return self._gather_rows(
+            table, cv, (cv >= 0) & (cv < int(table.shape[0])), fill)
+
+    def _gather_rows(self, table, rows: np.ndarray, valid: np.ndarray,
+                     fill) -> np.ndarray:
+        """``table[rows]`` where ``valid``, ``fill`` elsewhere: one
+        batch-sized gather on the path the engine takes."""
+        safe = np.where(valid, rows, 0)
         if self.prefer_host:
             got = table[safe]
         else:
             got = _fetch(
                 _gather(jnp.asarray(table), jnp.asarray(_pad_ids(safe))),
-                len(cv),
+                len(rows),
             )
         return np.where(valid, got, fill)
 
@@ -935,6 +965,9 @@ class QueryEngine:
                 us = np.asarray([queries[i].u for i in idxs], np.int64)
                 vs = np.asarray([queries[i].v for i in idxs], np.int64)
                 vals = self.connected(snap, us, vs)
+            elif qcls is DegreeCountQuery:
+                vals = self.degree_count(
+                    snap, np.asarray([queries[i].d for i in idxs], np.int64))
             else:
                 vs = np.asarray([queries[i].v for i in idxs], np.int64)
                 if qcls is DegreeQuery:

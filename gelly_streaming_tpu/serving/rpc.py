@@ -73,6 +73,7 @@ from .query import (
     BipartiteQuery,
     ComponentSizeQuery,
     ConnectedQuery,
+    DegreeCountQuery,
     DegreeQuery,
     Query,
     RankQuery,
@@ -182,6 +183,7 @@ class Wire:
 _Q_KINDS = {
     "C": (ConnectedQuery, 2),
     "D": (DegreeQuery, 1),
+    "H": (DegreeCountQuery, 1),
     "R": (RankQuery, 1),
     "S": (ComponentSizeQuery, 1),
     "P": (SummaryPullQuery, 0),
@@ -190,6 +192,7 @@ _Q_KINDS = {
 _Q_TAGS = {
     ConnectedQuery: "C",
     DegreeQuery: "D",
+    DegreeCountQuery: "H",
     RankQuery: "R",
     ComponentSizeQuery: "S",
     SummaryPullQuery: "P",
@@ -217,6 +220,8 @@ def encode_queries(queries) -> List[list]:
                 out.append([tag])
         elif tag == "B":
             out.append([tag])
+        elif tag == "H":
+            out.append([tag, int(q.d)])
         else:
             out.append([tag, int(q.v)])
     return out

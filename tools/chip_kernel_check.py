@@ -422,6 +422,7 @@ def query_engine_device_all_classes() -> str:
         BipartiteQuery,
         ComponentSizeQuery,
         ConnectedQuery,
+        DegreeCountQuery,
         DegreeQuery,
         RankQuery,
         SummaryPullQuery,
@@ -471,8 +472,14 @@ def query_engine_device_all_classes() -> str:
         window=CountWindow(window), vertex_dict=datasets.IdentityDict(vcap)
     )
     events = zip(src.tolist(), dst.tolist(), ["+"] * len(src))
-    got = _serve(dd.servable(), events, [DegreeQuery(int(v)) for v in qv])
-    assert got == deg[qv].tolist(), "degree"
+    counts = np.bincount(deg[deg > 0])
+    qd = [1, 2, 3, 5, 8, len(counts) - 1, len(counts) + 7]
+    got = _serve(dd.servable(), events,
+                 [DegreeQuery(int(v)) for v in qv]
+                 + [DegreeCountQuery(d) for d in qd])
+    assert got[:len(qv)] == deg[qv].tolist(), "degree"
+    assert got[len(qv):] == [
+        int(counts[d]) if d < len(counts) else 0 for d in qd], "degree count"
 
     # rank table gather (the PageRank step always donates its carry)
     pr = IncrementalPageRank(tol=1e-9, max_iter=500)
@@ -493,7 +500,7 @@ def query_engine_device_all_classes() -> str:
     want_bp = oracle_bipartite(vcap, src, dst)
     assert verdict["bipartite"] == want_bp, verdict
     assert want_bp or verdict["witness"] is not None, verdict
-    return (f"6 query classes, {2 * n + 2 + n + n + 1} answers after "
+    return (f"7 query classes, {2 * n + 2 + n + len(qd) + n + 1} answers after "
             f"{n_win} windows of {window} edges; delta pull kind="
             f"{delta['kind']}; bipartite={want_bp}")
 

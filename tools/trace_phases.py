@@ -7,8 +7,9 @@ spans inside the program.
 Runs the cell exactly as ``benchmarks/run.py --trace 1`` does (the same
 ``cellrun.run_cell``) and adds, to the cell's per-layer metrics, the
 ones ``PROPOSED`` lists: the forest step's phases and loop trips
-(``benchmarks/lib/scope_reduce.py``), the host fold's spans and the
-serving waits. They are not entries of ``BENCHMARK.json``: the harness
+(``benchmarks/lib/scope_reduce.py``), the degree step's phases (the
+``dd-g500-s28`` cell: ``degrees.sort|scan|gather|scatter|hist`` inside
+``jit_degree_step``), the host fold's spans and the serving waits. They are not entries of ``BENCHMARK.json``: the harness
 requires every per-layer metric of a cell on the line of a traced run,
 and a program that lacks the span or scope (the parent of the PR that
 adds it) would then print no line at all. Until a ``benchmark`` PR
@@ -50,10 +51,15 @@ if ROOT not in sys.path:
 SAT = ["cc-g500-s28.ingest-saturated", "bip-g500-s27.ingest-saturated-poll"]
 CC = ["cc-g500-s28.ingest-saturated", "cc-g500-s28.paced-query-heavy"]
 V4 = ["cc-g500-s30-v4.ingest-saturated"]
+DYN = ["dd-g500-s28.ingest-saturated-dyn"]
+#: the per-window program of a cell (the one whose phases are read)
+STEP_PROGRAM = {**dict.fromkeys(SAT + CC + V4, "jit_step"),
+                **dict.fromkeys(DYN, "jit_degree_step")}
+DEGREE_PHASES = ("sort", "scan", "gather", "scatter", "hist")
 
 
-def _scope(kind: str, scope: str) -> dict:
-    return {"kind": kind, "program": "jit_step", "scope": scope}
+def _scope(kind: str, scope: str, program: str = "jit_step") -> dict:
+    return {"kind": kind, "program": program, "scope": scope}
 
 
 #: name -> (unit, layer, moves, cells, reader): what a ``benchmark`` PR
@@ -106,6 +112,24 @@ PROPOSED = {
     "answer_wait_ms.v4": ("ms", "serving", "query_p95_ms", V4,
                           {"kind": "span_mean_ms",
                            "span": "serving.device_wait"}),
+    # the degree step (dd-g500-s28): its five scopes partition it, but
+    # for the compiler's copy of the table; `degree_gather_ms.dyn` is
+    # the accepted metric of the QUERY gather, hence `degree_step_*`
+    **{f"degree_step_{p}_ms.dyn": (
+        "ms", "degree step", "edges_per_s", DYN,
+        _scope("scope_mean_ms", f"degrees.{p}", "jit_degree_step"))
+       for p in DEGREE_PHASES},
+    "degree_prep_ms.dyn": ("ms", "window host step", "edges_per_s", DYN,
+                           {"kind": "span_mean_ms", "span": "degrees.prep"}),
+    "degree_dispatch_ms.dyn": ("ms", "window host step", "edges_per_s", DYN,
+                               {"kind": "span_mean_ms",
+                                "span": "degrees.dispatch"}),
+    "queue_wait_ms.dyn": ("ms", "serving", "query_p95_ms", DYN,
+                          {"kind": "span_mean_ms",
+                           "span": "serving.queue_wait"}),
+    "answer_wait_ms.dyn": ("ms", "serving", "query_p95_ms", DYN,
+                           {"kind": "span_mean_ms",
+                            "span": "serving.device_wait"}),
 }
 
 
@@ -126,6 +150,17 @@ def phases_block(m: dict) -> dict:
     over the scope's rounds; a scope holds a little beside its loop,
     the chase its first two gathers; the fixpoint's contraction, which
     runs once a step under a scope of its own, is taken out first)."""
+    if "degree_step_ms.dyn" in m:
+        # the degree step has no loop and nothing nested: the scopes'
+        # sum against the whole step (the rest is the table's copy)
+        step = m["degree_step_ms.dyn"]["value"]
+        parts = {p: m[f"degree_step_{p}_ms.dyn"]["value"]
+                 for p in DEGREE_PHASES if f"degree_step_{p}_ms.dyn" in m}
+        if not parts:
+            return {}
+        return {"sum_ms": sum(parts.values()), "step_ms": step,
+                "share": sum(parts.values()) / step,
+                **{f"{p}_ms": v for p, v in parts.items()}}
     step = next((m[k]["value"] for k in m if k.startswith("forest_step_ms")),
                 None)
     tag = next((t for t in (".sat", ".v4") if f"forest_chase_ms{t}" in m),
@@ -185,16 +220,38 @@ def _exchanges(ctx: dict) -> dict:
     return out
 
 
+def _table_ops(ctx: dict) -> list:
+    """The ops of the per-window program that have an operand or a
+    result with a row per vertex (the instruction's text names the
+    shape ``[<rows>]``): ``[instruction, scope path, ms]`` over the
+    first traced execution. What shows whether a step's cost follows
+    the window: for the degree step these are the gather, the scatter
+    and the compiler's copy of the table, and nothing else."""
+    from benchmarks.lib import scope_reduce, trace_reduce
+
+    cell = ctx["cell"]
+    rows = f"[{cell.algorithm().table_rows(cell.config)}]"
+    scoped = scope_reduce._scoped(ctx)
+    a, b = scope_reduce.executions(
+        scoped, STEP_PROGRAM[cell.name], ctx["lo"], ctx["hi"])[0]
+    ops = trace_reduce.line_of(trace_reduce.device_planes(scoped)[0],
+                               trace_reduce.OPS_LINE)["events"]
+    return [[scope_reduce.op_id(n), "/".join(scope_reduce.op_path(n)),
+             d / 1e6] for n, s, d in ops if a <= s < b and rows in n]
+
+
 def _span_counts(ctx: dict) -> dict:
     """Span events per window and per sweep inside the measured window."""
     by_name: dict = {}
     for e in ctx["spans"]:
         by_name[e["name"]] = by_name.get(e["name"], 0) + 1
-    windows = by_name.get("forest.window", 0)
+    windows = by_name.get("forest.window", 0) + by_name.get(
+        "degrees.window", 0)
     sweeps = by_name.get("serving.answer", 0)
     ingest = sum(by_name.get(n, 0) for n in (
         "ingest.wait_source", "window.pack", "forest.window",
-        "forest.prep", "forest.place", "forest.dispatch"))
+        "forest.prep", "forest.place", "forest.dispatch",
+        "degrees.window", "degrees.prep", "degrees.dispatch"))
     serve = sum(by_name.get(n, 0) for n in (
         "serving.queue_wait", "serving.answer", "serving.device_wait"))
     return {"by_name": by_name,
@@ -210,7 +267,7 @@ def _clock_check(ctx: dict) -> dict:
     a = ctx["traced"]["lo"]           # perf_counter just before the mark
     mark = ctx["lo"]                  # the mark's start, profiler's clock
     out = {}
-    for name in ("forest.window", "serving.answer"):
+    for name in ("forest.window", "degrees.window", "serving.answer"):
         ann = sorted(
             s for p in ctx["planes"]
             if not trace_reduce.DEVICE_PLANE_RE.match(p["name"])
@@ -229,7 +286,7 @@ def _clock_check(ctx: dict) -> dict:
 
 def _dump(ctx: dict, out_dir: str, n_exec: int) -> None:
     """The stretch of the trace that holds the first whole executions of
-    ``jit_step`` in the window, as plain lists: the device's modules and
+    the cell's per-window program in the window, as plain lists: the device's modules and
     ops (names uncut, scopes in them), and the host annotations of the
     program's spans and the window's mark. What a test recording is cut
     from, and what to look at by hand."""
@@ -237,7 +294,7 @@ def _dump(ctx: dict, out_dir: str, n_exec: int) -> None:
 
     os.makedirs(out_dir, exist_ok=True)
     scoped = scope_reduce._scoped(ctx)
-    runs = scope_reduce.executions(scoped, "jit_step",
+    runs = scope_reduce.executions(scoped, STEP_PROGRAM[ctx["cell"].name],
                                    ctx["lo"], ctx["hi"])[:n_exec]
     lo, hi = runs[0][0] - 2e5, runs[-1][1] + 2e5
     span_names = {e["name"] for e in ctx["run"]["spans"]}
@@ -312,6 +369,8 @@ def main(argv=None) -> int:
         readers = [("events", _span_counts), ("clock", _clock_check)]
         if cell.name in V4:
             readers.append(("exchanges", _exchanges))
+        if cell.name in DYN:
+            readers.append(("table_ops", _table_ops))
         for key, fn in readers:
             try:
                 extras[key] = fn(ctx)
