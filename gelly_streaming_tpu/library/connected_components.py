@@ -44,6 +44,13 @@ Emission converts either carry to a
 ``DisjointSet`` stand-in); checkpoints always store canonical flat labels
 + touched, so the two carries share one checkpoint format.
 
+``component_sizes=True`` carries a second int32 table, ``sizes``, beside
+the forest and folds it in the window's own step (``summaries/forest.py``
+``fold_sizes``: one gather, one sum in fast memory and one sorted
+scatter, all window-sized), so that ``ComponentSizeQuery`` is one root
+chase and one gather out of the snapshot ``labels`` came in. Semantics,
+cost and what it refuses: :class:`ConnectedComponents`.
+
 ``superbatch=K`` fuses K consecutive windows into one dispatch on every
 carry (the small-window latency-cliff fix, ISSUE 2): the forest carry
 runs a group-local fused fold (one vcap-sized chase/commit per GROUP,
@@ -81,10 +88,13 @@ from ..summaries.forest import (
     forest_superbatch,
     forest_window,
     grow_forest,
+    grow_sizes,
     init_forest,
+    init_sizes,
     mirror_update,
     resolve_flat,
     resolve_flat_host,
+    sized_layout,
     vertex_layout,
 )
 from ..summaries.labels import (
@@ -139,11 +149,16 @@ def _auto_carry() -> str:
 
 
 class _CCMixin:
-    def __init__(self, *args, carry: str = "auto", **kwargs):
+    def __init__(self, *args, carry: str = "auto",
+                 component_sizes: bool = False, **kwargs):
         super().__init__(*args, **kwargs)
         if carry not in ("auto", "forest", "host", "dense"):
             raise ValueError(f"carry must be auto/forest/host/dense, got {carry!r}")
         self.carry = carry
+        self.component_sizes = bool(component_sizes)
+        if self.component_sizes:
+            self._refuse_unsized_carries()
+        self._sizes = None    # device size table (component_sizes)
         self._cc_mode = None  # None | "forest" | "host" | "dense"
         self._canon = None    # device pointer forest (forest/host carries)
         self._log = None      # host TouchLog
@@ -169,6 +184,21 @@ class _CCMixin:
         return Components.from_labels(state, vdict)
 
     # ---- windowed-carry run loop ---- #
+    def _refuse_unsized_carries(self) -> None:
+        """``component_sizes`` is folded by the forest's per-window step
+        alone; what else a constructor can ask for is refused here."""
+        if self.carry in ("host", "dense"):
+            raise NotImplementedError(
+                f"component_sizes with carry={self.carry!r}: the size table "
+                "is folded by the forest carry's device step; the host "
+                "union-find's mirror and the dense label table keep none"
+            )
+        if self.superbatch > 1 or self.superbatch_auto:
+            raise NotImplementedError(
+                "component_sizes with superbatch above 1: the group fold "
+                "(forest.group_body) carries no size table; run superbatch=1"
+            )
+
     def _vertex_sharded_mesh(self, mesh):
         """The forest carry is laid out over the ``vertices`` axis; what
         that layout lacks is refused here, before the first window."""
@@ -186,10 +216,14 @@ class _CCMixin:
     def _pick_carry(self) -> str:
         if self.carry != "auto":
             return self.carry
-        return "forest" if self._vmesh is not None else _auto_carry()
+        if self._vmesh is not None or self.component_sizes:
+            return "forest"
+        return _auto_carry()
 
     def run(self, stream) -> Iterator[Components]:
         mesh = self._resolve_mesh(stream)
+        if self.component_sizes:
+            sized_layout(mesh)
         self._vmesh = mesh if vertex_shards(mesh) > 1 else None
         eff_degree = getattr(self, "degree", 2)
         if mesh is not None and self._vmesh is None and self._is_tree():
@@ -275,6 +309,12 @@ class _CCMixin:
                     "device-transformed stream) folds through the dense "
                     "label table, which is not sharded over `vertices`"
                 )
+            if self.component_sizes:
+                raise NotImplementedError(
+                    "component_sizes: a window without host column views "
+                    "(a device-transformed stream) folds through the dense "
+                    "label table, which keeps no size table"
+                )
             if self._cc_mode in ("forest", "host"):
                 self._to_dense()
             self._cc_mode = "dense"
@@ -299,11 +339,15 @@ class _CCMixin:
                     self._vcap,
                 )
             else:
-                self._canon, tids = forest_window(
+                folded = forest_window(
                     self._canon, src_h, dst_h, self._vcap, self._prep,
                     mesh=mesh, tree=self._is_tree(),
-                    degree=eff_degree,
+                    degree=eff_degree, sizes=self._sizes,
                 )
+                if self.component_sizes:
+                    self._canon, tids, self._sizes = folded
+                else:
+                    self._canon, tids = folded
             self._log.add(tids)
             # sync()/bench barriers block on _summary; keep it aimed
             # at the live carry
@@ -429,10 +473,25 @@ class _CCMixin:
                 self._uf.load(np.asarray(self._canon))
             else:
                 self._prep = WindowPrep()
+            if self.component_sizes:
+                self._sizes = self._derive_sizes()
         if vcap > self._vcap:
             self._canon = grow_forest(self._canon, vcap, self._vmesh)
+            if self.component_sizes:
+                self._sizes = grow_sizes(self._sizes, vcap)
             self._vcap = vcap
         self._log.grow(self._vcap)
+
+    def _derive_sizes(self):
+        """The size table of the carry as it stands: ones beside a fresh
+        forest, and for restored labels the members counted per root
+        (the whole-table derivation the serving tier keeps for
+        snapshots without ``sizes``)."""
+        if self._summary is None or "touched" not in self._summary:
+            return init_sizes(self._vcap)
+        from ..serving.query import _component_size_table
+
+        return _component_size_table(self._canon)[1]
 
     def _to_dense(self) -> None:
         """Downgrade to the dense engine; the dense path owns growth from
@@ -452,6 +511,8 @@ class _CCMixin:
     def _reset_transient(self) -> None:
         if self._cc_mode in ("forest", "host"):
             self._canon = init_forest(self._vcap, self._vmesh)
+            if self.component_sizes:
+                self._sizes = init_sizes(self._vcap)
             self._log = TouchLog(self._vcap)
             self._summary = {"labels": self._canon}
             if self._cc_mode == "host":
@@ -480,6 +541,7 @@ class _CCMixin:
         # restored flat labels work as any carry
         self._cc_mode = None
         self._canon = None
+        self._sizes = None
         self._log = None
         self._uf = None
         self._prep = None
@@ -491,7 +553,9 @@ class _CCMixin:
         host carries — each window's functional scatter leaves the
         published buffer immutable) or the dense flat-label table; the
         :class:`~gelly_streaming_tpu.serving.query.QueryEngine` chases
-        either. Serves ``ConnectedQuery`` and ``ComponentSizeQuery``.
+        either. Serves ``ConnectedQuery`` and ``ComponentSizeQuery``;
+        with ``component_sizes=True`` every snapshot also holds
+        ``sizes``, folded by the same step as ``labels``.
         ``vdict`` seeds the boot payload when restoring from a
         checkpoint before any stream is attached."""
         return CCServable(self, vdict)
@@ -515,7 +579,8 @@ class CCServable:
     published one is immutable; under a ``vertices`` mesh axis it is the
     sharded array itself, a block of rows a chip, and is never gathered)
     or the dense flat table — plus the stream's vertex dict for raw-id
-    resolution.
+    resolution. An aggregation built with ``component_sizes=True``
+    publishes its size table ``sizes`` in the same payload.
 
     SUPERBATCH GRANULARITY: with ``superbatch=K`` the aggregation
     yields a group's K emissions after its fused fold, so the live
@@ -559,6 +624,10 @@ class CCServable:
         else:
             return None
         payload = {"labels": labels, "vdict": vdict}
+        if agg._sizes is not None:
+            # folded by the step that made ``labels``: one snapshot, one
+            # prefix, and ready on one is ready on both
+            payload["sizes"] = agg._sizes
         log = getattr(agg, "_log", None)
         if log is not None:
             # the TouchLog novelty shadow rides every snapshot (count-
@@ -603,7 +672,21 @@ class CCServable:
 
 
 class ConnectedComponents(_CCMixin, SummaryBulkAggregation):
-    """Flat-combine streaming CC (``library/ConnectedComponents.java``)."""
+    """Flat-combine streaming CC (``library/ConnectedComponents.java``).
+
+    ``carry`` picks the carried summary (module docstring; ``"auto"``).
+    ``component_sizes=True`` carries a size table beside the pointer
+    forest and publishes it with every snapshot, so that
+    ``ComponentSizeQuery(v)`` answers ``sizes[root(v)]`` from the
+    snapshot ``root(v)`` was chased in: every id of the id space is a
+    vertex and starts as a component of size 1 (over ``IdentityDict``
+    an untouched id answers 1), sizes are exact at the roots of the
+    stamped prefix, duplicate edges and self-loops change nothing. It
+    costs one more ``vcap``-row int32 table a snapshot and window-sized
+    work a step; without it the query derives a whole size table per
+    snapshot version. Forest carry on one chip only: ``carry="host"``
+    or ``"dense"``, ``superbatch`` above 1 and a ``vertices`` or
+    ``edges`` mesh axis above 1 each raise ``NotImplementedError``."""
 
     @classmethod
     def sliding(cls, size: int, slide=None, **kwargs):

@@ -14,9 +14,13 @@ per query class with ONE jitted lookup:
 - Degree / rank queries are one table gather; a degree-count query is
   the same gather out of the degree histogram published beside the
   degree table.
-- Component-size queries canonicalize the forest once per snapshot
-  version (cached) and bincount, then answer any number of batches from
-  the cached size table.
+- Component-size queries over a snapshot that holds ``sizes`` (a size
+  table carried beside the forest, ``ConnectedComponents(
+  component_sizes=True)``) share the batch's ONE root chase with the
+  ``ConnectedQuery`` ids and gather their sizes at the roots behind it:
+  one wait for both. Over a snapshot without it they canonicalize the
+  forest once per snapshot version (cached) and bincount, then answer
+  any number of batches from the cached size table.
 
 Batch id arrays are padded to power-of-two buckets so a serving session
 compiles O(log batch-size) jit signatures, the stream-ingest convention
@@ -112,7 +116,17 @@ class RankQuery(Query):
 
 @dataclass(frozen=True)
 class ComponentSizeQuery(Query):
-    """Size of ``v``'s component (0 for a never-seen vertex)."""
+    """Size of ``v``'s component (0 for a vertex the payload's vertex
+    dict cannot decode; over ``IdentityDict`` every id of the id space
+    is a vertex, and one the stream never touched answers 1).
+
+    Over a snapshot that holds ``sizes`` (``ConnectedComponents(
+    component_sizes=True)``) the answer is ``sizes[root(v)]`` from the
+    SAME snapshot ``root(v)`` was chased in: one batched root chase,
+    shared with the sweep's ``ConnectedQuery`` ids, and one gather.
+    Over a snapshot without it the engine canonicalizes the whole
+    ``labels`` table and counts its members once per snapshot version,
+    which in a served stream is once per window."""
 
     v: int
 
@@ -364,15 +378,17 @@ def _pad_ids(ids: np.ndarray) -> np.ndarray:
     return out
 
 
-def _fetch(out: jax.Array, n: int) -> np.ndarray:
-    """The first ``n`` lanes of a dispatched batch kernel's result, on
-    the host. The copy blocks until the device has run the kernel, which
+def _fetch(out, n: int):
+    """The first ``n`` lanes of a dispatched batch kernel's result (of
+    each result of a tuple dispatched back to back), on the host. The copy blocks until the device has run the kernel, which
     queues behind every fold dispatched before it: the span
     ``serving.device_wait`` is that wait alone (the dispatch stays
     outside it), the fold's part of ``serving.answer``."""
     with _trace.span(
         "serving.device_wait", {"n": n} if _trace.on() else None
     ):
+        if isinstance(out, tuple):
+            return tuple(np.asarray(x)[:n] for x in out)
         return np.asarray(out)[:n]
 
 
@@ -490,11 +506,18 @@ class QueryEngine:
             # np.asarray waits for THIS array's producer, not the whole
             # dispatch queue — the property the host path exists for
             cached = np.asarray(table)
-            self._host_cache.clear()  # only the newest version is hot
+            # only the newest version is hot, with every table it holds
+            for old in [k for k in self._host_cache if k[:2] != ck[:2]]:
+                del self._host_cache[old]
             self._host_cache[ck] = cached
         return cached
 
-    def _roots(self, table, ids: np.ndarray) -> np.ndarray:
+    def _roots(self, table, ids: np.ndarray, sizes=None):
+        """Roots of ``ids`` in ``table``: ONE batched chase. With
+        ``sizes`` (a size table of the same snapshot) also
+        ``sizes[root]`` of every id, ``(roots, sizes)``: the gather is
+        enqueued behind the chase with no host read between, so one
+        wait brings both back."""
         if table_vertex_shards(table) > 1:
             # the ids go to every chip; the roots come back whole
             mesh = table.sharding.mesh
@@ -502,11 +525,12 @@ class QueryEngine:
                 table, jax.device_put(_pad_ids(ids), replicated(mesh)))
             return _fetch(out, len(ids))
         if self.prefer_host:
-            return _host_batch_roots(table, ids)
-        return _fetch(
-            _batch_roots(jnp.asarray(table), jnp.asarray(_pad_ids(ids))),
-            len(ids),
-        )
+            roots = _host_batch_roots(table, ids)
+            return roots if sizes is None else (roots, sizes[roots])
+        out = _batch_roots(jnp.asarray(table), jnp.asarray(_pad_ids(ids)))
+        if sizes is not None:
+            out = (out, _gather(jnp.asarray(sizes), out))
+        return _fetch(out, len(ids))
 
     # -- per-class batch kernels --------------------------------------- #
     def connected(
@@ -514,30 +538,65 @@ class QueryEngine:
     ) -> np.ndarray:
         """bool[n]: same component per (u, v) pair, one batched chase for
         all 2n endpoints."""
+        return self._same_root(us, vs, *self._chase(
+            snap, np.concatenate([np.asarray(us), np.asarray(vs)])))
+
+    def _chase(self, snap: PublishedSnapshot, raw: np.ndarray,
+               sizes: bool = False):
+        """``(valid, roots)`` of the raw ids, or ``(valid, roots,
+        sizes[roots])`` over the snapshot's own size table: ONE lookup
+        (the batched native lookup takes the encoder mutex once per
+        call, so a call per endpoint column would double lock
+        contention with the ingest thread) and ONE chase for them all.
+        An id the dict cannot decode chases from 0 and is not valid."""
         canon = self._table(snap, "labels")
-        vdict = snap.payload["vdict"]
-        # ONE lookup for all 2n endpoints: the batched native lookup
-        # takes the encoder mutex once per call, so separate u/v calls
-        # would double lock contention with the ingest thread
-        both = _lookup_batch(
-            vdict, np.concatenate([np.asarray(us), np.asarray(vs)])
-        )
-        vcap = int(canon.shape[0])
-        valid = (both >= 0) & (both < vcap)
-        roots = self._roots(canon, np.where(valid, both, 0))
+        cv = _lookup_batch(snap.payload["vdict"], raw)
+        valid = (cv >= 0) & (cv < int(canon.shape[0]))
+        safe = np.where(valid, cv, 0)
+        if not sizes:
+            return valid, self._roots(canon, safe)
+        return (valid,) + self._roots(
+            canon, safe, self._table(snap, "sizes"))
+
+    @staticmethod
+    def _same_root(us, vs, valid, roots) -> np.ndarray:
         n = len(us)
-        ru, rv = roots[:n], roots[n:]
-        ok = valid[:n] & valid[n:]
+        ok = valid[:n] & valid[n:2 * n]
         # an unseen vertex is its own singleton: connected only to itself
-        return np.where(ok, ru == rv, np.asarray(us) == np.asarray(vs))
+        return np.where(ok, roots[:n] == roots[n:2 * n],
+                        np.asarray(us) == np.asarray(vs))
+
+    def connected_and_sizes(
+        self, snap: PublishedSnapshot, us: np.ndarray, vs: np.ndarray,
+        ws: np.ndarray,
+    ) -> Tuple[np.ndarray, np.ndarray]:
+        """``(bool[n], int[m])``: same component per (u, v) pair and the
+        component size of every ``w``, over a snapshot that holds
+        ``sizes``: ONE chase for all ``2n + m`` ids, the size lanes'
+        gather behind it, one wait (span ``serving.size_lookup``)."""
+        raw = np.concatenate([np.asarray(x, np.int64) for x in (us, vs, ws)])
+        with _trace.span(
+            "serving.size_lookup",
+            {"n": len(ws), "ids": len(raw)} if _trace.on() else None,
+        ):
+            valid, roots, sizes = self._chase(snap, raw, sizes=True)
+        m = 2 * len(us)
+        return (self._same_root(us, vs, valid, roots),
+                np.where(valid[m:], sizes[m:], 0).astype(np.int64))
 
     def component_size(
         self, snap: PublishedSnapshot, vs: np.ndarray
     ) -> np.ndarray:
-        """int[n] component sizes; the size table derives once per
-        snapshot version. Sizes count COMPACT ids sharing the root —
-        vertices the stream has actually seen (plus the queried vertex's
-        own singleton when it is seen but never merged)."""
+        """int[n] component sizes. Over a snapshot that holds ``sizes``:
+        one chase and one gather (:meth:`connected_and_sizes`), every
+        slot of the table a vertex. Over one without, the size table
+        derives once per snapshot version, and sizes count COMPACT ids
+        sharing the root — vertices the stream has actually seen (plus
+        the queried vertex's own singleton when it is seen but never
+        merged)."""
+        if "sizes" in snap.payload:
+            none = np.zeros(0, np.int64)
+            return self.connected_and_sizes(snap, none, none, vs)[1]
         canon = self._table(snap, "labels", whole="ComponentSizeQuery")
         vdict = snap.payload["vdict"]
         cv = _lookup_batch(vdict, vs)
@@ -937,6 +996,21 @@ class QueryEngine:
         groups: Dict[type, List[int]] = {}
         for i, q in enumerate(queries):
             groups.setdefault(type(q), []).append(i)
+        merged: Dict[type, np.ndarray] = {}
+        if ("sizes" in snap.payload and ConnectedQuery in groups
+                and ComponentSizeQuery in groups):
+            # both classes read roots of ONE forest: one chase, one wait
+            pairs = [queries[i] for i in groups[ConnectedQuery]]
+            merged = dict(zip(
+                (ConnectedQuery, ComponentSizeQuery),
+                self.connected_and_sizes(
+                    snap,
+                    np.asarray([q.u for q in pairs], np.int64),
+                    np.asarray([q.v for q in pairs], np.int64),
+                    np.asarray([queries[i].v
+                                for i in groups[ComponentSizeQuery]],
+                               np.int64),
+                )))
         for qcls, idxs in groups.items():
             key = self.PAYLOAD_KEYS.get(qcls)
             if key is None or key not in snap.payload:
@@ -963,7 +1037,9 @@ class QueryEngine:
                         boot=getattr(snap, "boot", ""),
                     )
                 continue
-            if qcls is ConnectedQuery:
+            if qcls in merged:
+                vals = merged[qcls]
+            elif qcls is ConnectedQuery:
                 us = np.asarray([queries[i].u for i in idxs], np.int64)
                 vs = np.asarray([queries[i].v for i in idxs], np.int64)
                 vals = self.connected(snap, us, vs)

@@ -148,7 +148,7 @@ def _cover_step_fn(tcap: int, wcap: int, vcap: int):
         )
 
         def step(canon, failed, tid, tmask, lu, lv, emask):
-            canon, nr = body(
+            canon, nr, _sizes = body(
                 canon, *_cover_lanes(tid, tmask, lu, lv, emask, tcap, vcap)
             )
             with jax.named_scope("forest.latch"):

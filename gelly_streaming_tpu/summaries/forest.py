@@ -36,6 +36,10 @@ Per window, every kernel is sized by the window, not the vertex space:
    representative (:func:`_make_local_fixpoint`).
 4. A pair of masked scatters re-roots the old roots (and the touched
    vertices, for path compression) to the merged component's min root.
+5. Where CC carries a size table beside the forest
+   (``ConnectedComponents(component_sizes=True)``), one more gather and
+   one more sorted scatter, both window-sized, move every merged
+   group's member count to its new root (:func:`fold_sizes`).
 
 Steps 2 to 4 are written ONCE: :func:`window_body` (``chase_and_group``,
 the local fixpoint of :func:`_make_local_fixpoint`, ``commit_roots``)
@@ -62,9 +66,10 @@ loop) and ``forest.narrow`` (the compaction, the slab's loop and the
 write-back), ``forest.group`` (its vcap-sized same-root
 scratch), ``forest.fixpoint`` (step 3) and inside it
 ``forest.contract`` (its once-a-step part: the endpoints relabelled,
-the labels read back), ``forest.commit`` (step 4), inside group and
-commit ``forest.sort`` (the sort ahead of each table-sized scatter)
-and, on the cover, ``forest.latch``. A device
+the labels read back), ``forest.commit`` (step 4), where a size table
+is carried beside the forest ``forest.sizes`` (:func:`fold_sizes`),
+inside group, commit and sizes ``forest.sort`` (the sort ahead of each
+table-sized scatter) and, on the cover, ``forest.latch``. A device
 trace carries the scope in
 the ``tf_op`` stat of each ``XLA Ops`` event's metadata; the jitted
 programs keep the name ``jit_step``. On the host one window is the span ``forest.window`` with
@@ -281,6 +286,24 @@ def vertex_layout(mesh, superbatch: bool = False) -> int:
     return shards
 
 
+def sized_layout(mesh) -> None:
+    """Refuses, in one place, the layouts a size table carried beside
+    the forest (:func:`fold_sizes`) is not built for: any mesh axis
+    above 1."""
+    if vertex_shards(mesh) > 1:
+        raise NotImplementedError(
+            "component_sizes: the size table is carried whole on one chip; "
+            "splitting it by rows beside a forest sharded over a `vertices` "
+            "axis above 1 is not built"
+        )
+    if mesh is not None and mesh.shape.get(EDGE_AXIS, 1) > 1:
+        raise NotImplementedError(
+            f"component_sizes under mesh {dict(mesh.shape)}: the sized "
+            "step has run on one chip only; under an `edges` axis above 1 "
+            "it is not built"
+        )
+
+
 def chase_roots(canon, r0, tab: TableOps = None):
     """Follow every lane of ``r0`` along ``canon`` to its root: the one
     pointer chase of the repo (the forest steps and the serving tier's
@@ -416,6 +439,28 @@ def reroot(canon, nr, r, tid, tmask, vcap: int, tab: TableOps = None):
     return tab.scatter(canon, tid_s, nr)
 
 
+def fold_sizes(sizes, r, v2, local, nr, tmask, lanes, vcap: int,
+               tab: TableOps = None):
+    """The size table's back phase of a window (scope ``forest.sizes``):
+    ``sizes[root]`` is the number of ids in ``root``'s component, exact
+    at every root and never read anywhere else. A merged group's new
+    size is the sum over its DISTINCT old roots: each old root's size is
+    gathered out of the table (window-sized) and kept on the one
+    representative lane of its same-root group (``v2[i] == i``), the
+    kept sizes are summed by the lane's label in the quotient graph
+    (``local``) in fast memory, and every lane writes its group's sum at
+    its new root ``nr``: one sorted scatter, and ``set`` is sound since
+    the lanes of a group all write one value to one row (as in
+    :func:`reroot`). A group that merged nothing rewrites its own size;
+    an old root that is no longer a root keeps what it last held."""
+    tab = tab or TableOps(vcap)
+    with jax.named_scope("forest.sizes"):
+        old = tab.gather(sizes, jnp.where(tmask, r, 0))
+        kept = jnp.where(tmask & (v2 == lanes), old, 0)
+        tot = jnp.zeros(lanes.shape[0], jnp.int32).at[local].add(kept)
+        return tab.scatter(sizes, jnp.where(tmask, nr, vcap), tot[local])
+
+
 def _make_local_fixpoint(tcap: int, mesh=None, tree: bool = False,
                          degree: int = 2):
     """The T-sized local min-label fixpoint of every forest program, CC's
@@ -486,23 +531,30 @@ def _make_local_fixpoint(tcap: int, mesh=None, tree: bool = False,
 
 def window_body(tcap: int, vcap: int, tab: TableOps, fixpoint):
     """THE forest fold of one window, over ``tab``'s layout of a
-    ``vcap``-row table: ``body(canon, tid, tmask, lu, lv, emask=None) ->
-    (canon, nr)`` is :func:`chase_and_group`, the local ``fixpoint``
-    (:func:`_make_local_fixpoint`; scope ``forest.fixpoint``) seeded
-    from the lane iota with each lane's same-root group's min lane as
-    targets, and :func:`commit_roots`. CC's step is this body and
-    returns ``canon``; the cover's doubles the lanes, masks its pad rows
-    and reads its latch off ``nr`` (``candidates.py``)."""
+    ``vcap``-row table: ``body(canon, tid, tmask, lu, lv, emask=None,
+    sizes=None) -> (canon, nr, sizes)`` is :func:`chase_and_group`, the
+    local ``fixpoint`` (:func:`_make_local_fixpoint`; scope
+    ``forest.fixpoint``) seeded from the lane iota with each lane's
+    same-root group's min lane as targets, :func:`commit_roots` and,
+    where a size table is carried beside the forest, :func:`fold_sizes`
+    (``sizes`` comes back as it went in otherwise: ``None``). CC's step
+    is this body and returns ``canon`` (with sizes: ``(canon,
+    sizes)``); the cover's doubles the lanes, masks its pad rows and
+    reads its latch off ``nr`` (``candidates.py``)."""
 
-    def body(canon, tid, tmask, lu, lv, emask=None):
+    def body(canon, tid, tmask, lu, lv, emask=None, sizes=None):
         r, v2, key_, lanes = chase_and_group(
             canon, tid, tmask, tcap, vcap, tab
         )
         with jax.named_scope("forest.fixpoint"):
             local = fixpoint(lanes, lu, lv, v2, emask)
-        return commit_roots(
+        canon, nr = commit_roots(
             canon, local, key_, r, tid, tmask, tcap, vcap, tab
         )
+        if sizes is not None:
+            sizes = fold_sizes(sizes, r, v2, local, nr, tmask, lanes, vcap,
+                               tab)
+        return canon, nr, sizes
 
     return body
 
@@ -569,9 +621,12 @@ def group_body(tcap: int, vcap: int, fixpoint):
 
 
 def _forest_step_fn(tcap: int, wcap: int, vcap: int, mesh=None,
-                    tree: bool = False, degree: int = 2):
+                    tree: bool = False, degree: int = 2,
+                    sizes: bool = False):
     """CC's jitted per-window program: :func:`window_body`, returning
-    the table (under ``shard_map`` when the table is split by rows)."""
+    the table (under ``shard_map`` when the table is split by rows).
+    With ``sizes`` the size table rides as a last argument and ONE
+    program returns ``(canon, sizes)``: ready on one is ready on both."""
 
     def build():
         shards = vertex_layout(mesh)
@@ -584,14 +639,27 @@ def _forest_step_fn(tcap: int, wcap: int, vcap: int, mesh=None,
             ),
         )
 
-        def step(canon, tid, tmask, lu, lv):
-            return body(canon, tid, tmask, lu, lv)[0]
+        if sizes:
+            sized_layout(mesh)
+
+            # every forest program is ``jit_step`` (the device trace's
+            # readers look for it)
+            def step(canon, tid, tmask, lu, lv, size_table):
+                canon, _nr, size_table = body(
+                    canon, tid, tmask, lu, lv, sizes=size_table
+                )
+                return canon, size_table
+        else:
+            def step(canon, tid, tmask, lu, lv):
+                return body(canon, tid, tmask, lu, lv)[0]
 
         if shards > 1:
             step = sharded_table_fn(step, mesh, 4, table_out=True)
         return jax.jit(step)
 
-    return cached_step(("cc", tcap, wcap, vcap, mesh, tree, degree), build)
+    return cached_step(
+        ("cc", tcap, wcap, vcap, mesh, tree, degree, sizes), build
+    )
 
 
 def _forest_superbatch_fn(tcap: int, wcap: int, vcap: int, k: int,
@@ -632,6 +700,21 @@ def init_forest(vcap: int, mesh=None) -> jax.Array:
     return jax.jit(comm.shard_map(
         lambda: _own_rows(vcap // shards), mesh, (), P(VERTEX_AXIS)
     ))()
+
+
+def init_sizes(vcap: int) -> jax.Array:
+    """Fresh size table beside a fresh forest: every id its own
+    component of one."""
+    return jnp.ones(vcap, jnp.int32)
+
+
+def grow_sizes(sizes: jax.Array, new_vcap: int) -> jax.Array:
+    """The size table at ``new_vcap`` rows, the new ones components of
+    one (beside :func:`grow_forest`)."""
+    old = sizes.shape[0]
+    if new_vcap <= old:
+        return sizes
+    return jnp.concatenate([sizes, jnp.ones(new_vcap - old, jnp.int32)])
 
 
 def grow_forest(canon: jax.Array, new_vcap: int, mesh=None) -> jax.Array:
@@ -809,7 +892,8 @@ def forest_window(
     mesh=None,
     tree: bool = False,
     degree: int = 2,
-) -> Tuple[jax.Array, np.ndarray]:
+    sizes: jax.Array = None,
+) -> tuple:
     """Fold one window (host compact-id columns) into the forest.
 
     ``prep`` is REQUIRED: it is the reusable per-stream scratch (native
@@ -823,6 +907,11 @@ def forest_window(
     by position or treats them as a set) — the caller maintains the host
     first-seen log for emission. All device inputs are bucketed to
     powers of two so a stream hits O(log^2) jit signatures.
+
+    With ``sizes`` (the size table carried beside the forest,
+    :func:`fold_sizes`) the one program folds both and the result is
+    ``(new_canon, touched_ids, new_sizes)``; the span ``forest.window``
+    then carries the attribute ``sizes``.
     """
     if prep is None:
         raise ValueError(
@@ -830,9 +919,11 @@ def forest_window(
             "is reusable by design; allocating one per window would "
             "silently re-create the native handle and vcap-sized table)"
         )
+    sized = sizes is not None
     n = len(src_h)
     if n == 0:
-        return canon, np.zeros(0, np.int32)
+        tids = np.zeros(0, np.int32)
+        return (canon, tids, sizes) if sized else (canon, tids)
     wmin = 8
     if mesh is not None:
         # the sharded columns must divide by the axis size; passing it as
@@ -845,6 +936,8 @@ def forest_window(
             prep, src_h, dst_h, vcap, wmin
         )
         note_buckets(sp, tids, tcap, wcap)
+        if sized and sp.recording:
+            sp.set(sizes=True)
         cols = (tid, tmask, lu, lv)
         if shards > 1:
             note_owners(sp, tids, vcap, shards)
@@ -853,9 +946,15 @@ def forest_window(
                 # columns a chip
                 cols = jax.device_put(cols, replicated(mesh))
         with _trace.span("forest.dispatch"):
-            step = _forest_step_fn(tcap, wcap, vcap, mesh, tree, degree)
-            canon = step(canon, *(jnp.asarray(c) for c in cols))
-    return canon, tids
+            step = _forest_step_fn(
+                tcap, wcap, vcap, mesh, tree, degree, sizes=sized
+            )
+            cols = tuple(jnp.asarray(c) for c in cols)
+            if sized:
+                canon, sizes = step(canon, *cols, sizes)
+            else:
+                canon = step(canon, *cols)
+    return (canon, tids, sizes) if sized else (canon, tids)
 
 
 class ForestReplay:

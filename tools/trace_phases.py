@@ -11,7 +11,9 @@ ones ``PROPOSED`` lists: the forest step's phases and loop trips
 of each, ``forest_wide_rounds`` and ``forest_narrow_rounds``), the
 degree step's phases (the
 ``dd-g500-s28`` cell: ``degrees.sort|scan|gather|scatter|hist`` inside
-``jit_degree_step``), the host fold's spans and the serving waits. They are not entries of ``BENCHMARK.json``: the harness
+``jit_degree_step``), the size table's back phase (the
+``ccsize-g500-s28`` cell: ``forest.sizes`` inside ``jit_step``, and the
+size lanes' ``jit__gather``), the host fold's spans and the serving waits. They are not entries of ``BENCHMARK.json``: the harness
 requires every per-layer metric of a cell on the line of a traced run,
 and a program that lacks the span or scope (the parent of the PR that
 adds it) would then print no line at all. Until a ``benchmark`` PR
@@ -55,8 +57,9 @@ SAT = ["cc-g500-s28.ingest-saturated", "bip-g500-s27.ingest-saturated-poll"]
 CC = ["cc-g500-s28.ingest-saturated", "cc-g500-s28.paced-query-heavy"]
 V4 = ["cc-g500-s30-v4.ingest-saturated"]
 DYN = ["dd-g500-s28.ingest-saturated-dyn"]
+SIZE = ["ccsize-g500-s28.ingest-saturated-size"]
 #: the per-window program of a cell (the one whose phases are read)
-STEP_PROGRAM = {**dict.fromkeys(SAT + CC + V4, "jit_step"),
+STEP_PROGRAM = {**dict.fromkeys(SAT + CC + V4 + SIZE, "jit_step"),
                 **dict.fromkeys(DYN, "jit_degree_step")}
 DEGREE_PHASES = ("sort", "scan", "gather", "scatter", "hist")
 
@@ -140,6 +143,33 @@ PROPOSED = {
     "answer_wait_ms.dyn": ("ms", "serving", "query_p95_ms", DYN,
                            {"kind": "span_mean_ms",
                             "span": "serving.device_wait"}),
+    # the sized CC cell (ccsize-g500-s28): the forest step's phases and
+    # the size table's back phase (``forest.sizes``: the gather of the
+    # old roots' sizes, the sum in fast memory, the sorted scatter; its
+    # sort is one more under ``forest.sort``), the sweep's one wait and
+    # the size lanes' gather behind the chase
+    **{f"forest_{p}_ms.size": ("ms", "forest step", "edges_per_s", SIZE,
+                               _scope("scope_mean_ms", f"forest.{p}"))
+       for p in ("chase", "group", "fixpoint", "commit", "sizes", "sort",
+                 "contract", "narrow")},
+    **{f"forest_{p}_rounds.size": ("count", "forest step", "edges_per_s",
+                                   SIZE,
+                                   _scope("scope_rounds_mean", f"forest.{p}"))
+       for p in ("chase", "fixpoint", "wide", "narrow")},
+    "fold_host_ms.size": ("ms", "window host step", "edges_per_s", SIZE,
+                          {"kind": "span_mean_ms", "span": "forest.window"}),
+    "fold_dispatch_ms.size": ("ms", "window host step", "edges_per_s", SIZE,
+                              {"kind": "span_mean_ms",
+                               "span": "forest.dispatch"}),
+    "queue_wait_ms.size": ("ms", "serving", "query_p95_ms", SIZE,
+                           {"kind": "span_mean_ms",
+                            "span": "serving.queue_wait"}),
+    "answer_wait_ms.size": ("ms", "serving", "query_p95_ms", SIZE,
+                            {"kind": "span_mean_ms",
+                             "span": "serving.device_wait"}),
+    "size_gather_ms.size": ("ms", "query kernels", "query_p95_ms", SIZE,
+                            {"kind": "program_mean_ms",
+                             "program": "jit__gather"}),
 }
 
 
@@ -179,11 +209,11 @@ def phases_block(m: dict) -> dict:
                 **{f"{p}_ms": v for p, v in parts.items()}}
     step = next((m[k]["value"] for k in m if k.startswith("forest_step_ms")),
                 None)
-    tag = next((t for t in (".sat", ".v4") if f"forest_chase_ms{t}" in m),
-               ".sat")
-    # the exchanges run inside chase and group, the sorts inside group
-    # and commit, the contraction inside the fixpoint, the slab inside
-    # the chase: beside the sum, not in it
+    tag = next((t for t in (".sat", ".v4", ".size")
+                if f"forest_chase_ms{t}" in m), ".sat")
+    # the exchanges run inside chase and group, the sorts inside group,
+    # commit and sizes, the contraction inside the fixpoint, the slab
+    # inside the chase: beside the sum, not in it
     nested = {f"forest_{p}_ms{tag}": p
               for p in ("exchange", "sort", "contract", "narrow")}
     parts = {k: m[k]["value"] for k in m
@@ -269,7 +299,8 @@ def _span_counts(ctx: dict) -> dict:
         "forest.prep", "forest.place", "forest.dispatch",
         "degrees.window", "degrees.prep", "degrees.dispatch"))
     serve = sum(by_name.get(n, 0) for n in (
-        "serving.queue_wait", "serving.answer", "serving.device_wait"))
+        "serving.queue_wait", "serving.answer", "serving.size_lookup",
+        "serving.device_wait"))
     return {"by_name": by_name,
             "per_window": ingest / windows if windows else None,
             "per_sweep": serve / sweeps if sweeps else None}
@@ -385,7 +416,7 @@ def main(argv=None) -> int:
         readers = [("events", _span_counts), ("clock", _clock_check)]
         if cell.name in V4:
             readers.append(("exchanges", _exchanges))
-        if cell.name in DYN:
+        if cell.name in DYN + SIZE:
             readers.append(("table_ops", _table_ops))
         for key, fn in readers:
             try:
