@@ -26,9 +26,10 @@ Per window, every kernel is sized by the window, not the vertex space:
    chase runs full-width rounds only while more than ``T / 8`` lanes
    still move, then compacts those into a slab of ``T / 8`` lanes and
    finishes there, scopes ``forest.wide`` and ``forest.narrow``).
-3. One scatter-min into a vcap-sized scratch gives every touched lane
-   the representative (min lane) of its "same current root" group. The
-   window's edges are relabelled through it, once, and a min-label
+3. Two sorts of the touched lanes and a doubling between them, all
+   window-sized, give every touched lane the representative (min lane)
+   of its "same current root" group (:func:`group_reps`). The window's
+   edges are relabelled through it, once, and a min-label
    fixpoint over the **local** T-sized table (exactly the dense
    kernel's hook+shortcut, on a table the size of the window) joins the
    representatives: connected components of the window's quotient
@@ -52,9 +53,9 @@ All four programs live in one bounded cache (:func:`cached_step`).
 
 The remaining vcap-sized costs are the functional scatter's buffer copy
 (which is also what keeps per-window emissions valid snapshots — the
-pre-scatter buffer stays alive for any lazy emission holding it), the
-step-2 scratch and the three table-sized scatters. What each phase
-costs on the chip, per cell, is measured and kept in ``PERF.md``
+pre-scatter buffer stays alive for any lazy emission holding it) and
+the commit's two table-sized scatters. What each phase costs on the
+chip, per cell, is measured and kept in ``PERF.md``
 section 5 (the rates in ``PERF_LEDGER.jsonl``); no CPU timing says
 anything about it.
 
@@ -63,14 +64,14 @@ inside the jitted step, shared by the per-window and the superbatch
 steps of both carries (CC and the signed cover) — ``forest.chase``
 (step 2's pointer chase) and inside it ``forest.wide`` (the full-width
 loop) and ``forest.narrow`` (the compaction, the slab's loop and the
-write-back), ``forest.group`` (its vcap-sized same-root
-scratch), ``forest.fixpoint`` (step 3) and inside it
+write-back), ``forest.group`` (step 3's same-root grouping of
+the lanes), ``forest.fixpoint`` (step 3's fixpoint) and inside it
 ``forest.contract`` (its once-a-step part: the endpoints relabelled,
 the labels read back), ``forest.commit`` (step 4), where a size table
 is carried beside the forest ``forest.sizes`` (:func:`fold_sizes`),
-inside group, commit and sizes ``forest.sort`` (the sort ahead of each
-table-sized scatter) and, on the cover, ``forest.latch``. A device
-trace carries the scope in
+inside group, commit and sizes ``forest.sort`` (the group's two sorts
+of the lanes, and the sort ahead of each table-sized scatter) and, on
+the cover, ``forest.latch``. A device trace carries the scope in
 the ``tf_op`` stat of each ``XLA Ops`` event's metadata; the jitted
 programs keep the name ``jit_step``. On the host one window is the span ``forest.window`` with
 the children ``forest.prep`` (step 1 and the padding) and
@@ -82,12 +83,13 @@ contiguous block of rows. Every access to the ``vcap``-sized table goes
 through one pair of primitives, :class:`TableOps` ``gather`` and
 ``scatter``; with no axis they are ``table[idx]`` and, behind a sort
 of the (row, value) lanes by row (scope ``forest.sort``),
-``table.at[idx].set/min(mode="drop", indices_are_sorted=True)``; with
+``table.at[idx].set(mode="drop", indices_are_sorted=True)``; with
 the axis the whole step runs under ``shard_map``, the lanes are
 replicated, the owner of a row answers a gather and one all-reduce
 (scope ``forest.exchange``) makes the lanes whole, and a scatter sorts
-and applies the lanes this chip owns with no exchange. The host then
-also emits ``forest.place`` (child of
+and applies the lanes this chip owns with no exchange; the grouping of
+the lanes touches no table, so every chip does it whole and alike. The
+host then also emits ``forest.place`` (child of
 ``forest.window``: the window's columns placed on every chip) and the
 attributes ``shards`` and ``owner_max_share`` on ``forest.window``.
 
@@ -175,11 +177,11 @@ def _table_combine(tcap: int):
 
 class TableOps:
     """The one pair of primitives through which a step reads and writes
-    a ``vcap``-sized table: a gather of lanes and a masked scatter of
-    lanes (``set`` or ``min``).
+    a ``vcap``-sized table: a gather of lanes and a masked scatter
+    (``set``) of lanes.
 
     ``TableOps(vcap)`` is the whole table on one chip: ``table[idx]``
-    and ``table.at[idx].set/min(val, mode="drop")`` over lanes sorted by
+    and ``table.at[idx].set(val, mode="drop")`` over lanes sorted by
     row.
 
     Every scatter goes out sorted: the (row, value) pairs are sorted by
@@ -188,10 +190,9 @@ class TableOps:
     the chip a table-sized scatter of unsorted lanes costs 91 ns a lane
     and one of sorted lanes 17 ns; the sort, in fast memory, under a
     microsecond a thousand lanes. The table that comes out is the same
-    on every row: ``min`` does not care for order, and the callers'
-    ``set`` writes one value to a row however often the row repeats.
-    ``unique_indices`` is NOT said: an old root repeats once per touched
-    member, and the pads all sit on one sentinel.
+    on every row: the callers write one value to a row however often
+    the row repeats. ``unique_indices`` is NOT said: an old root repeats
+    once per touched member, and the pads all sit on one sentinel.
 
     ``TableOps(vcap, shards, exchange)`` is one chip's block of a table
     split over the ``vertices`` axis, and is used INSIDE ``shard_map``
@@ -225,10 +226,6 @@ class TableOps:
         off = idx - lax.axis_index(VERTEX_AXIS).astype(idx.dtype) * self.rows
         return (off >= 0) & (off < self.rows), off
 
-    def full(self, fill):
-        """This chip's block of a fresh int32 table."""
-        return jnp.full(self.rows, fill, jnp.int32)
-
     def gather(self, table, idx):
         if self.shards == 1:
             return table[idx]
@@ -237,7 +234,7 @@ class TableOps:
         with jax.named_scope(self.exchange):
             return lax.psum(got, VERTEX_AXIS)
 
-    def scatter(self, table, idx, val, op: str = "set"):
+    def scatter(self, table, idx, val):
         if self.shards > 1:
             mine, off = self._local(idx)
             idx = jnp.where(mine, off, self.rows)
@@ -246,10 +243,7 @@ class TableOps:
         # the order they came in
         with jax.named_scope("forest.sort"):
             idx, val = lax.sort((idx, val), num_keys=2)
-        at = table.at[idx]
-        return (at.min if op == "min" else at.set)(
-            val, mode="drop", indices_are_sorted=True
-        )
+        return table.at[idx].set(val, mode="drop", indices_are_sorted=True)
 
 
 def sharded_table_fn(fn, mesh, n_lanes: int, table_out: bool):
@@ -369,25 +363,58 @@ def chase_roots(canon, r0, tab: TableOps = None):
         return r.at[at].set(r_s, mode="drop", unique_indices=True)
 
 
-def chase_and_group(canon, tid, tmask, tcap: int, vcap: int,
-                    tab: TableOps = None):
+def group_reps(r, tmask, vcap: int):
+    """Every live lane's same-root group's smallest lane index (its
+    representative), a pad lane itself: a window-sized fact, found in
+    window-sized work. ONE sort of the
+    lanes by (root, lane) lays every group out as a run that opens on
+    its smallest lane (pads sort to the end under the sentinel
+    ``vcap``, nobody's row). Every lane of a run then takes the run's
+    first by doubling, in fast memory: after the step of stride ``k`` a
+    lane holds the first of the ``2 * k`` places up to its own, as far
+    as they lie in its run (a sorted run is one stretch, so the lane
+    ``k`` places back is in it if its root is equal). A second sort,
+    keyed by lane, brings the representatives back to lane order; both
+    sorts stand under ``forest.sort``. Nothing has a row per vertex,
+    and the lanes are whole on every chip of a ``vertices`` axis: no
+    scratch table, and no exchange. On the chip, at 2^17 / 2^18 lanes
+    (``PERF.md`` section 6, PR 37): 0.26 / 0.59 ms a step, all but 0.01
+    of it the two sorts; ``lax.associative_scan`` in the doubling's
+    place 0.54 / 1.12, a ``cummax`` of the runs' opening places and one
+    read through it 1.18 at 2^17, a scatter back in the second sort's
+    place 0.4 / 0.9 more."""
+    # an iota of its own: sorted in place as the callers' would be, that
+    # one (the fixpoint's seed) reaches its loop through a copy in slow
+    # memory, and the sized step's rounds cost twice (PERF.md, PR 37)
+    lanes = jnp.arange(r.shape[0], dtype=jnp.int32)
+    with jax.named_scope("forest.sort"):
+        s_r, first = lax.sort((jnp.where(tmask, r, vcap), lanes), num_keys=2)
+    s_lane, k = first, 1
+    while k < lanes.shape[0]:
+        first = jnp.concatenate([first[:k], jnp.where(
+            s_r[k:] == s_r[:-k], first[:-k], first[k:])])
+        k *= 2
+    with jax.named_scope("forest.sort"):
+        _, rep = lax.sort((s_lane, first), num_keys=1)
+    return jnp.where(tmask, rep, lanes)
+
+
+def chase_and_group(canon, tid, tmask, vcap: int, tab: TableOps = None):
     """Shared forest-step front half (CC + signed-cover carries).
 
     1. Chase touched pointers to their current roots
        (:func:`chase_roots`). Padding lanes chase from 0, which is
        always self-rooted (canon[0] <= 0).
-    2. "Same current root" constraints: scatter-min each lane's local
-       index into a vcap scratch keyed by root, so every lane learns
-       its group's representative lane — a memset, a scatter and a
-       gather. The scatter's lanes are sorted by root first
-       (:meth:`TableOps.scatter`: 17 against 91 ns a lane on the chip).
-       ``rep_i`` stands for lane i's whole group; pads self-loop.
+    2. "Same current root" constraints: every lane learns its group's
+       representative lane, the smallest lane that shares its root
+       (:func:`group_reps`: two sorts and a doubling over the ``tcap``
+       lanes; the table is not touched). ``rep_i`` stands for lane i's
+       whole group; pads self-loop.
 
     Returns ``(r, v2, key_, iota)``: current roots per lane, each
     lane's group's representative (a depth-1 forest: ``v2[v2] == v2``),
     the root-value keys (+inf on pads), and the lane iota.
-    ``tab`` is the table's layout (default: whole, on one chip); the
-    scratch is laid out like the table.
+    ``tab`` is the table's layout (default: whole, on one chip).
     """
     tab = tab or TableOps(vcap)
     with jax.named_scope("forest.chase"):
@@ -395,14 +422,8 @@ def chase_and_group(canon, tid, tmask, tcap: int, vcap: int,
             canon, jnp.where(tmask, tab.gather(canon, tid), 0), tab
         )
     with jax.named_scope("forest.group"):
-        iota = jnp.arange(tcap, dtype=jnp.int32)
-        sid_r = jnp.where(tmask, r, vcap)
-        scratch = tab.scatter(
-            tab.full(_I32_MAX), sid_r,
-            jnp.where(tmask, iota, _I32_MAX), "min",
-        )
-        rep = tab.gather(scratch, jnp.where(tmask, r, 0))
-        v2 = jnp.where(tmask, rep, iota)
+        iota = jnp.arange(tid.shape[0], dtype=jnp.int32)
+        v2 = group_reps(r, tmask, vcap)
         key_ = jnp.where(tmask, r, _I32_MAX)
     return r, v2, key_, iota
 
@@ -543,9 +564,7 @@ def window_body(tcap: int, vcap: int, tab: TableOps, fixpoint):
     reads its latch off ``nr`` (``candidates.py``)."""
 
     def body(canon, tid, tmask, lu, lv, emask=None, sizes=None):
-        r, v2, key_, lanes = chase_and_group(
-            canon, tid, tmask, tcap, vcap, tab
-        )
+        r, v2, key_, lanes = chase_and_group(canon, tid, tmask, vcap, tab)
         with jax.named_scope("forest.fixpoint"):
             local = fixpoint(lanes, lu, lv, v2, emask)
         canon, nr = commit_roots(
@@ -567,13 +586,12 @@ def group_body(tcap: int, vcap: int, fixpoint):
 
     The naive fusion — scanning the per-window body with the vcap-sized
     canon as the carry — still pays vcap-sized work per window (XLA
-    materializes carry updates, and the group-rep scratch memset is
-    vcap-wide), which is exactly the cost shape the forest carry exists
-    to avoid. This body instead hoists ALL vcap-sized work to the group
-    boundary:
+    materializes carry updates), which is exactly the cost shape the
+    forest carry exists to avoid. This body instead hoists ALL
+    vcap-sized work to the group boundary:
 
     1. ONE root chase + same-root grouping over the group's union
-       touched set (``chase_and_group`` — one vcap scratch memset per
+       touched set (``chase_and_group``: one chase out of the table per
        GROUP, not per window);
     2. a ``lax.scan`` over the K windows whose carry is only the
        T-sized local label table: window k folds its edge columns into
@@ -598,7 +616,7 @@ def group_body(tcap: int, vcap: int, fixpoint):
     replaces the per-window path's copy per WINDOW."""
 
     def body(canon, tid, tmask, lu, lv, emask=None):
-        r, v2, key_, _lanes = chase_and_group(canon, tid, tmask, tcap, vcap)
+        r, v2, key_, _lanes = chase_and_group(canon, tid, tmask, vcap)
 
         def fold(lab, cols):
             lu_k, lv_k, *em_k = cols

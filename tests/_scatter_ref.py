@@ -13,13 +13,12 @@ from gelly_streaming_tpu.datasets import rmat_edges
 from gelly_streaming_tpu.summaries import candidates, forest
 
 
-def plain_scatter(self, table, idx, val, op: str = "set"):
+def plain_scatter(self, table, idx, val):
     """``TableOps.scatter`` with the lanes in the order they come."""
     if self.shards > 1:
         mine, off = self._local(idx)
         idx = jnp.where(mine, off, self.rows)
-    at = table.at[idx]
-    return (at.min if op == "min" else at.set)(val, mode="drop")
+    return table.at[idx].set(val, mode="drop")
 
 
 def _clear_steps():
@@ -111,23 +110,34 @@ def scoped_lanes(text: str, name: str) -> list:
     return out
 
 
+def ops_under(text: str, scope: str) -> list:
+    """The line that carries the location (and, for an op with a region,
+    the types) of every operation under the named scope ``scope``."""
+    lines = text.splitlines()
+    paths = dict(m.groups() for m in map(_LOC_DEF.match, lines) if m)
+    used = ((_LOC_USE.search(ln), ln) for ln in lines)
+    return [ln for m, ln in used
+            if m and scope in paths.get(m.group(1), "").split("/")]
+
+
 def _from_scope(path: str) -> str:
     """``jit(step)/[shard_map/]forest.group/...`` from its phase on."""
     return path[path.index("forest."):]
 
 
 def assert_table_scatters_go_out_sorted(text: str) -> None:
-    """The group's scatter-min and the commit's two sets say
-    ``indices_are_sorted``, each behind a sort under ``forest.sort``;
-    the window-sized scatters stay as they were."""
+    """The commit's two sets say ``indices_are_sorted``, each behind a
+    sort under ``forest.sort``; the window-sized scatters stay as they
+    were. Since ISSUE 37 the group scatters nothing: its two sorts (by
+    root, and back by lane) stand under ``forest.group/forest.sort``."""
     scatters = scoped_ops(text, "scatter")
     said = [_from_scope(path) for path, ln in scatters
             if "indices_are_sorted = true" in ln]
-    assert said == ["forest.group/scatter-min", "forest.commit/scatter",
-                    "forest.commit/scatter"]
-    assert len(scatters) == 6
+    assert said == ["forest.commit/scatter", "forest.commit/scatter"]
+    assert len(scatters) == 5
     assert all("unique_indices = false" in ln for _p, ln in scatters)
     sorts = [_from_scope(path) for path, _ln in scoped_ops(text, "sort")]
     assert sorts == ["forest.group/forest.sort/sort",
+                     "forest.group/forest.sort/sort",
                      "forest.commit/forest.sort/sort",
                      "forest.commit/forest.sort/sort"]

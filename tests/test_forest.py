@@ -527,7 +527,12 @@ def test_ccs_lowered_step_holds_no_constant_the_size_of_its_lanes():
     # has no slab, and its text is shorter for that and no constant's sake
     small = _lowered_step("cc-step", 1 << 12, 1 << 11, 1 << 14)
     cells = _lowered_step("cc-step", 1 << 17, 1 << 16, 1 << 28)
-    assert len(cells) < 100_000 and abs(len(cells) - len(small)) < 1_000
+    # the group's doubling (ISSUE 37) is unrolled: one stretch of ops,
+    # some 1,600 characters, for every doubling of the lanes, so five
+    # more at the cells' 2^17 lanes than at 2^12; a literal of ``tcap``
+    # entries would be a quarter of a megabyte
+    assert len(cells) < 100_000
+    assert 0 <= len(cells) - len(small) < 5 * 2_000
     assert not re.search(r"stablehlo\.constant dense<[^>]{64,}>", cells)
 
 

@@ -358,9 +358,12 @@ def test_a_shard_count_that_is_no_power_of_two_is_refused(shards):
 #: before this layout was added; the two texts were identical to the
 #: parent's letter for letter when this was written. ISSUE 33 added the
 #: three gathers of the fixpoint's contraction (window-sized, off the
-#: table: no all-reduce comes with them)
+#: table: no all-reduce comes with them); ISSUE 37 took the group's
+#: scatter-min into a table-sized scratch and its gather back out (two
+#: sorts of the lanes and a doubling stand in their place), and with the
+#: gather its all-reduce
 PARENT_OPS = {
-    "step": {"gather": 11, "scatter": 6, "while": 2},
+    "step": {"gather": 10, "scatter": 5, "while": 2},
     "batch_roots": {"gather": 3, "scatter": 0, "while": 1},
 }
 COLLECTIVES = ("all_reduce", "all_gather", "all_to_all",
@@ -398,7 +401,7 @@ def test_the_mesh_less_program_is_the_one_the_cells_run(which):
 
 
 @pytest.mark.parametrize("which,exchange,n", [
-    ("step", "forest.exchange", 4), ("batch_roots", "query.exchange", 3)])
+    ("step", "forest.exchange", 3), ("batch_roots", "query.exchange", 3)])
 def test_the_sharded_program_keeps_its_name_and_scopes_its_collectives(
         mesh, which, exchange, n):
     """Same gathers, scatters and loops; one all-reduce a gather, each
@@ -420,40 +423,42 @@ def test_the_sharded_program_keeps_its_name_and_scopes_its_collectives(
 # --------------------------------------------------------------------- #
 # every scatter into the table goes out sorted (ISSUE 31)
 # --------------------------------------------------------------------- #
-def _scatter_lanes(seed: int, vcap: int, lanes: int, op: str):
+def _scatter_lanes(seed: int, vcap: int, lanes: int, rows: str):
     """Lanes out of order, a third of them pads at the sentinel
-    ``vcap``, rows that repeat: with one value a row for ``set`` (what
-    the commit writes), with unequal ones for ``min``."""
+    ``vcap``: rows that repeat with one value a row (what the commit
+    and the size phase write), or rows met once with a value each (what
+    the degree step writes)."""
     rng = np.random.default_rng(seed)
-    idx = rng.integers(0, vcap, lanes // 8).astype(np.int32)
-    idx = rng.choice(idx, lanes).astype(np.int32)
-    val = (idx * 7 + 3 if op == "set"
-           else rng.integers(0, 1 << 20, lanes)).astype(np.int32)
+    if rows == "repeated":
+        idx = rng.integers(0, vcap, lanes // 8).astype(np.int32)
+        idx = rng.choice(idx, lanes).astype(np.int32)
+        val = (idx * 7 + 3).astype(np.int32)
+    else:
+        idx = rng.choice(vcap, lanes, replace=False).astype(np.int32)
+        val = rng.integers(0, 1 << 20, lanes).astype(np.int32)
     idx[rng.random(lanes) < 1 / 3] = vcap
-    assert len(np.unique(idx)) < lanes // 4 and (np.diff(idx) < 0).any()
+    assert (np.diff(idx) < 0).any()
+    assert (len(np.unique(idx)) < lanes // 4) == (rows == "repeated")
     return idx, val
 
 
 @pytest.mark.parametrize("seed", [7, 2**31 + 9])
-@pytest.mark.parametrize("op", ["set", "min"])
+@pytest.mark.parametrize("rows", ["repeated", "unique"])
 @pytest.mark.parametrize("shards", [1, SHARDS])
 def test_the_tables_scatter_is_the_plain_one_lane_order_and_all(
-        mesh, shards, op, seed):
+        mesh, shards, rows, seed):
     vcap, lanes = 1 << 10, 512
-    idx, val = _scatter_lanes(seed, vcap, lanes, op)
+    idx, val = _scatter_lanes(seed, vcap, lanes, rows)
     table = np.random.default_rng(seed + 1).integers(
         1 << 19, 1 << 21, vcap).astype(np.int32)
     want = table.copy()
     keep = idx < vcap
-    if op == "set":
-        want[idx[keep]] = val[keep]
-    else:
-        np.minimum.at(want, idx[keep], val[keep])
+    want[idx[keep]] = val[keep]
     tab = forest.TableOps(vcap, shards)
 
     def run(scatter, order):
         def fn(t, i, v):
-            return scatter(tab, t, i, v, op)
+            return scatter(tab, t, i, v)
 
         if shards > 1:
             fn = forest.sharded_table_fn(fn, mesh, 2, table_out=True)
@@ -465,8 +470,8 @@ def test_the_tables_scatter_is_the_plain_one_lane_order_and_all(
     got = run(forest.TableOps.scatter, order)
     assert np.array_equal(got, want)
     assert np.array_equal(got, run(plain_scatter, order))
-    assert np.array_equal(got, np.asarray(getattr(
-        jnp.asarray(table).at[jnp.asarray(idx)], op)(
+    assert np.array_equal(got, np.asarray(
+        jnp.asarray(table).at[jnp.asarray(idx)].set(
             jnp.asarray(val), mode="drop")))
     assert np.array_equal(
         got, run(forest.TableOps.scatter, order[::-1].copy()))

@@ -76,8 +76,9 @@ PROPOSED = {
        for p in ("chase", "group", "fixpoint", "commit")},
     "forest_latch_ms.sat": ("ms", "forest step", "edges_per_s", SAT[1:],
                             _scope("scope_mean_ms", "forest.latch")),
-    # the sorts ahead of the three table-sized scatters: inside group
-    # and commit, so NOT a phase to add to their sum
+    # the group's two sorts of the lanes and the sorts ahead of the
+    # commit's two table-sized scatters: inside group and commit, so
+    # NOT a phase to add to their sum
     "forest_sort_ms.sat": ("ms", "forest step", "edges_per_s", SAT,
                            _scope("scope_mean_ms", "forest.sort")),
     # the fixpoint's once-a-step part (the endpoints relabelled through
@@ -108,8 +109,8 @@ PROPOSED = {
                        {"kind": "span_mean_ms",
                         "span": "serving.device_wait"}),
     # the vertex-sharded cell: the same phases on chip 0's line, the
-    # sorts, and the collectives that make the lanes whole (inside chase
-    # and group, so NOT a phase to add to their sum)
+    # sorts, and the collectives that make the lanes whole (inside the
+    # chase, so NOT a phase to add to the sum)
     **{f"forest_{p}_ms.v4": ("ms", "forest step", "edges_per_s", V4,
                              _scope("scope_mean_ms", f"forest.{p}"))
        for p in ("chase", "group", "fixpoint", "commit", "sort",
@@ -211,7 +212,7 @@ def phases_block(m: dict) -> dict:
                 None)
     tag = next((t for t in (".sat", ".v4", ".size")
                 if f"forest_chase_ms{t}" in m), ".sat")
-    # the exchanges run inside chase and group, the sorts inside group,
+    # the exchanges run inside the chase, the sorts inside group,
     # commit and sizes, the contraction inside the fixpoint, the slab
     # inside the chase: beside the sum, not in it
     nested = {f"forest_{p}_ms{tag}": p
