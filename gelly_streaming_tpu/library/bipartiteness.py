@@ -187,8 +187,11 @@ class BipartitenessCheck(SummaryBulkAggregation):
                     self._to_dense()
                 self._bp_mode = "dense"
                 self._device_block(block, mesh)
-                self._sync_ref = self._summary
-                yield self.transform(self._summary, vdict)
+                with _trace.span("window.emit"):
+                    self._sync_ref = self._summary
+                    out = self.transform(self._summary, vdict)
+                yield out
+                del out  # the loop's frame must not hold a table
             else:
                 if self._bp_mode is None:
                     self._bp_mode = (
@@ -207,14 +210,20 @@ class BipartitenessCheck(SummaryBulkAggregation):
                     # half derives as base + vcap at emission/checkpoint
                     # time, so growth never needs a log rebuild and held
                     # emissions cannot leak grown ids into the negative
-                    # half
-                    self._log.add(tids)
-                    self._summary = {"labels": self._canon}
-                    self._sync_ref = (self._canon, self._failed)
-                    yield Candidates.from_forest(
-                        self._canon, self._failed, self._log,
-                        self._log.count, self._vcap, vdict,
-                    )
+                    # half. The span is CC's (``_one_window``): the log
+                    # and the emission, closed before the yield
+                    with _trace.span("window.emit") as sp:
+                        fresh = self._log.add(tids)
+                        self._summary = {"labels": self._canon}
+                        self._sync_ref = (self._canon, self._failed)
+                        out = Candidates.from_forest(
+                            self._canon, self._failed, self._log,
+                            self._log.count, self._vcap, vdict,
+                        )
+                        if sp.recording:
+                            sp.set(fresh=fresh)
+                    yield out
+                    del out  # the loop's frame must not hold a table
             if self.transient_state:
                 self._reset_transient()
 
@@ -231,15 +240,19 @@ class BipartitenessCheck(SummaryBulkAggregation):
             np.concatenate([r, cr]),
             2 * vcap,
         )
-        if not self._failed:
-            self._failed = _delta_conflict(t, r, vcap)
-        self._log.add(t[t < vcap])
-        self._summary = {"labels": self._canon}
-        self._sync_ref = self._canon
-        return Candidates.from_forest(
-            self._canon, self._failed, self._log, self._log.count,
-            vcap, vdict,
-        )
+        with _trace.span("window.emit") as sp:
+            if not self._failed:
+                self._failed = _delta_conflict(t, r, vcap)
+            fresh = self._log.add(t[t < vcap])
+            self._summary = {"labels": self._canon}
+            self._sync_ref = self._canon
+            out = Candidates.from_forest(
+                self._canon, self._failed, self._log, self._log.count,
+                vcap, vdict,
+            )
+            if sp.recording:
+                sp.set(fresh=fresh)
+        return out
 
     # ---- GroupFoldable declaration (summaries/groupfold.py) ---------- #
     def fold_group(self, group) -> Iterator[Candidates]:

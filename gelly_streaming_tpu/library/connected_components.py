@@ -319,8 +319,10 @@ class _CCMixin:
                 self._to_dense()
             self._cc_mode = "dense"
             self._device_block(block, mesh)
-            self._sync_ref = self._summary
-            yield self.transform(self._summary, vdict)
+            with _trace.span("window.emit"):
+                self._sync_ref = self._summary
+                out = self.transform(self._summary, vdict)
+            yield out
         else:
             if self._cc_mode is None:
                 self._cc_mode = self._pick_carry()
@@ -348,12 +350,20 @@ class _CCMixin:
                     self._canon, tids, self._sizes = folded
                 else:
                     self._canon, tids = folded
-            self._log.add(tids)
-            # sync()/bench barriers block on _summary; keep it aimed
-            # at the live carry
-            self._summary = {"labels": self._canon}
-            self._sync_ref = self._canon
-            yield Components.from_forest(self._canon, self._log, vdict)
+            # what follows the fold on the host: the first-seen log
+            # (a gather and a scatter of the touched ids in a table of a
+            # row per vertex) and the lazy emission. Closed before the
+            # yield (core/window.py _timed_pulls says why)
+            with _trace.span("window.emit") as sp:
+                fresh = self._log.add(tids)
+                # sync()/bench barriers block on _summary; keep it aimed
+                # at the live carry
+                self._summary = {"labels": self._canon}
+                self._sync_ref = self._canon
+                out = Components.from_forest(self._canon, self._log, vdict)
+                if sp.recording:
+                    sp.set(fresh=fresh)
+            yield out
         if self.transient_state:
             self._reset_transient()
 

@@ -187,10 +187,15 @@ class DegreeDistribution:
                         self._deg, self._hist,
                         block.src, block.dst, block.val, block.mask,
                     )
-            self._events_total += n_events
-            yield HistogramBatch(
-                self, self._hist, self._events_total, self._inc_total
-            )
+            # closed before the yield: a span open across it would
+            # mis-nest the ingest thread's stack (core/window.py)
+            with _trace.span("window.emit"):
+                self._events_total += n_events
+                batch = HistogramBatch(
+                    self, self._hist, self._events_total, self._inc_total
+                )
+            yield batch
+            del batch  # the loop's frame must not hold a table
 
     def _size_tables(self, block, cache) -> int:
         """Allocate or grow the two carried tables for ``block``; returns

@@ -53,6 +53,22 @@ dispatch (+ ``engine.donated_dispatches`` counter),
 prefetch coupling, ``checkpoint.barrier`` / ``barrier_wait`` /
 ``serialize``, and the ``serving.*`` admission/batch/drain surface.
 
+A served window's host life is ONE span tree on the ingest thread
+(``serving/server.py``): the root ``serving.window`` (attributes
+``window``, ``in_flight``, ``ring``; never a profiler annotation, so a
+device trace's idle gaps still go to its children) over
+``ingest.wait_source``, ``window.pack``, the fold's span
+(``forest.window`` / ``degrees.window`` and their children),
+``window.emit`` (the touch log and the emission; ``fresh``) and
+``serving.publish`` (``evicted``, ``evicted_addr``). The root's SELF
+time is the host time of a window that no span names. The gauge
+``serving.windows_in_flight`` is the operator's reading of who sets
+the pace, set at every publish while tracing is on: the published
+tables still being computed, the one just published among them. At the
+ingest loop's depth (2 under a closed loop of 2) the device does; at 1
+the device was waiting for the window just dispatched (the host or the
+source sets the pace); at 0 the fold was over before it was published.
+
 Resilience events (PR 4) are ALWAYS on — a restart or a rejected
 checkpoint is operational truth, not optional telemetry:
 ``resilience.restarts{kind}`` / ``recovery_seconds`` /

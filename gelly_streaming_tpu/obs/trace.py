@@ -339,6 +339,9 @@ class _NoopSpan:
     def set(self, **attrs):
         return self
 
+    def cancel(self):
+        return self
+
 
 NOOP_SPAN = _NoopSpan()
 
@@ -347,12 +350,15 @@ class Span:
     """One recorded stage. Use via ``with span("pack", {...}):``."""
 
     __slots__ = ("name", "attrs", "sid", "parent", "depth", "t0",
-                 "dur_s", "_ann", "ctx")
+                 "dur_s", "_ann", "ctx", "annotate", "cancelled")
     recording = True
 
-    def __init__(self, name: str, attrs: Optional[dict] = None):
+    def __init__(self, name: str, attrs: Optional[dict] = None,
+                 annotate: bool = True):
         self.name = name
         self.attrs = attrs
+        self.annotate = annotate
+        self.cancelled = False
         self.sid = 0
         self.parent = None
         self.depth = 0
@@ -368,6 +374,13 @@ class Span:
         if self.attrs is None:
             self.attrs = {}
         self.attrs.update(attrs)
+        return self
+
+    def cancel(self) -> "Span":
+        """This span turned out to time nothing (the pull that found the
+        stream at its end): it still leaves the thread's stack on exit,
+        but emits no event and observes nothing."""
+        self.cancelled = True
         return self
 
     def __enter__(self) -> "Span":
@@ -387,7 +400,7 @@ class Span:
         else:
             self.parent = None
         stack.append(self)
-        if _CFG.annotate_jax:
+        if _CFG.annotate_jax and self.annotate:
             try:
                 import jax
 
@@ -411,6 +424,8 @@ class Span:
             stack.pop()
         elif stack and self in stack:  # mis-nested exit: drop through it
             del stack[stack.index(self):]
+        if self.cancelled:
+            return False
         event = {
             "kind": "span",
             "name": self.name,
@@ -437,17 +452,26 @@ class Span:
         return False
 
 
-def span(name: str, attrs: Optional[dict] = None):
+def span(name: str, attrs: Optional[dict] = None, annotate: bool = True):
     """A context manager timing one named stage (no-op when disabled).
 
     ``attrs`` is an optional plain dict of span attributes (window
     index, superbatch K, block edges, ...). Truly hot call sites guard
     with :func:`on` before building the dict; everywhere else the dict
     literal's cost is negligible next to the stage it measures.
+
+    ``annotate=False`` keeps the span out of the profiler even under
+    ``enable(jax_annotations=True)``: an event for the sinks only. For
+    a span that ENCLOSES a whole unit of work (``serving.window``, one
+    per window around everything the ingest thread does for it): a
+    reader that gives each idle gap of the device to the host
+    annotation covering most of it would give every gap to the
+    enclosing one and never name a child. ``t0`` still places the
+    event on a device trace (ONE CLOCK, above).
     """
     if not _CFG.enabled:
         return NOOP_SPAN
-    return Span(name, attrs)
+    return Span(name, attrs, annotate)
 
 
 def current_span() -> Optional[Span]:

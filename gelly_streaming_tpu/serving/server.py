@@ -7,7 +7,10 @@ consumers never stall it):
 - **ingest thread**: drives the servable's emission iterator (any
   per-window payload stream) and publishes one immutable snapshot per
   window into the :class:`~.snapshot_store.SnapshotStore`. Publishing is
-  one atomic reference swap, so ingest never waits on readers.
+  one atomic reference swap, so ingest never waits on readers. With
+  tracing on, each window is one span tree under ``serving.window``
+  (the pull, the publish, and how many published tables the device
+  still owed: ``obs/__init__.py``).
 - **query worker thread**: drains ALL currently-pending queries in one
   sweep, groups them by class, and answers each group with one
   vectorized :class:`~.query.QueryEngine` kernel against the latest
@@ -41,6 +44,10 @@ from .query import Answer, Query, QueryEngine
 from .snapshot_store import PublishedSnapshot, SnapshotStore
 from .stats import ServingStats
 from .txn import PinnedQuery, TxnSnapshotExpired
+
+
+#: the payload iterator's end, told apart from a window's item
+_NO_WINDOW = object()
 
 
 def _unwrap(q):
@@ -261,34 +268,70 @@ class StreamServer:
             )
         return iter(self._servable)
 
+    def _publish_window(self, payload, watermark: int) -> None:
+        # a mirror follower smuggles the PRIMARY's version and boot
+        # lineage through the payload (carry_version) so a standby's
+        # ring mirrors the primary's stamps; pop the smuggled keys off
+        # a COPY — the published payload must look like any other
+        # servable payload
+        version = boot = None
+        if hasattr(payload, "get") and "snap_version" in payload:
+            payload = dict(payload)
+            version = int(payload.pop("snap_version"))
+            boot = payload.pop("snap_boot", None)
+        # the publish drops the ring's oldest snapshot, as a rule the
+        # last reference to its table: the buffer goes back to the
+        # allocator HERE, on the ingest thread, then the waiters and
+        # the listeners run
+        with _trace.span("serving.publish") as sp:
+            if sp.recording:
+                # which window's snapshot this publish pushes out of the
+                # ring, noted by index: no reference is held across the
+                # publish, so the table is freed where it always was
+                ring = self.store.ring()
+                oldest, depth = (ring[-1].window if ring else -1), len(ring)
+                del ring
+            # an event-time pipeline's servable carries its watermark
+            # stamp in the payload; count windows do not (-1 = "no
+            # event time", the Answer default)
+            self.store.publish(
+                payload, self._window, watermark,
+                event_ts=int(payload.get("event_ts", -1))
+                if hasattr(payload, "get") else -1,
+                version=version, boot=boot,
+            )
+            if sp.recording:
+                full = self.store.ring_depth() == depth
+                sp.set(evicted=oldest if full else -1)
+
     def _ingest(self) -> None:
         it = self._payload_iter()
         try:
-            for payload, watermark in it:
-                if self._stop_ingest.is_set():
-                    break
-                if payload is None:  # a window with nothing servable
-                    continue
-                self._window += 1
-                # a mirror follower smuggles the PRIMARY's version and
-                # boot lineage through the payload (carry_version) so a
-                # standby's ring mirrors the primary's stamps; pop the
-                # smuggled keys off a COPY — the published payload must
-                # look like any other servable payload
-                version = boot = None
-                if hasattr(payload, "get") and "snap_version" in payload:
-                    payload = dict(payload)
-                    version = int(payload.pop("snap_version"))
-                    boot = payload.pop("snap_boot", None)
-                # an event-time pipeline's servable carries its
-                # watermark stamp in the payload; count windows do not
-                # (-1 = "no event time", the Answer default)
-                self.store.publish(
-                    payload, self._window, int(watermark),
-                    event_ts=int(payload.get("event_ts", -1))
-                    if hasattr(payload, "get") else -1,
-                    version=version, boot=boot,
-                )
+            while True:
+                # the window's host life under ONE root: the pull (the
+                # source's wait, the pack, the fold's host side, the
+                # emission) and the publish are its children, and what
+                # it holds beside them is host time nobody names yet.
+                # An event for the sinks alone (annotate=False): as an
+                # annotation it would cover every idle gap of a device
+                # trace and hide its own children there
+                with _trace.span("serving.window", annotate=False) as root:
+                    item = next(it, _NO_WINDOW)
+                    if item is _NO_WINDOW or self._stop_ingest.is_set():
+                        root.cancel()
+                        break
+                    payload, watermark = item
+                    if payload is None:  # a window with nothing servable
+                        root.cancel()
+                        continue
+                    self._window += 1
+                    self._publish_window(payload, int(watermark))
+                    if root.recording:
+                        in_flight = self.store.in_flight()
+                        root.set(window=self._window, in_flight=in_flight,
+                                 ring=self.store.ring_depth())
+                        get_registry().gauge(
+                            "serving.windows_in_flight").set(in_flight)
         except BaseException as e:  # surfaced via join()/close()
             self._ingest_error = e
         finally:

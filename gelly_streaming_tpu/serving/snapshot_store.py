@@ -209,6 +209,21 @@ class SnapshotStore:
         """How many snapshots the retention ring currently holds."""
         return len(self._recent)
 
+    def ring(self) -> tuple:
+        """The retained snapshots, newest first (the immutable tuple
+        itself: one reference read). Holding it holds their tables."""
+        return self._recent
+
+    def in_flight(self) -> int:
+        """How many retained snapshots are still being computed on the
+        device: one ``is_ready`` probe an array, never a wait. Read
+        right after a publish it says who sets the pace: at the ingest
+        loop's depth (2 under a closed loop of 2) the device does; at 1
+        only the window just dispatched is outstanding and the device
+        was waiting for it; at 0 the fold was over before the host had
+        published it."""
+        return sum(not _payload_ready(s.payload) for s in self._recent)
+
     def head_window(self) -> int:
         """Window index of the newest snapshot; -2 before any publish
         (so a boot snapshot's ``-1`` still reads as ahead of nothing)."""

@@ -1379,12 +1379,13 @@ class TouchLog:
                 [self.seen, np.zeros(vcap - len(self.seen), bool)]
             )
 
-    def add(self, tids: np.ndarray) -> None:
+    def add(self, tids: np.ndarray) -> int:
+        """Take in the ids not seen before; returns how many there were."""
         fresh = tids[~self.seen[tids]]
-        if len(fresh) == 0:
-            return
-        self.seen[fresh] = True
-        self._append(fresh)
+        if len(fresh):
+            self.seen[fresh] = True
+            self._append(fresh)
+        return len(fresh)
 
     def _append(self, fresh: np.ndarray) -> None:
         need = self.count + len(fresh)

@@ -16,6 +16,19 @@ sys.path.insert(0, ROOT)
 import chip_smoke  # noqa: E402
 
 
+@pytest.fixture(autouse=True)
+def _cold_programs():
+    """The smoke's body counts on a process that has compiled nothing of
+    the fold yet: its first window then compiles ("set-up"), and while
+    it does a live query is answered. A test file that folded the same
+    shapes on this xdist worker before this one (``tests/test_control.py``
+    does) would leave every program warm: no set-up window, and four
+    tiny windows folded before the first answer."""
+    import jax
+
+    jax.clear_caches()
+
+
 def test_body_runs_and_matches_the_oracle_at_a_tiny_size(tmp_path):
     result = chip_smoke.run_smoke(
         str(tmp_path), scale=10, window=256, n_windows=8,

@@ -13,12 +13,31 @@ degree step's phases (the
 ``dd-g500-s28`` cell: ``degrees.sort|scan|gather|scatter|hist`` inside
 ``jit_degree_step``), the size table's back phase (the
 ``ccsize-g500-s28`` cell: ``forest.sizes`` inside ``jit_step``, and the
-size lanes' ``jit__gather``), the host fold's spans and the serving waits. They are not entries of ``BENCHMARK.json``: the harness
-requires every per-layer metric of a cell on the line of a traced run,
-and a program that lacks the span or scope (the parent of the PR that
-adds it) would then print no line at all. Until a ``benchmark`` PR
-takes them over (PERF.md, section 7) this is where they are read. The
-reader kinds are registered here, at run time; no file of the
+size lanes' ``jit__gather``), the window's host life under its root
+span (``window.emit``, ``serving.publish`` and the root's self time) and,
+in the four-chip, the sized and the degree cell, the host fold's spans
+and the serving waits. What still keeps a metric HERE and out of
+``BENCHMARK.json`` (PERF.md, section 7, "What the harness needs"):
+
+- its span is one the PARENT of the PR that adds it does not emit: the
+  harness requires every per-layer metric of a cell on the line of a
+  traced run, so the parent would print no line at all. The first later
+  PR whose parent emits the span enters it, with data files alone (the
+  kind ``span_mean_ms`` is the harness's own). PR 38 entered so the
+  host fold's spans and the serving waits of cell 1, the cover and the
+  paced cell, which every parent since PR 26 emits, and left the three
+  new spans of its own here;
+- its kind is one of the ``scope_*`` kinds (``scope_reduce.READERS``),
+  which the harness does not know yet;
+- its cell is the four-chip, the degree or the sized cell: tests of
+  ``tests/bench_harness/`` (``test_perf_degdist.py``,
+  ``test_perf_ccsize.py``) hold the SET of each of those cells'
+  per-layer metrics, and ``test_perf_trace.py`` reads every
+  ``program_mean_ms`` entry of every cell in a recording of cell 1, so
+  an entry more in one of them fails a test only a ``benchmark`` PR
+  may edit.
+
+The reader kinds are registered here, at run time; no file of the
 benchmark is edited.
 
 The last line of standard output is the result document, with
@@ -30,12 +49,18 @@ contraction under ``forest.contract`` and, where the table is
 sharded over chips, the collectives of a step under ``forest.exchange``:
 their ms, their count and the span attributes ``shards`` and
 ``owner_max_share``), ``events`` (span
-events per window and per sweep) and ``clock`` (how far a span's ``t0``,
+events per window and per sweep), ``window`` (the host life of a window
+under its root span ``serving.window``: the mean of the root, of each
+child and of the root's SELF time, which is the host time no span names;
+the mean and the histogram of ``in_flight``, the published tables still
+being computed at a publish; span events a window) and ``clock`` (how far a
+span's ``t0``,
 mapped through the traced slice's bracket, lies from the same span's
 annotation on the profiler's clock) beside the harness's keys.
 ``--dump`` writes the first executions' stretch of the trace as plain
 lists, names uncut and scopes in them, for a by-hand look and for
-cutting a test recording.
+cutting a test recording, and beside it (``spans.json``) the program's
+span events of the measured window with the collector's pauses.
 """
 
 from __future__ import annotations
@@ -62,6 +87,16 @@ SIZE = ["ccsize-g500-s28.ingest-saturated-size"]
 STEP_PROGRAM = {**dict.fromkeys(SAT + CC + V4 + SIZE, "jit_step"),
                 **dict.fromkeys(DYN, "jit_degree_step")}
 DEGREE_PHASES = ("sort", "scan", "gather", "scatter", "hist")
+
+
+#: every span the ingest thread emits for a window of a cell (5.0 events
+#: a window on one chip and 6.0 on four before PR 38; 8.0 and 9.0 since)
+INGEST_SPANS = (
+    "serving.window", "ingest.wait_source", "window.pack", "forest.window",
+    "forest.prep", "forest.place", "forest.dispatch", "degrees.window",
+    "degrees.prep", "degrees.dispatch", "window.emit", "serving.publish")
+#: the root's self time is held under the larger of these two
+UNSEEN_LIMIT_MS, UNSEEN_LIMIT_SHARE = 0.3, 0.03
 
 
 def _scope(kind: str, scope: str, program: str = "jit_step") -> dict:
@@ -96,18 +131,20 @@ PROPOSED = {
     **{f"forest_{p}_rounds.sat": ("count", "forest step", "edges_per_s", SAT,
                                   _scope("scope_rounds_mean", f"forest.{p}"))
        for p in ("chase", "fixpoint", "wide", "narrow")},
-    "fold_host_ms.sat": ("ms", "window host step", "edges_per_s", SAT,
-                         {"kind": "span_mean_ms", "span": "forest.window"}),
-    "fold_prep_ms.sat": ("ms", "window host step", "edges_per_s", SAT,
-                         {"kind": "span_mean_ms", "span": "forest.prep"}),
-    "fold_dispatch_ms.sat": ("ms", "window host step", "edges_per_s", SAT,
-                             {"kind": "span_mean_ms",
-                              "span": "forest.dispatch"}),
-    "queue_wait_ms": ("ms", "serving", "query_p95_ms", CC,
-                      {"kind": "span_mean_ms", "span": "serving.queue_wait"}),
-    "answer_wait_ms": ("ms", "serving", "query_p95_ms", CC,
-                       {"kind": "span_mean_ms",
-                        "span": "serving.device_wait"}),
+    # the window's host life under its root span (PR 38): the spans are
+    # new, so the first later PR whose parent emits them enters these
+    # (kind ``span_mean_ms``, data files alone). The root's SELF time is
+    # the host time of a window that no span names
+    **{name + tag: ("ms", layer, moves, cells,
+                    {"kind": "span_mean_ms", "span": span, **extra})
+       for tag, moves, cells in ((
+           "", "edges_per_s", SAT + V4 + DYN + SIZE),
+           (".paced", "window_p95_ms", CC[1:]))
+       for name, layer, span, extra in (
+           ("emit_ms", "window host step", "window.emit", {}),
+           ("publish_ms", "serving", "serving.publish", {}),
+           ("window_unseen_ms", "window host step", "serving.window",
+            {"self_time": True}))},
     # the vertex-sharded cell: the same phases on chip 0's line, the
     # sorts, and the collectives that make the lanes whole (inside the
     # chase, so NOT a phase to add to the sum)
@@ -295,16 +332,77 @@ def _span_counts(ctx: dict) -> dict:
     windows = by_name.get("forest.window", 0) + by_name.get(
         "degrees.window", 0)
     sweeps = by_name.get("serving.answer", 0)
-    ingest = sum(by_name.get(n, 0) for n in (
-        "ingest.wait_source", "window.pack", "forest.window",
-        "forest.prep", "forest.place", "forest.dispatch",
-        "degrees.window", "degrees.prep", "degrees.dispatch"))
+    ingest = sum(by_name.get(n, 0) for n in INGEST_SPANS)
     serve = sum(by_name.get(n, 0) for n in (
         "serving.queue_wait", "serving.answer", "serving.size_lookup",
         "serving.device_wait"))
     return {"by_name": by_name,
             "per_window": ingest / windows if windows else None,
             "per_sweep": serve / sweeps if sweeps else None}
+
+
+def window_block(spans: list, every: list) -> dict:
+    """The result document's ``window``: the host life of a window under
+    its root span ``serving.window``, over the roots in ``spans`` (those
+    that ended inside the measured window), their descendants looked up
+    in ``every`` (all the run's span events). Per root, in ms: the mean
+    of the root, of each child by name, and of the root's SELF time,
+    which is the host time no span names (``unseen_limit_ms`` is what
+    it is held under: the larger of 0.3 ms and 3% of the root less
+    ``ingest.wait_source``), with its median and its largest; WHERE the
+    self time lies (``gaps_ms``: the mean stretch before each child,
+    from the end of the child before it, and after the last); the mean
+    and the histogram of the attribute ``in_flight``; span events a
+    window (the root, its children and theirs). Empty for a program
+    without the root."""
+    from statistics import fmean, median
+
+    roots = [e for e in spans if e["name"] == "serving.window"]
+    if not roots:
+        return {}
+    kids: dict = {}
+    for e in every:
+        if "parent" in e:
+            kids.setdefault(e["parent"], []).append(e)
+    by_child: dict = {}
+    gaps: dict = {}
+    self_ms, events = [], 0
+    for r in roots:
+        mine = sorted(kids.get(r["sid"], []), key=lambda c: c["t0"])
+        events += 1 + len(mine) + sum(
+            len(kids.get(c["sid"], [])) for c in mine)
+        self_ms.append(1e3 * (r["dur_s"] - sum(c["dur_s"] for c in mine)))
+        edge = r["t0"]
+        for c in mine:
+            by_child[c["name"]] = by_child.get(c["name"], 0.0) + c["dur_s"]
+            key = "before:" + c["name"]
+            gaps[key] = gaps.get(key, 0.0) + c["t0"] - edge
+            edge = c["t0"] + c["dur_s"]
+        gaps["after:last"] = gaps.get("after:last", 0.0) + (
+            r["t0"] + r["dur_s"] - edge)
+    n = len(roots)
+    root_ms = 1e3 * fmean(r["dur_s"] for r in roots)
+    children = {k: 1e3 * v / n for k, v in sorted(by_child.items())}
+    in_flight = [r["attrs"]["in_flight"] for r in roots
+                 if "in_flight" in r.get("attrs", {})]
+    hist: dict = {}
+    for v in in_flight:
+        hist[str(v)] = hist.get(str(v), 0) + 1
+    out = {"windows": n, "root_ms": root_ms, "children_ms": children,
+           "self_ms": fmean(self_ms), "self_ms_p50": median(self_ms),
+           "self_ms_max": max(self_ms),
+           "gaps_ms": {k: 1e3 * v / n for k, v in gaps.items()},
+           "unseen_limit_ms": max(UNSEEN_LIMIT_MS, UNSEEN_LIMIT_SHARE * (
+               root_ms - children.get("ingest.wait_source", 0.0))),
+           "events_per_window": events / n}
+    if in_flight:
+        out.update(in_flight_mean=fmean(in_flight), in_flight_hist=hist,
+                   ring=max(r["attrs"].get("ring", 0) for r in roots))
+    return out
+
+
+def _window_tree(ctx: dict) -> dict:
+    return window_block(ctx["spans"], ctx["run"]["spans"])
 
 
 def _clock_check(ctx: dict) -> dict:
@@ -369,6 +467,12 @@ def _dump(ctx: dict, out_dir: str, n_exec: int) -> None:
     with open(os.path.join(out_dir, "scoped.json"), "w") as f:
         json.dump({"lo": lo, "hi": hi, "window": [ctx["lo"], ctx["hi"]],
                    "planes": planes}, f)
+    # the program's span events inside the measured window, as they
+    # were emitted, and the collector's pauses beside them: where a
+    # window's host time went, stall by stall
+    with open(os.path.join(out_dir, "spans.json"), "w") as f:
+        json.dump({"spans": ctx["spans"],
+                   "gc_pauses": ctx["run"]["stats"]["gc_pauses"]}, f)
 
 
 def main(argv=None) -> int:
@@ -414,7 +518,8 @@ def main(argv=None) -> int:
     def read_extras(_spec: dict, ctx: dict):
         """Rides the harness's own pass over the readers for its
         context (the loaded trace, the spans); reports no metric."""
-        readers = [("events", _span_counts), ("clock", _clock_check)]
+        readers = [("events", _span_counts), ("clock", _clock_check),
+                   ("window", _window_tree)]
         if cell.name in V4:
             readers.append(("exchanges", _exchanges))
         if cell.name in DYN + SIZE:
