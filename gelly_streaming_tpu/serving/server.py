@@ -694,10 +694,13 @@ class StreamServer:
                 "serving.answer",
                 {"batch": len(batch), "window": snap.window}
                 if tracing else None,
-            ):
+            ) as sp:
                 answers = self.engine.answer_batch(
                     snap, queries, head_window=self.store.head_window()
                 )
+                if sp.recording:
+                    reads, late_reads = self.engine.last_sweep
+                    sp.set(reads=reads, late_reads=late_reads)
         except Exception as e:
             for _, f, *_rest in batch:
                 if not f.done():

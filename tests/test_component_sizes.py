@@ -28,11 +28,15 @@ from gelly_streaming_tpu.parallel.mesh import make_mesh
 from gelly_streaming_tpu.serving import (
     ComponentSizeQuery,
     ConnectedQuery,
+    DegreeCountQuery,
+    DegreeQuery,
+    RankQuery,
     SnapshotStore,
     StreamServer,
 )
 from gelly_streaming_tpu.serving import query as squery
 from gelly_streaming_tpu.serving.query import QueryEngine
+from gelly_streaming_tpu.serving.snapshot_store import PublishedSnapshot
 from gelly_streaming_tpu.summaries import forest
 
 from _size_ref import SizeRef
@@ -205,6 +209,135 @@ def test_a_mixed_sweep_is_one_chase_one_gather_and_one_wait(monkeypatch):
         assert calls == {"_batch_roots": 1, "_gather": gathers, "_fetch": 1}
 
 
+# ---- the order of a sweep (ISSUE 39) ----------------------------------- #
+_KERNELS = ("_batch_roots", "_gather", "_gather_sizes",
+            "_component_size_table")
+
+
+def _every_table(n, w, src, dst, sized):
+    """A snapshot that holds EVERY table a read is made of: the served
+    forest of a Kronecker stream (with its size table where ``sized``)
+    and, made by hand over the same id space, a degree table, its
+    histogram and a rank table."""
+    _agg, snaps, _live = _snapshots(n, w, src, dst, component_sizes=sized)
+    deg = np.bincount(np.concatenate([src, dst]), minlength=n).astype(np.int32)
+    payload = dict(snaps[-1].payload)
+    payload.update(
+        deg=jnp.asarray(deg),
+        hist=jnp.asarray(np.bincount(deg[deg > 0], minlength=64)[:64]
+                         .astype(np.int32)),
+        ranks=jnp.asarray((deg / max(1, deg.sum())).astype(np.float32)))
+    return PublishedSnapshot(payload, window=7, watermark=len(src),
+                             version=3, epoch=39), deg
+
+
+def _one_by_one(engine, snap, queries):
+    """The same queries through the class's own public method, a class
+    at a time."""
+    def col(cls, field):
+        return np.asarray([getattr(q, field) for q in queries
+                           if type(q) is cls], np.int64)
+
+    return {
+        ConnectedQuery: lambda: engine.connected(
+            snap, col(ConnectedQuery, "u"), col(ConnectedQuery, "v")),
+        ComponentSizeQuery: lambda: engine.component_size(
+            snap, col(ComponentSizeQuery, "v")),
+        DegreeQuery: lambda: engine.degree(snap, col(DegreeQuery, "v")),
+        DegreeCountQuery: lambda: engine.degree_count(
+            snap, col(DegreeCountQuery, "d")),
+        RankQuery: lambda: engine.rank(snap, col(RankQuery, "v")),
+    }
+
+
+_PAIRS = [ConnectedQuery(v, (v * 7 + 3) % 512) for v in range(24)]
+_SIZES = [ComponentSizeQuery(v) for v in range(0, 480, 10)]
+_DEGREES = [DegreeQuery(v) for v in range(0, 512, 8)]
+_COUNTS = [DegreeCountQuery(d) for d in (1, 2, 3, 5, 8, 13, 63)]
+_RANKS = [RankQuery(v) for v in range(5, 500, 45)]
+#: mix -> (queries, the snapshot holds ``sizes``, device reads, the
+#: kernels the sweep runs)
+MIXES = {
+    "degrees_and_degree_counts": (
+        _DEGREES + _COUNTS, False, 2, {"_gather": 2}),
+    "pairs_alone": (_PAIRS, False, 1, {"_batch_roots": 1}),
+    "pairs_and_sizes_over_a_sized_snapshot": (
+        _SIZES + _PAIRS, True, 1, {"_batch_roots": 1, "_gather": 1}),
+    "sizes_alone_over_a_sized_snapshot": (
+        _SIZES, True, 1, {"_batch_roots": 1, "_gather": 1}),
+    "pairs_and_sizes_over_an_unsized_snapshot": (
+        _PAIRS + _SIZES, False, 2,
+        {"_batch_roots": 1, "_component_size_table": 1, "_gather_sizes": 1}),
+    "degrees_and_ranks": (_DEGREES + _RANKS, False, 2, {"_gather": 2}),
+    "every_class_over_a_sized_snapshot": (
+        _PAIRS + _DEGREES + _SIZES + _COUNTS + _RANKS, True, 4,
+        {"_batch_roots": 1, "_gather": 4}),
+    "invalid_and_unseen_ids": (
+        [DegreeQuery(v) for v in (3, -1, 512, 10**12, 0, 511)]
+        + [DegreeCountQuery(d) for d in (0, -4, 64, 999, 1, 2)]
+        + [ConnectedQuery(u, v) for u, v in (
+            (-1, -1), (700, 700), (700, 3), (3, 3), (1, 2), (2**40, 1))]
+        + [RankQuery(v) for v in (-7, 4, 4096)],
+        False, 4, {"_batch_roots": 1, "_gather": 3}),
+}
+
+
+@pytest.mark.parametrize("mix", sorted(MIXES))
+def test_a_sweep_enqueues_every_read_before_its_first_fetch(
+        mix, monkeypatch):
+    queries, sized, n_reads, kernels = MIXES[mix]
+    # the classes interleaved: answers come back in the INPUT's order
+    order = np.random.default_rng(39).permutation(len(queries))
+    queries = [queries[i] for i in order]
+    n, w, src, dst = _kronecker(windows=4)
+    snap, deg = _every_table(n, w, src, dst, sized)
+    engine = QueryEngine(prefer_host=False)
+    # (an engine of its own: the unsized size table is cached a version)
+    want = {cls: ask().tolist()
+            for cls, ask in _one_by_one(
+                QueryEngine(prefer_host=False), snap, queries).items()
+            if any(type(q) is cls for q in queries)}
+    log = []
+
+    def logged(name):
+        inner = getattr(squery, name)
+
+        def call(*a, **kw):
+            log.append(name)
+            return inner(*a, **kw)
+        return call
+
+    for name in _KERNELS + ("_fetch",):
+        monkeypatch.setattr(squery, name, logged(name))
+    answers = engine.answer_batch(snap, queries)
+    # every kernel of the sweep is out before the first result is
+    # fetched, and every read is fetched once
+    assert log[-n_reads:] == ["_fetch"] * n_reads, log
+    assert {k: log.count(k) for k in kernels} == kernels
+    assert len(log) == n_reads + sum(kernels.values()), log
+    assert engine.last_sweep[0] == n_reads
+    assert {(a.window, a.version, a.watermark, a.staleness)
+            for a in answers} == {(7, 3, len(src), 0)}
+    # value for value what the class's own method says, in input order
+    taken = dict.fromkeys(want, 0)
+    for q, a in zip(queries, answers):
+        assert a.value == want[type(q)][taken[type(q)]], (q, a)
+        taken[type(q)] += 1
+    # ... what the host path says, which enqueues and waits for nothing
+    host = QueryEngine(prefer_host=True)
+    del log[:]
+    assert [a.value for a in host.answer_batch(snap, queries)] == [
+        a.value for a in answers]
+    assert log == [] and host.last_sweep == (0, 0)
+    # ... and what the tables say
+    for q, a in zip(queries, answers):
+        if type(q) is DegreeQuery:
+            assert a.value == (int(deg[q.v]) if 0 <= q.v < n else 0), q
+        elif type(q) is DegreeCountQuery:
+            assert a.value == (int(np.sum(deg == q.d))
+                               if 1 <= q.d < 64 else 0), q
+
+
 @contextlib.contextmanager
 def _span_events():
     """-> the list the program's span events land in while tracing is
@@ -237,6 +370,25 @@ def test_the_size_lookup_span_counts_the_sweeps_lanes():
     assert spans["serving.size_lookup"]["attrs"] == {"n": 2, "ids": 4}
     assert (spans["serving.device_wait"]["parent"]
             == spans["serving.size_lookup"]["sid"])
+
+
+def test_the_size_lookup_span_ends_with_its_own_wait_in_a_longer_sweep():
+    """The sized pair beside a second read: both kernels are out before
+    the pair's wait, which alone lies under ``serving.size_lookup``."""
+    n, w, src, dst = _kronecker(windows=2)
+    with _span_events() as events:
+        snap, _deg = _every_table(n, w, src, dst, sized=True)
+        del events[:]
+        engine = QueryEngine(prefer_host=False)
+        engine.answer_batch(snap, _DEGREES[:5] + _SIZES[:3] + _PAIRS[:2])
+    assert engine.last_sweep[0] == 2
+    spans = [e for e in events if e.get("kind") == "span"]
+    (lookup,) = [e for e in spans if e["name"] == "serving.size_lookup"]
+    assert lookup["attrs"] == {"n": 3, "ids": 7}
+    waits = [e for e in spans if e["name"] == "serving.device_wait"]
+    assert [(e["attrs"]["n"], e.get("parent") == lookup["sid"])
+            for e in waits] == [(7, True), (5, False)]
+    assert lookup["t0"] + lookup["dur_s"] <= waits[1]["t0"]
 
 
 def test_the_forest_window_span_says_the_step_is_sized():

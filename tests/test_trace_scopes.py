@@ -250,6 +250,102 @@ def test_a_sweep_without_a_trace_context_says_how_long_it_waited(spans):
         assert d["attrs"]["n"] == 2 * a["attrs"]["batch"]
 
 
+def _degree_sweep():
+    from gelly_streaming_tpu.core.stream import SimpleEdgeStream
+    from gelly_streaming_tpu.core.window import CountWindow
+    from gelly_streaming_tpu.datasets import IdentityDict
+    from gelly_streaming_tpu.library.degrees import DegreeDistribution
+    from gelly_streaming_tpu.serving import DegreeCountQuery, DegreeQuery
+
+    rng = np.random.default_rng(39)
+    src, dst = (rng.integers(0, 64, 256).astype(np.int32) for _ in "sd")
+    stream = SimpleEdgeStream(
+        (src, dst, np.ones(256, np.int32)), window=CountWindow(64),
+        vertex_dict=IdentityDict(64))
+    return (DegreeDistribution(hist_capacity=64).servable(), stream,
+            [DegreeQuery(v) for v in range(24)]
+            + [DegreeCountQuery(d) for d in range(1, 8)])
+
+
+def _pair_sweep():
+    from gelly_streaming_tpu.serving import ConnectedQuery
+
+    return (_cc().servable(), _stream(),
+            [ConnectedQuery(2 * i, 2 * i + 1) for i in range(20)])
+
+
+def _sized_sweep():
+    from gelly_streaming_tpu.library import ConnectedComponents
+    from gelly_streaming_tpu.serving import ComponentSizeQuery, ConnectedQuery
+
+    return (ConnectedComponents(component_sizes=True).servable(), _stream(),
+            [ComponentSizeQuery(v) for v in range(12)]
+            + [ConnectedQuery(2 * i, 2 * i + 1) for i in range(4)])
+
+
+@pytest.mark.parametrize("make,waits,under", [
+    (_degree_sweep, [24, 7], "serving.answer"),
+    (_pair_sweep, [40], "serving.answer"),
+    (_sized_sweep, [20], "serving.size_lookup"),
+], ids=["two_degree_classes", "pairs_alone", "sized_pair"])
+def test_a_sweeps_answer_span_counts_its_reads(spans, make, waits, under):
+    """``serving.answer`` says how many device reads the sweep enqueued
+    before its first fetch (``reads``) and how many of them, the first
+    aside, still waited (``late_reads``); every read keeps a
+    ``serving.device_wait`` of its own, with its lanes."""
+    from gelly_streaming_tpu.serving import StreamServer
+    from gelly_streaming_tpu.serving.query import QueryEngine
+
+    servable, stream, queries = make()
+    server = StreamServer(servable, stream, max_pending=4096,
+                          engine=QueryEngine(prefer_host=False))
+    server.start()
+    server.join(60)
+    for f in server.submit_many(queries):
+        f.result(60)
+    server.close()
+    events = spans()
+    (answer,) = [e for e in events if e["name"] == "serving.answer"]
+    assert answer["attrs"]["batch"] == len(queries)
+    assert answer["attrs"]["reads"] == len(waits)
+    assert 0 <= answer["attrs"]["late_reads"] < len(waits)
+    parent = answer
+    if under != "serving.answer":
+        (parent,) = [e for e in events if e["name"] == under]
+        assert parent["parent"] == answer["sid"]
+    dev = [e for e in events if e["name"] == "serving.device_wait"]
+    assert [d["attrs"]["n"] for d in dev] == waits
+    assert all(d["parent"] == parent["sid"] for d in dev)
+    assert sum(d["dur_s"] for d in dev) <= answer["dur_s"]
+    # tools/trace_phases.py prints both a sweep, and the first read's
+    # wait beside a later one's
+    block = _trace_phases().serving_block(events, events)
+    assert block["sweeps"] == 1
+    assert block["reads_per_sweep"] == len(waits)
+    assert block["late_reads_per_sweep"] == answer["attrs"]["late_reads"]
+    assert block["first_wait_ms"] == pytest.approx(1e3 * dev[0]["dur_s"])
+    assert (block["later_wait_ms"] is None) == (len(waits) == 1)
+    # a program whose sweeps do not count their reads: the waits alone
+    older = [dict(e, attrs={"batch": 1}) if e is answer else e
+             for e in events]
+    assert "reads_per_sweep" not in _trace_phases().serving_block(
+        older, older)
+    assert _trace_phases().serving_block([], events) == {}
+
+
+def _trace_phases():
+    import os
+    import sys
+
+    tools = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools")
+    if tools not in sys.path:
+        sys.path.insert(0, tools)
+    import trace_phases
+
+    return trace_phases
+
+
 # --------------------------------------------------------------------- #
 # the operator's device trace
 # --------------------------------------------------------------------- #

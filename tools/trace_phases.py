@@ -53,7 +53,9 @@ events per window and per sweep), ``window`` (the host life of a window
 under its root span ``serving.window``: the mean of the root, of each
 child and of the root's SELF time, which is the host time no span names;
 the mean and the histogram of ``in_flight``, the published tables still
-being computed at a publish; span events a window) and ``clock`` (how far a
+being computed at a publish; span events a window), ``serving`` (a
+sweep's ``reads`` and ``late_reads`` as ``serving.answer`` says them,
+and the wait of its first read beside that of a later one) and ``clock`` (how far a
 span's ``t0``,
 mapped through the traced slice's bracket, lies from the same span's
 annotation on the profiler's clock) beside the harness's keys.
@@ -341,6 +343,15 @@ def _span_counts(ctx: dict) -> dict:
             "per_sweep": serve / sweeps if sweeps else None}
 
 
+def _children(every: list) -> dict:
+    """Span id -> the span events whose ``parent`` it is."""
+    kids: dict = {}
+    for e in every:
+        if "parent" in e:
+            kids.setdefault(e["parent"], []).append(e)
+    return kids
+
+
 def window_block(spans: list, every: list) -> dict:
     """The result document's ``window``: the host life of a window under
     its root span ``serving.window``, over the roots in ``spans`` (those
@@ -360,10 +371,7 @@ def window_block(spans: list, every: list) -> dict:
     roots = [e for e in spans if e["name"] == "serving.window"]
     if not roots:
         return {}
-    kids: dict = {}
-    for e in every:
-        if "parent" in e:
-            kids.setdefault(e["parent"], []).append(e)
+    kids = _children(every)
     by_child: dict = {}
     gaps: dict = {}
     self_ms, events = [], 0
@@ -399,6 +407,46 @@ def window_block(spans: list, every: list) -> dict:
         out.update(in_flight_mean=fmean(in_flight), in_flight_hist=hist,
                    ring=max(r["attrs"].get("ring", 0) for r in roots))
     return out
+
+
+def serving_block(spans: list, every: list) -> dict:
+    """The result document's ``serving``: over the sweeps in ``spans``
+    (``serving.answer``, those that ended inside the measured window),
+    a sweep's ``reads`` (the device reads it enqueued before its first
+    fetch) and ``late_reads`` (those of them, the first aside, whose
+    ``serving.device_wait`` took over 1 ms: a fold slipped between two
+    dispatches), as the span says them, and the mean
+    ``serving.device_wait`` of a sweep's first read and of a later one,
+    in ms, the waits looked up in ``every`` under the sweep or under
+    its ``serving.size_lookup``. A program whose sweeps do not count
+    their reads gives the waits alone."""
+    from statistics import fmean
+
+    sweeps = [e for e in spans if e["name"] == "serving.answer"]
+    if not sweeps:
+        return {}
+    kids = _children(every)
+    first, later = [], []
+    for a in sweeps:
+        under = kids.get(a["sid"], [])
+        under = under + [g for c in under for g in kids.get(c["sid"], [])]
+        waits = sorted((e for e in under
+                        if e["name"] == "serving.device_wait"),
+                       key=lambda e: e["t0"])
+        first += [1e3 * w["dur_s"] for w in waits[:1]]
+        later += [1e3 * w["dur_s"] for w in waits[1:]]
+    out = {"sweeps": len(sweeps),
+           "first_wait_ms": fmean(first) if first else None,
+           "later_wait_ms": fmean(later) if later else None}
+    for key in ("reads", "late_reads"):
+        said = [a["attrs"][key] for a in sweeps if key in a.get("attrs", {})]
+        if said:
+            out[f"{key}_per_sweep"] = fmean(said)
+    return out
+
+
+def _serving(ctx: dict) -> dict:
+    return serving_block(ctx["spans"], ctx["run"]["spans"])
 
 
 def _window_tree(ctx: dict) -> dict:
@@ -519,7 +567,7 @@ def main(argv=None) -> int:
         """Rides the harness's own pass over the readers for its
         context (the loaded trace, the spans); reports no metric."""
         readers = [("events", _span_counts), ("clock", _clock_check),
-                   ("window", _window_tree)]
+                   ("window", _window_tree), ("serving", _serving)]
         if cell.name in V4:
             readers.append(("exchanges", _exchanges))
         if cell.name in DYN + SIZE:
