@@ -21,7 +21,11 @@ degree table and its copy has a row per vertex.
   forest's pair of table primitives).
 - The histogram is derived state: subtract old-degree counts of touched
   vertices, add new-degree counts (degree 0 never tracked, matching the
-  reference's remove-on-zero): two window-sized scatter-adds.
+  reference's remove-on-zero). The ``4 * W`` weighted lanes (old degrees
+  at -1, new at +1) are counted by :func:`count_bins`: the head bins by
+  ONE exact one-hot contraction on the matrix unit, the rare bins past
+  it (the hubs) by a scatter-add of a short slab, and a window whose
+  tail overflows the slab by a scatter-add of every lane.
 
 Two ways in, one step: :meth:`DegreeDistribution.run` takes records
 ``(src, dst, change)``; :meth:`DegreeDistribution.run_stream` takes a
@@ -40,6 +44,7 @@ from typing import Iterable, Iterator, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..core.edgeblock import bucket_capacity
 from ..core.emission import LazyListBatch
@@ -55,6 +60,93 @@ def _combine(a, b):
     s1, m1 = a
     s2, m2 = b
     return s1 + s2, jnp.maximum(m2, m1 + s2)
+
+
+#: the head: bins under ``HEAD[0] * HEAD[1]`` are counted by one one-hot
+#: contraction, ``[HEAD[0], lanes] @ [lanes, HEAD[1]]``
+HEAD = (128, 128)
+#: the tail's slab: the lanes are dealt in turn to blocks of ``SLAB[0]``
+#: lanes, and each block keeps ``SLAB[1]`` slots for its tail lanes. In a
+#: Graph500 scale-28 stream of 2^16-event windows (4,600 of them, degrees
+#: up to 1.4e5) at most 58 of a window's 2^18 lanes lie past the head,
+#: and at most 6 in one block
+SLAB = (2048, 8)
+
+
+def count_bins(hist, bins, sign):
+    """``hist`` with each lane's ``sign`` (±1) added at its bin; a lane
+    at or past ``hist.shape[0]`` drops. Exact, and the same table as
+    ``hist.at[bins].add(sign, mode="drop")``, at a cost that follows the
+    lanes and not their scatter:
+
+    - a bin under the head (``HEAD[0] * HEAD[1]`` bins, or the whole
+      histogram where it is no larger) is counted by ONE contraction on
+      the matrix unit: int8 one-hots of ``bin // HEAD[1]`` weighted by
+      the sign and of ``bin % HEAD[1]``, both with the lanes minor,
+      accumulated in int32, so every count is exact for any number of
+      lanes under 2^31 (named scope ``degrees.hist.head``);
+    - the tail's lanes (the hubs: few, but in every window) are dealt to
+      blocks of ``SLAB[0]`` lanes, lane ``i`` to block ``i % blocks``, so
+      that lanes of neighbouring rows land in different blocks; each
+      block's first ``SLAB[1]`` tail lanes fill its slots of a short
+      slab, which ONE scatter-add writes (``degrees.hist.tail``);
+    - a window in which some block holds more tail lanes than it has
+      slots scatters every tail lane instead, the exact fallback
+      (``degrees.hist.fallback``, the branch of a ``lax.cond``)."""
+    hcap = hist.shape[0]
+    lo_w = min(HEAD[1], hcap)
+    hi_w = min(HEAD[0], -(-hcap // lo_w))
+    n_head = min(hcap, hi_w * lo_w)
+    with jax.named_scope("degrees.hist.head"):
+        at = jnp.where(bins < n_head, bins, 0)
+        w = jnp.where(bins < n_head, sign, 0).astype(jnp.int8)
+        hi = jnp.where(
+            jnp.arange(hi_w)[:, None] == (at // lo_w)[None, :], w[None, :], 0
+        ).astype(jnp.int8)
+        lo = (jnp.arange(lo_w)[:, None] == (at % lo_w)[None, :]).astype(
+            jnp.int8)
+        counts = lax.dot_general(
+            hi, lo, (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32
+        ).reshape(-1)[:n_head]
+        hist = lax.dynamic_update_slice(hist, hist[:n_head] + counts, (0,))
+    if n_head == hcap:
+        return hist
+    tail = (bins >= n_head) & (bins < hcap)
+    with jax.named_scope("degrees.hist.tail"):
+        slab_bins, slab_sign, fits = _fill_slab(tail, bins, sign, hcap)
+
+    def on_slab(h):
+        with jax.named_scope("degrees.hist.tail"):
+            return h.at[slab_bins].add(slab_sign, mode="drop")
+
+    def fallback(h):
+        with jax.named_scope("degrees.hist.fallback"):
+            return h.at[jnp.where(tail, bins, hcap)].add(sign, mode="drop")
+
+    return lax.cond(fits, on_slab, fallback, hist)
+
+
+def _fill_slab(tail, bins, sign, hcap: int):
+    """``(bins, sign, fits)`` of the slab: ``SLAB[1]`` slots a block, the
+    empty ones at the sentinel ``hcap``; ``fits`` is False where a block
+    holds more tail lanes than it has slots (the slab is then short)."""
+    lanes, slots = SLAB
+    n = tail.shape[0]
+    blocks = -(-n // lanes)
+    rows = -(-n // blocks)
+    # a tail lane's bin and sign in one word, -1 for every other lane
+    word = jnp.where(tail, bins * 2 + (sign > 0), -1)
+    if rows * blocks > n:
+        word = jnp.pad(word, (0, rows * blocks - n), constant_values=-1)
+    grid = word.reshape(rows, blocks)         # lane i: block i % blocks
+    rank = jnp.where(grid >= 0,
+                     jnp.cumsum(grid >= 0, axis=0, dtype=jnp.int32) - 1, -1)
+    slot = jnp.arange(slots, dtype=jnp.int32)[:, None, None]
+    got = jnp.max(jnp.where(rank[None] == slot, grid[None], -1), axis=1)
+    got = got.reshape(-1)
+    return (jnp.where(got >= 0, got >> 1, hcap),
+            jnp.where(got & 1, 1, -1).astype(sign.dtype),
+            jnp.max(rank) < slots)
 
 
 @jax.jit
@@ -88,7 +180,12 @@ def degree_step(deg, hist, src, dst, val, mask):
         # degrees at or past the capacity count in the last bin
         dec = jnp.where(last & (old > 0), jnp.minimum(old, hcap - 1), hcap)
         inc = jnp.where(last & (new > 0), jnp.minimum(new, hcap - 1), hcap)
-        hist = hist.at[dec].add(-1, mode="drop").at[inc].add(1, mode="drop")
+        # the new degrees one lane on: a vertex's two lanes go to two
+        # blocks of the tail's slab, not to one (and no interleave: on
+        # the chip that copy unrolls to megabytes of code)
+        hist = count_bins(
+            hist, jnp.concatenate([dec, jnp.roll(inc, 1)]),
+            jnp.concatenate([jnp.full_like(dec, -1), jnp.ones_like(inc)]))
     return deg, hist
 
 

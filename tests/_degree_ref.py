@@ -7,10 +7,11 @@ from __future__ import annotations
 import numpy as np
 
 
-def replay(src, dst, sign, n_ids: int):
+def replay(src, dst, sign, n_ids: int, deg=None):
     """Both endpoints move by the event's sign, source first; a degree
-    that would fall under zero stays at zero."""
-    deg = np.zeros(n_ids, np.int64)
+    that would fall under zero stays at zero. ``deg``, where given, is
+    the vector the events continue from (it is not written)."""
+    deg = np.zeros(n_ids, np.int64) if deg is None else deg.copy()
     for s, d, c in zip(src.tolist(), dst.tolist(), sign.tolist()):
         for v in (s, d):
             deg[v] = max(0, deg[v] + c)

@@ -3,9 +3,10 @@ ISSUE 34) against a plain per-event replay of
 ``DegreeDistribution.java:83-131``: seeded streams with deletions and
 clamps at zero, a vertex added, removed and added again in ONE window,
 self-loops, padded windows, a fixed histogram capacity used and unused;
-the column path against the record path; and the lowered step's shapes
-(nothing but the table and its copy has a row per vertex, so the dense
-step cannot come back unnoticed)."""
+the histogram window by window through its head's contraction, its
+tail's slab and the slab's fallback; the column path against the record
+path; and the lowered step's shapes (nothing but the table and its copy
+has a row per vertex, so the dense step cannot come back unnoticed)."""
 
 from __future__ import annotations
 
@@ -21,6 +22,7 @@ from _degree_ref import hist_of
 from gelly_streaming_tpu.core.stream import SimpleEdgeStream
 from gelly_streaming_tpu.core.window import CountWindow
 from gelly_streaming_tpu.datasets import IdentityDict
+from gelly_streaming_tpu.library import degrees
 from gelly_streaming_tpu.library.degrees import (
     DegreeDistribution,
     degree_step,
@@ -33,8 +35,8 @@ from gelly_streaming_tpu.ops.segment import (
 N_IDS = 48
 
 
-def replay(src, dst, sign, n_ids=N_IDS):
-    return _degree_ref.replay(src, dst, sign, n_ids)
+def replay(src, dst, sign, n_ids=N_IDS, deg=None):
+    return _degree_ref.replay(src, dst, sign, n_ids, deg)
 
 
 def events(seed: int, n: int, n_ids: int = N_IDS, p_add: float = 0.6):
@@ -170,8 +172,142 @@ def test_published_tables_are_fresh_buffers_every_window():
         assert np.array_equal(np.asarray(dd._deg)[:N_IDS], want)
 
 
+# ---- the histogram's head, slab and fallback ------------------------- #
+HEAD_BINS = degrees.HEAD[0] * degrees.HEAD[1]
+
+
+def hub_events(seed: int, n_hubs: int, n_mixed: int,
+               ramp: int = HEAD_BINS // 2 + 400):
+    """``n_hubs`` vertices raised past the head by ``ramp * n_hubs``
+    additions between two of them, then ``n_mixed`` events of a hub or
+    any id against any id, a third of them deletions."""
+    rng = np.random.default_rng(seed)
+    hubs = rng.choice(N_IDS, n_hubs, replace=False).astype(np.int32)
+    pairs = hubs[rng.integers(0, n_hubs, (ramp * n_hubs, 2))]
+    src = np.where(rng.random(n_mixed) < 0.5,
+                   hubs[rng.integers(0, n_hubs, n_mixed)],
+                   rng.integers(0, N_IDS, n_mixed)).astype(np.int32)
+    dst = rng.integers(0, N_IDS, n_mixed).astype(np.int32)
+    sign = np.where(rng.random(n_mixed) < 2 / 3, 1, -1).astype(np.int32)
+    return (np.concatenate([pairs[:, 0], src]),
+            np.concatenate([pairs[:, 1], dst]),
+            np.concatenate([np.ones(len(pairs), np.int32), sign]))
+
+
+def tail_lanes(before, after, capacity):
+    """The lanes of a window whose bin lies past the head: a touched
+    vertex's old degree and its new one, clipped as the step clips."""
+    n_head = min(capacity, HEAD_BINS)
+    return sum(int(np.sum((np.minimum(d, capacity - 1) >= n_head) & (d > 0)))
+               for d in (before, after))
+
+
+# case: (events, window, hist_capacity, what the windows' tail lanes show)
+HIST_CASES = {
+    # every lane in the head of a histogram that has a tail
+    "all_head": (lambda: events(21, 600), 37, 2 * HEAD_BINS, "none"),
+    # hubs past the head, at most 8 tail lanes a window over 2 blocks
+    "tail_on_slab": (lambda: hub_events(22, 4, 4096), 1024, 2 * HEAD_BINS,
+                     "slab"),
+    # 6 hubs in windows of 256 events: 12 tail lanes in one block of 8
+    # slots, so the block overflows and the fallback counts
+    "slab_overflow": (lambda: hub_events(23, 6, 2048), 256, 2 * HEAD_BINS,
+                      "overflow"),
+    # the head is the whole histogram: no tail, hubs clipped in its last
+    # bin
+    "hcap_is_the_head": (lambda: hub_events(24, 4, 1024), 64, HEAD_BINS,
+                         "none"),
+    # a histogram under the head and no multiple of its rows' width
+    "ragged_head": (lambda: hub_events(25, 3, 1024, ramp=700), 64, 1000,
+                    "none"),
+    # a tail no multiple of the head's width, hubs clipped into its
+    # last bin
+    "ragged_tail_clipped": (lambda: hub_events(26, 3, 1024), 128,
+                            HEAD_BINS + 100, "slab"),
+    # degrees that reach zero and meet the clamp all the time
+    "deletions_to_zero": (lambda: events(27, 900, n_ids=8, p_add=0.45), 16,
+                          1 << 13, "none"),
+}
+
+
+@pytest.mark.parametrize("case", list(HIST_CASES))
+def test_the_histogram_is_exact_window_by_window(case):
+    """Every window's histogram against upstream's event-by-event replay,
+    through the head's contraction, the tail's slab and its fallback."""
+    make, window, capacity, tail = HIST_CASES[case]
+    src, dst, sign = make()
+    dd = DegreeDistribution(hist_capacity=capacity)
+    want = np.zeros(N_IDS, np.int64)
+    lanes, zeroed = [], 0
+    for k, _ in enumerate(dd.run_stream(
+            column_stream(src, dst, sign, window))):
+        part = slice(k * window, (k + 1) * window)
+        before = want
+        want = replay(src[part], dst[part], sign[part], deg=before)
+        touched = np.unique(np.concatenate([src[part], dst[part]]))
+        lanes.append(tail_lanes(before[touched], want[touched], capacity))
+        zeroed += int(np.sum((before > 0) & (want == 0)))
+        assert dd.histogram() == hist_of(want, capacity=capacity), k
+        assert np.array_equal(dd.degrees()[:N_IDS], want), k
+    assert k + 1 == -(-len(src) // window)
+    # the windows took the path the case is for
+    slots = degrees.SLAB[1]
+    blocks = -(-4 * window // degrees.SLAB[0])
+    if tail == "none":
+        assert max(lanes) == 0
+    elif tail == "slab":      # no block can hold more than its slots
+        assert 0 < max(lanes) <= slots
+    else:                     # some block must hold more
+        assert max(lanes) > blocks * slots
+    if case in ("hcap_is_the_head", "ragged_head", "ragged_tail_clipped"):
+        assert want.max() >= capacity      # the last bin holds the hubs
+    if case == "deletions_to_zero":
+        assert zeroed > 0
+
+
+@pytest.mark.parametrize("capacity,n,n_tail,one_block,fits", [
+    (37, 64, 0, False, True),      # the head is the whole histogram
+    (1000, 256, 0, False, True),   # and no multiple of its rows' width
+    (HEAD_BINS + 3000, 8192, 0, False, True),    # a tail, none in use
+    (HEAD_BINS + 3000, 8192, 8, True, True),     # one block's 8 slots
+    (HEAD_BINS + 3000, 8192, 9, True, False),    # 9 in it: fallback
+    (HEAD_BINS + 3000, 8192, 24, False, True),   # 6 a block of 4
+    (HEAD_BINS + 3000, 6000, 60, False, False),  # 3 ragged blocks
+])
+def test_count_bins_is_the_scatter_add_it_replaces(
+        capacity, n, n_tail, one_block, fits):
+    """``count_bins`` against ``np.add.at``: random head lanes, lanes at
+    and past the capacity, and ``n_tail`` lanes past the head, side by
+    side (dealt over the slab's blocks, lane ``i`` to block
+    ``i % blocks``) or all in one block, so that the slab fits or the
+    fallback counts."""
+    rng = np.random.default_rng(capacity + n + n_tail)
+    n_head = min(capacity, HEAD_BINS)
+    bins = rng.integers(0, n_head, n)
+    bins[rng.random(n) < 0.1] = capacity + rng.integers(0, 3)
+    blocks = -(-n // degrees.SLAB[0])
+    # neighbouring lanes go to neighbouring blocks; every ``blocks``-th
+    # lane to one
+    at = rng.integers(0, n - blocks * n_tail) + (
+        blocks if one_block else 1) * np.arange(n_tail)
+    bins[at] = rng.integers(n_head, capacity, n_tail)
+    bins = jnp.asarray(bins.astype(np.int32))
+    sign = jnp.asarray(rng.choice(np.array([-1, 1], np.int32), n))
+    hist = rng.integers(0, 100, capacity).astype(np.int32)
+    want = hist.copy()
+    live = np.asarray(bins) < capacity
+    np.add.at(want, np.asarray(bins)[live], np.asarray(sign)[live])
+    got = jax.jit(degrees.count_bins)(jnp.asarray(hist), bins, sign)
+    assert np.array_equal(np.asarray(got), want)
+    if capacity > HEAD_BINS:
+        tail = (bins >= HEAD_BINS) & (bins < capacity)
+        assert bool(degrees._fill_slab(tail, bins, sign, capacity)[2]) == fits
+
+
 # ---- the shapes of the lowered step ---------------------------------- #
-VCAP, HCAP, W = 1 << 20, 256, 64
+# a histogram with a tail past the head: the contraction, the slab and
+# the fallback are all in the program
+VCAP, HCAP, W = 1 << 20, 1 << 15, 64
 
 
 def _lowered():
@@ -200,13 +336,21 @@ def test_nothing_but_the_table_and_its_copy_has_a_row_per_vertex():
     closing = [o for o in ops if o.startswith("})")]
     named = [o for o in ops if not o.startswith("})")]
     assert sorted(named) == ["func.func", "return", "stablehlo.gather"], ops
-    # three scatters in all: the table's and the histogram's two
+    # three scatters in all: the table's, and the histogram's tail's two
+    # (the slab's and the fallback's, the branches of ONE conditional);
+    # the head is ONE int8 contraction into int32
     assert len(closing) == 1 and text.count('"stablehlo.scatter"(') == 3
+    assert text.count("stablehlo.case") == 1
+    (dot,) = [ln for ln in text.splitlines() if "stablehlo.dot_general" in ln]
+    assert "xi8>" in dot and "xi32>" in dot.rsplit("->", 1)[1]
     assert f"tensor<{VCAP}xi1>" not in text and "iota" not in "".join(
         line for line in text.splitlines() if rows in line)
-    # every other tensor is a window's (2 * W lanes) or the histogram's
+    # every other tensor is a window's (2 * W lanes, 4 * W weighted
+    # lanes), the histogram's, its head's (128 x 128 bins) or the slab's
+    n_head = degrees.HEAD[0] * degrees.HEAD[1]
     sizes = {int(n) for n in re.findall(r"tensor<(\d+)x", text)}
-    assert sizes <= {VCAP, HCAP, W, 2 * W, W - 1, 2 * W - 1, 1} | {
+    assert sizes <= {VCAP, HCAP, n_head, 4 * W, 2 * W, W, W - 1, 2 * W - 1,
+                     *degrees.HEAD, degrees.SLAB[1], 1} | {
         2 ** k for k in range(8)} | {2 ** k - 1 for k in range(8)}, sizes
 
 
@@ -214,7 +358,7 @@ def test_the_compiled_step_keeps_to_the_window_too():
     """After XLA's passes (the CPU's here; the TPU's compile is checked
     on the chip by tools/trace_phases.py): the instructions with a
     ``vcap``-row operand or result are parameter, copy, gather, scatter
-    and the root tuple."""
+    and the root tuple; the histogram's conditional is not among them."""
     text = _lowered().compile().as_text()
     kinds = set()
     for line in text.splitlines():
@@ -224,6 +368,7 @@ def test_the_compiled_step_keeps_to_the_window_too():
     assert kinds <= {"parameter", "copy", "gather", "scatter", "tuple",
                      "fusion"}, kinds
     assert "copy" in kinds and "scatter" in kinds
+    assert re.search(rf"s32\[{HCAP}\][^\n]* conditional\(", text)
 
 
 # ---- ops/segment.py: the window-sized form --------------------------- #

@@ -347,6 +347,92 @@ def _trace_phases():
 
 
 # --------------------------------------------------------------------- #
+# the degree step's histogram: three scopes nested in `degrees.hist`
+# --------------------------------------------------------------------- #
+def test_the_histograms_parts_are_scopes_inside_degrees_hist():
+    """The head's contraction, the tail's slab and the fallback branch
+    carry their scopes under ``degrees.hist``, where the tool reads
+    them; a histogram no larger than the head has neither tail nor
+    fallback."""
+    from gelly_streaming_tpu.library.degrees import degree_step
+
+    def lowered(hcap):
+        return degree_step.lower(
+            _I((VCAP,), jnp.int32), _I((hcap,), jnp.int32),
+            _I((8,), jnp.int32), _I((8,), jnp.int32), _I((8,), jnp.int32),
+            _I((8,), jnp.bool_)).as_text(debug_info=True)
+
+    text = lowered(1 << 15)
+    assert "degrees.hist/degrees.hist.head/dot_general" in text
+    assert "degrees.hist/degrees.hist.tail/" in text
+    assert "degrees.hist.tail/scatter-add" in text
+    assert "degrees.hist.fallback/scatter-add" in text
+    fallback = [ln for ln in text.splitlines()
+                if "degrees.hist.fallback/" in ln]
+    assert all("degrees.hist/" in ln for ln in fallback)
+    small = lowered(64)
+    assert "degrees.hist.head/dot_general" in small
+    assert "degrees.hist.tail" not in small
+    assert "degrees.hist.fallback" not in small
+
+
+def _op(name, scope_path, start, dur=10.0):
+    return [f'%{name} = s32[8]{{0}} fusion(), metadata={{op_name='
+            f'"jit(degree_step)/{scope_path}/add"}}', start, dur]
+
+
+def test_the_tool_reads_the_histograms_parts_and_the_fallbacks_share():
+    """Three executions of the step, the second of which took the
+    fallback: the share is a third, and the histogram's parts stand
+    beside the phases' sum, not in it."""
+    tp = _trace_phases()
+    ops = []
+    for k, took in enumerate((False, True, False)):
+        a = 1000.0 * k
+        ops += [_op("f1", "degrees.sort", a + 10),
+                _op("f2", "degrees.hist/degrees.hist.head", a + 100, 20),
+                _op("f3", "degrees.hist/degrees.hist.tail", a + 200, 30)]
+        if took:
+            ops.append(_op("f4", "degrees.hist/cond/branch_0_fun/"
+                           "degrees.hist.fallback", a + 300, 200))
+    planes = [{"name": "/device:TPU:0", "lines": [
+        {"name": "XLA Modules",
+         "events": [["jit_degree_step(1)", 1000.0 * k, 900.0]
+                    for k in range(3)]},
+        {"name": "XLA Ops", "events": ops}]}]
+    block = tp.hist_fallback_block(planes, 0.0, 3000.0)
+    assert block == {"executions": 3, "hist_fallback_executions": 1,
+                     "hist_fallback_share": pytest.approx(1 / 3)}
+    from benchmarks.lib import scope_reduce
+
+    fb = scope_reduce.scope_seconds(planes, "jit_degree_step",
+                                    "degrees.hist.fallback", 0.0, 3000.0)
+    assert [round(x * 1e9) for x in fb] == [0, 200, 0]
+    hist = scope_reduce.scope_seconds(planes, "jit_degree_step",
+                                      "degrees.hist", 0.0, 3000.0)
+    assert [round(x * 1e9) for x in hist] == [50, 250, 50]
+    # a step without the scope (the parent's): no execution took it
+    older = [dict(planes[0], lines=[planes[0]["lines"][0], {
+        "name": "XLA Ops", "events": ops[:3]}])]
+    assert tp.hist_fallback_block(older, 0.0, 3000.0)[
+        "hist_fallback_executions"] == 0
+    m = {"degree_step_ms.dyn": {"value": 0.9},
+         "degree_step_sort_ms.dyn": {"value": 0.01},
+         "degree_step_hist_ms.dyn": {"value": 0.117},
+         "degree_step_hist_head_ms.dyn": {"value": 0.02},
+         "degree_step_hist_tail_ms.dyn": {"value": 0.03},
+         "degree_step_hist_fallback_ms.dyn": {"value": 0.067}}
+    phases = tp.phases_block(m)
+    assert phases["sum_ms"] == pytest.approx(0.127)
+    assert phases["hist_head_ms"] == 0.02
+    assert phases["hist_tail_ms"] == 0.03
+    assert phases["hist_fallback_ms"] == 0.067
+    assert set(tp.DEGREE_HIST_PARTS) == {"head", "tail", "fallback"}
+    assert {f"degree_step_hist_{p}_ms.dyn" for p in tp.DEGREE_HIST_PARTS} \
+        <= set(tp.PROPOSED)
+
+
+# --------------------------------------------------------------------- #
 # the operator's device trace
 # --------------------------------------------------------------------- #
 def test_device_trace_turns_annotations_on_for_the_block_and_back(tmp_path):

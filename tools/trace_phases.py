@@ -11,7 +11,9 @@ ones ``PROPOSED`` lists: the forest step's phases and loop trips
 of each, ``forest_wide_rounds`` and ``forest_narrow_rounds``), the
 degree step's phases (the
 ``dd-g500-s28`` cell: ``degrees.sort|scan|gather|scatter|hist`` inside
-``jit_degree_step``), the size table's back phase (the
+``jit_degree_step``, and inside ``degrees.hist`` its head's contraction,
+its tail's slab and the slab's fallback, ``degrees.hist.head|tail|
+fallback``), the size table's back phase (the
 ``ccsize-g500-s28`` cell: ``forest.sizes`` inside ``jit_step``, and the
 size lanes' ``jit__gather``), the window's host life under its root
 span (``window.emit``, ``serving.publish`` and the root's self time) and,
@@ -48,7 +50,8 @@ and of the fixpoint over their loops' rounds, the chase's slab under
 contraction under ``forest.contract`` and, where the table is
 sharded over chips, the collectives of a step under ``forest.exchange``:
 their ms, their count and the span attributes ``shards`` and
-``owner_max_share``), ``events`` (span
+``owner_max_share``; for the degree step the histogram's three parts
+and how many executions took the fallback), ``events`` (span
 events per window and per sweep), ``window`` (the host life of a window
 under its root span ``serving.window``: the mean of the root, of each
 child and of the root's SELF time, which is the host time no span names;
@@ -72,6 +75,7 @@ import time
 _T_PROCESS = time.perf_counter()
 
 import argparse
+import bisect
 import json
 import os
 import sys
@@ -89,6 +93,9 @@ SIZE = ["ccsize-g500-s28.ingest-saturated-size"]
 STEP_PROGRAM = {**dict.fromkeys(SAT + CC + V4 + SIZE, "jit_step"),
                 **dict.fromkeys(DYN, "jit_degree_step")}
 DEGREE_PHASES = ("sort", "scan", "gather", "scatter", "hist")
+#: the scopes nested in ``degrees.hist``: the head's one contraction, the
+#: tail's slab, and the fallback a window takes where the slab is short
+DEGREE_HIST_PARTS = ("head", "tail", "fallback")
 
 
 #: every span the ingest thread emits for a window of a cell (5.0 events
@@ -172,6 +179,13 @@ PROPOSED = {
         "ms", "degree step", "edges_per_s", DYN,
         _scope("scope_mean_ms", f"degrees.{p}", "jit_degree_step"))
        for p in DEGREE_PHASES},
+    # inside `degrees.hist`, so NOT phases to add to the sum; the
+    # fallback's mean is over every execution, those that did not take
+    # it at 0 (none took it: no reading)
+    **{f"degree_step_hist_{p}_ms.dyn": (
+        "ms", "degree step", "edges_per_s", DYN,
+        _scope("scope_mean_ms", f"degrees.hist.{p}", "jit_degree_step"))
+       for p in DEGREE_HIST_PARTS},
     "degree_prep_ms.dyn": ("ms", "window host step", "edges_per_s", DYN,
                            {"kind": "span_mean_ms", "span": "degrees.prep"}),
     "degree_dispatch_ms.dyn": ("ms", "window host step", "edges_per_s", DYN,
@@ -237,16 +251,21 @@ def phases_block(m: dict) -> dict:
     trip costs at most ``narrow_ms`` (compaction and write-back in it)
     over ``forest_narrow_rounds``."""
     if "degree_step_ms.dyn" in m:
-        # the degree step has no loop and nothing nested: the scopes'
-        # sum against the whole step (the rest is the table's copy)
+        # the degree step has no loop: the scopes' sum against the whole
+        # step (the rest is the table's copy), and beside it the
+        # histogram's parts, nested in `degrees.hist`
         step = m["degree_step_ms.dyn"]["value"]
         parts = {p: m[f"degree_step_{p}_ms.dyn"]["value"]
                  for p in DEGREE_PHASES if f"degree_step_{p}_ms.dyn" in m}
         if not parts:
             return {}
+        nested = {p: m[f"degree_step_hist_{p}_ms.dyn"]["value"]
+                  for p in DEGREE_HIST_PARTS
+                  if f"degree_step_hist_{p}_ms.dyn" in m}
         return {"sum_ms": sum(parts.values()), "step_ms": step,
                 "share": sum(parts.values()) / step,
-                **{f"{p}_ms": v for p, v in parts.items()}}
+                **{f"{p}_ms": v for p, v in parts.items()},
+                **{f"hist_{p}_ms": v for p, v in nested.items()}}
     step = next((m[k]["value"] for k in m if k.startswith("forest_step_ms")),
                 None)
     tag = next((t for t in (".sat", ".v4", ".size")
@@ -304,6 +323,33 @@ def _exchanges(ctx: dict) -> dict:
             e["attrs"]["shards"] for e in ctx["spans"]
             if e["name"] == "forest.window" and "shards" in e.get("attrs", {}))
     return out
+
+
+def hist_fallback_block(planes: list, lo: float, hi: float) -> dict:
+    """How many executions of the degree step took the histogram's
+    fallback: those with an op under ``degrees.hist.fallback`` (the
+    branch that did not run leaves no op in the trace). A step without
+    the scope reads 0 of its executions."""
+    from benchmarks.lib import scope_reduce, trace_reduce
+
+    runs = scope_reduce.executions(planes, "jit_degree_step", lo, hi)
+    ops = trace_reduce.line_of(trace_reduce.device_planes(planes)[0],
+                               trace_reduce.OPS_LINE)["events"]
+    starts = sorted(s for n, s, _d in ops
+                    if "degrees.hist.fallback" in scope_reduce.op_path(n))
+    took = 0
+    for a, b in runs:
+        i = bisect.bisect_left(starts, a)
+        took += i < len(starts) and starts[i] < b
+    return {"executions": len(runs), "hist_fallback_executions": took,
+            "hist_fallback_share": took / len(runs)}
+
+
+def _hist_fallback(ctx: dict) -> dict:
+    from benchmarks.lib import scope_reduce
+
+    return hist_fallback_block(scope_reduce._scoped(ctx), ctx["lo"],
+                               ctx["hi"])
 
 
 def _table_ops(ctx: dict) -> list:
@@ -572,6 +618,8 @@ def main(argv=None) -> int:
             readers.append(("exchanges", _exchanges))
         if cell.name in DYN + SIZE:
             readers.append(("table_ops", _table_ops))
+        if cell.name in DYN:
+            readers.append(("hist_fallback", _hist_fallback))
         for key, fn in readers:
             try:
                 extras[key] = fn(ctx)
@@ -601,12 +649,16 @@ def main(argv=None) -> int:
     phases = phases_block(doc["metrics"])
     if phases:
         phases.update(extras.pop("exchanges", {}))
+        phases.update(extras.pop("hist_fallback", {}))
         extras["phases"] = phases
     doc.update(extras)
     doc["proposed"] = added
     line = json.dumps(doc)
+    # a proposed metric whose scope did not run (the fallback no window
+    # took, a scope the parent of the PR that adds it lacks) is left out
     problems = lastline.validate(
-        line, required=cell.units("per_layer"),
+        line, required={n: u for n, u in cell.units("per_layer").items()
+                        if n not in added},
         allowed={**cell.units("end_to_end"), **cell.units("per_layer")},
         trace=True, chips=cell.chips)
     for p in problems:
