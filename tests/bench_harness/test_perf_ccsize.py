@@ -24,6 +24,7 @@ for p in (REPO, HERE, os.path.dirname(HERE)):
     if p not in sys.path:
         sys.path.insert(0, p)
 
+import _contract  # noqa: E402
 from _size_ref import SizeRef  # noqa: E402
 from _tiny import make_tiny_root  # noqa: E402
 
@@ -32,12 +33,6 @@ from benchmarks.lib import bytes_model, cellrun, lastline, spec  # noqa: E402
 
 CELL = "ccsize-g500-s28.ingest-saturated-size"
 CONTROL_CELL = "cc-g500-s28.ingest-saturated"
-NEW_METRICS = [
-    "forest_step_ms.size", "forest_step_roofline.size", "ingest_host_ms.size",
-    "pack_ms.size", "answer_ms.size", "size_lookup_ms.size",
-    "query_kernel_ms.size", "generator_late_p95_ms.size",
-    "compiles_in_window.size"]
-
 
 # ---- the module's contract, from a tiny tree of its own -------------- #
 def _tiny_root(tmp_path) -> str:
@@ -214,54 +209,19 @@ def test_the_reference_keeps_the_sizes_a_sequential_union_find_keeps(
 
 
 # ---- the new entries of BENCHMARK.json ------------------------------- #
-def _names(bench, key):
-    return [entry["name"] for entry in bench[key]]
-
-
 def test_the_new_entries_resolve_and_keep_to_the_contract():
-    assert spec.check_names_resolve() == []
-    bench = spec.load_benchmark()
+    """Found by NAME: the cell, its nine per-layer entries whole,
+    together and in their order after the degree cell's, and the
+    end-to-end metrics it reports; a later PR's entries after them fit
+    in."""
+    _contract.entries_stand(REPO, CELL)
+    _contract.cells_stand(REPO)
+    _contract.four_chip_cells_fit(REPO)
+    # the device programs the trace readers name include the CC cells'
     cell = spec.load_cell(CELL)
-    assert cell.chips == 1 and cell.traffic_name == "ingest-saturated-size"
-    assert set(cell.end_to_end) == {"edges_per_s", "query_p95_ms", "setup_s"}
-    assert sorted(cell.per_layer) == sorted(NEW_METRICS)
-    for name, m in cell.per_layer.items():
-        assert m["workloads"] == [CELL]
-        assert cell.readers[name]["reader"]["kind"] in cellrun.READERS
-    assert cell.readers["forest_step_roofline.size"]["reader"] == {
-        "kind": "program_bytes_share", "program": "jit_step",
-        "bytes_model": "forest_step"}
-    assert cell.readers["size_lookup_ms.size"]["reader"] == {
-        "kind": "span_mean_ms", "span": "serving.size_lookup"}
-    assert cell.per_layer["size_lookup_ms.size"]["moves"] == "query_p95_ms"
-    assert cell.per_layer["forest_step_roofline.size"]["unit"] == "%"
-    # the device programs the trace readers name are the CC cells' own
     assert {r["reader"]["program"] for r in cell.readers.values()
-            if r["reader"]["kind"].startswith("program_")} == {
+            if r["reader"]["kind"].startswith("program_")} >= {
         "jit_step", "jit__batch_roots"}
-    # found by NAME: this PR's entries stand together, in order, after
-    # everything the benchmark had, wherever a later PR puts its own
-    cells = _names(bench, "workloads")
-    assert cells.index(CELL) > cells.index("dd-g500-s28.ingest-saturated-dyn")
-    configs = _names(bench, "configs")
-    assert configs.index("ccsize-g500-s28") > configs.index("dd-g500-s28")
-    metrics = _names(bench, "per_layer")
-    first = metrics.index(NEW_METRICS[0])
-    assert metrics[first:first + len(NEW_METRICS)] == NEW_METRICS
-    assert first > metrics.index("compiles_in_window.dyn")
-    for m in bench["end_to_end"]:
-        if m["name"] in ("edges_per_s", "query_p95_ms"):
-            assert m["workloads"].index(CELL) > m["workloads"].index(
-                "dd-g500-s28.ingest-saturated-dyn")
-        else:
-            assert CELL not in m.get("workloads", [])
-    four = [w for w in bench["workloads"] if w["chips"] == 4]
-    assert len(four) == 1 <= max(1, len(bench["workloads"]) // 4)
-    assert all(len(w["why"]) <= 200 for w in bench["workloads"])
-    assert all(len(c["source"]) <= 200 for c in bench["configs"])
-    assert len({c["source"] for c in bench["configs"]}) == len(
-        bench["configs"])
-    assert bench["run_seconds"] == 45
 
 
 def test_the_configuration_is_its_control_plus_the_size_table():

@@ -25,6 +25,7 @@ for p in (REPO, HERE):
     if p not in sys.path:
         sys.path.insert(0, p)
 
+import _contract  # noqa: E402
 from _tiny import make_tiny_root  # noqa: E402
 
 from benchmarks.algorithms import degdist  # noqa: E402
@@ -282,86 +283,29 @@ def test_the_closing_windows_are_the_seeds_own_with_deletions():
 
 
 # ---- the new entries of BENCHMARK.json ------------------------------- #
-V4_CELL = "cc-g500-s30-v4.ingest-saturated"
-V4_METRICS = [
-    "forest_step_ms.v4", "forest_step_roofline.v4", "place_ms.v4",
-    "ingest_host_ms.v4", "answer_ms.v4", "query_kernel_ms.v4",
-    "compiles_in_window.v4"]
-#: the cells the benchmark had before this PR, in their order
-OLD_CELLS = ["cc-g500-s28.ingest-saturated", "cc-g500-s28.paced-query-heavy",
-             "bip-g500-s27.ingest-saturated-poll", V4_CELL]
-
-
-def _names(bench, key):
-    return [entry["name"] for entry in bench[key]]
-
-
-NEW_METRICS = [
-    "degree_step_ms.dyn", "degree_step_roofline.dyn", "degree_window_ms.dyn",
-    "pack_ms.dyn", "ingest_host_ms.dyn", "answer_ms.dyn",
-    "degree_gather_ms.dyn", "generator_late_p95_ms.dyn",
-    "compiles_in_window.dyn"]
+V4_CELL = _contract.V4_CELL
 
 
 def test_the_new_entries_resolve_and_keep_to_the_contract():
-    assert spec.check_names_resolve() == []
-    bench = spec.load_benchmark()
-    cell = spec.load_cell(CELL)
-    assert cell.chips == 1 and cell.traffic_name == "ingest-saturated-dyn"
-    assert set(cell.end_to_end) == {"edges_per_s", "query_p95_ms", "setup_s"}
-    assert sorted(cell.per_layer) == sorted(NEW_METRICS)
-    for name, m in cell.per_layer.items():
-        assert m["workloads"] == [CELL]
-        assert cell.readers[name]["reader"]["kind"] in cellrun.READERS
-    assert cell.readers["degree_step_roofline.dyn"]["reader"] == {
-        "kind": "program_bytes_share", "program": "jit_degree_step",
-        "bytes_model": "forest_step"}
-    assert cell.per_layer["degree_step_roofline.dyn"]["unit"] == "%"
-    # found by NAME: this PR's entries keep their order and come after
-    # everything the benchmark had, wherever a later PR puts its own
-    cells = _names(bench, "workloads")
-    assert cells.index(CELL) > cells.index(V4_CELL) == len(OLD_CELLS) - 1
-    assert cells[:len(OLD_CELLS)] == OLD_CELLS
-    configs = _names(bench, "configs")
-    assert configs.index("dd-g500-s28") > configs.index("cc-g500-s30-v4")
-    metrics = _names(bench, "per_layer")
-    first = metrics.index(NEW_METRICS[0])
-    assert metrics[first:first + len(NEW_METRICS)] == NEW_METRICS
-    assert first > metrics.index(V4_METRICS[-1])
-    for m in bench["end_to_end"]:
-        if m["name"] in ("edges_per_s", "query_p95_ms"):
-            assert m["workloads"].index(CELL) > m["workloads"].index(V4_CELL)
-        else:
-            assert CELL not in m.get("workloads", [])
-    four = [w for w in bench["workloads"] if w["chips"] == 4]
-    assert len(four) <= max(1, len(bench["workloads"]) // 4)
-    assert all(len(w["why"]) <= 200 for w in bench["workloads"])
-    assert all(len(c["source"]) <= 200 for c in bench["configs"])
-    assert bench["run_seconds"] == 45
+    """Found by NAME: the cell, its nine per-layer entries whole (reader
+    kinds and files, units, ``moves``), together and in their order
+    after the four-chip cell's, and the end-to-end metrics it reports;
+    a later PR's entries after them fit in."""
+    _contract.entries_stand(REPO, CELL)
+    _contract.cells_stand(REPO)
+    _contract.four_chip_cells_fit(REPO)
 
 
 def test_pr_28s_entries_stand_as_they_stood():
-    """Every assertion of ``test_perf_vsharded.py``'s
-    ``test_the_new_entries_resolve_and_keep_to_the_contract`` but "they
-    are the last": that one fails since this PR's entries follow them
-    (``conftest.py``), so what else it held is held here, by name."""
-    bench = spec.load_benchmark()
-    cell = spec.load_cell(V4_CELL)
-    assert cell.chips == 4 and cell.traffic_name == "ingest-saturated"
-    assert set(cell.end_to_end) == {"edges_per_s", "query_p95_ms", "setup_s"}
-    assert sorted(cell.per_layer) == sorted(V4_METRICS)
-    for name, m in cell.per_layer.items():
-        assert m["workloads"] == [V4_CELL]
-        assert cell.readers[name]["reader"]["kind"] in cellrun.READERS
-    # one four-chip cell, and no more than a quarter of the cells
-    four = [w["name"] for w in bench["workloads"] if w["chips"] == 4]
-    assert four == [V4_CELL]
-    assert len(four) <= max(1, len(bench["workloads"]) // 4)
-    # its seven metrics stand together, in the order PR 28 gave them
-    metrics = _names(bench, "per_layer")
-    first = metrics.index(V4_METRICS[0])
-    assert metrics[first:first + len(V4_METRICS)] == V4_METRICS
-    assert "cc-g500-s30-v4" in _names(bench, "configs")
+    """The four-chip cell's entries by name, as
+    ``test_perf_vsharded.py``'s
+    ``test_the_new_entries_resolve_and_keep_to_the_contract`` holds them:
+    four chips, its end-to-end metrics, its seven per-layer entries
+    whole, together and in their order, its configuration, and among
+    the four-chip cells, which are no more than a quarter of the
+    cells."""
+    _contract.entries_stand(REPO, V4_CELL)
+    _contract.four_chip_cells_fit(REPO)
 
 
 def test_the_configuration_states_its_cut_and_its_stream():

@@ -1,7 +1,7 @@
 """The host fold's spans and the serving waits as per-layer metrics
 (ISSUE 38): five entries over spans every program since PR 26 emits,
-added as data files alone. They resolve, they stand at the end of
-``per_layer``, they use reader kinds the harness has, their cells
+added as data files alone. They resolve, they stand together after
+the sized cell's entries, they use reader kinds the harness has, their cells
 report what they move, and a tiny traced run on the CPU gives each of
 them a number (a time of the CPU's: never a device number)."""
 
@@ -22,48 +22,20 @@ for p in (REPO, HERE):
     if p not in sys.path:
         sys.path.insert(0, p)
 
+import _contract  # noqa: E402
 from _tiny import make_tiny_root  # noqa: E402
 
 from benchmarks.lib import cellrun, spec, trace_reduce  # noqa: E402
 
-SAT = ["cc-g500-s28.ingest-saturated", "bip-g500-s27.ingest-saturated-poll"]
-CC = ["cc-g500-s28.ingest-saturated", "cc-g500-s28.paced-query-heavy"]
 #: name -> (span, layer, moves, cells), in the order they were entered
-ENTERED = {
-    "fold_host_ms.sat": ("forest.window", "window host step",
-                         "edges_per_s", SAT),
-    "fold_prep_ms.sat": ("forest.prep", "window host step",
-                         "edges_per_s", SAT),
-    "fold_dispatch_ms.sat": ("forest.dispatch", "window host step",
-                             "edges_per_s", SAT),
-    "queue_wait_ms": ("serving.queue_wait", "serving", "query_p95_ms", CC),
-    "answer_wait_ms": ("serving.device_wait", "serving", "query_p95_ms", CC),
-}
+ENTERED = _contract.HOST_SPANS
 FIXTURE = os.path.join(HERE, "fixtures", "trace_cc_saturated_v5e.json")
 
 
 def test_the_new_entries_resolve_and_stand_at_the_end():
     assert spec.check_names_resolve() == []
-    bench = spec.load_benchmark()
-    names = [m["name"] for m in bench["per_layer"]]
-    # found by NAME: together, in order, after everything PR 36 entered
-    first = names.index("fold_host_ms.sat")
-    assert names[first:first + len(ENTERED)] == list(ENTERED)
-    assert first > names.index("compiles_in_window.size")
-    layers = {m["layer"] for m in bench["per_layer"][:first]}
-    for m in bench["per_layer"][first:first + len(ENTERED)]:
-        span, layer, moves, cells = ENTERED[m["name"]]
-        assert set(m) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        assert (m["unit"], m["better"], m["source"]) == (
-            "ms", "lower", "program_span")
-        assert (m["layer"], m["moves"], m["workloads"]) == (
-            layer, moves, cells)
-        assert layer in layers          # a layer the benchmark names
-        with open(os.path.join(REPO, "benchmarks", "layer_metrics",
-                               m["name"] + ".json")) as f:
-            assert json.load(f) == {
-                "reader": {"kind": "span_mean_ms", "span": span}}
+    # found by NAME: together, in order, after the sized cell's entries
+    _contract.host_span_entries_stand(REPO)
 
 
 @pytest.mark.parametrize("name", list(ENTERED))
@@ -79,29 +51,14 @@ def test_an_entrys_cells_report_what_it_moves(name):
 
 
 def test_only_entries_were_added_and_no_cell_lost_a_metric():
-    """Nothing but the end of ``per_layer`` grew: no configuration, no
-    cell, no traffic file, no end-to-end entry; the cells whose SET of
-    per-layer metrics other tests of this directory hold (four chips,
-    the degree cell, the sized cell) have what they had."""
-    bench = spec.load_benchmark()
-    assert len(bench["configs"]) == 5 and len(bench["workloads"]) == 6
-    assert [m["name"] for m in bench["end_to_end"]] == [
-        "edges_per_s", "window_p95_ms", "query_p95_ms", "answer_age_p95_ms",
-        "setup_s"]
-    assert bench["run_seconds"] == 45
-    grown = {c for _s, _l, _m, cells in ENTERED.values() for c in cells}
-    for w in bench["workloads"]:
-        cell = spec.load_cell(w["name"])
-        mine = set(cell.per_layer) & set(ENTERED)
-        assert bool(mine) == (w["name"] in grown)
-    # by name, so that a later PR's entries for these cells fit in
-    assert {"pack_ms.sat", "ingest_host_ms.sat", "forest_step_ms.sat",
-            "answer_ms", "query_kernel_ms"} <= set(
-        spec.load_cell(CC[0]).per_layer)
-    assert {"forest_step_ms.paced", "answer_ms", "query_kernel_ms"} <= set(
-        spec.load_cell(CC[1]).per_layer)
-    assert {"pack_ms.sat", "ingest_host_ms.sat", "verdict_query_ms"} <= set(
-        spec.load_cell(SAT[1]).per_layer)
+    """The five configurations and six cells stand, by name and ahead of
+    any added later; each cell reports the end-to-end metrics it had and
+    at least the per-layer metrics it had, in their order, and carries a
+    host-span entry exactly where that entry was entered; the end-to-end
+    list begins as it did and a run is 45 s. A configuration, a cell or
+    a metric added at the end of its list passes (``test_perf_growth``
+    holds that on trees with more cells)."""
+    _contract.cells_stand(REPO)
 
 
 def test_the_span_reader_means_a_span_and_leaves_out_what_is_not_there():

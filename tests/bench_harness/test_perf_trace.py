@@ -2,12 +2,13 @@
 recording of the first chip run of ``cc-g500-s28.ingest-saturated``
 (TPU v5e, PR 24): planes ``/device:TPU:0`` (lines ``XLA Modules`` and
 ``XLA Ops``) and ``/host:CPU``. The numbers asserted here are
-properties of the reduction, not measurements."""
+properties of the reduction, not measurements. Each cell's trace
+readers are read against the recording under ``fixtures/`` that holds
+their program (``_contract.index_recordings``)."""
 
 from __future__ import annotations
 
 import json
-import math
 import os
 import sys
 
@@ -15,19 +16,27 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-if REPO not in sys.path:
-    sys.path.insert(0, REPO)
+HERE = os.path.dirname(os.path.abspath(__file__))
+for p in (REPO, HERE):
+    if p not in sys.path:
+        sys.path.insert(0, p)
 
-from benchmarks.lib import cellrun, spec, trace_reduce as tr  # noqa: E402
+import _contract  # noqa: E402
 
-FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                       "fixtures", "trace_cc_saturated_v5e.json")
+from benchmarks.lib import spec, trace_reduce as tr  # noqa: E402
+
+FIXTURE = os.path.join(HERE, "fixtures", "trace_cc_saturated_v5e.json")
 
 
 @pytest.fixture(scope="module")
 def planes():
     with open(FIXTURE) as f:
         return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def recordings():
+    return _contract.index_recordings(_contract.FIXTURES)
 
 
 def test_the_window_is_the_host_annotation_on_the_trace_clock(planes):
@@ -118,19 +127,16 @@ def test_breakdown_lists_programs_and_attributed_gaps(planes):
 @pytest.mark.parametrize(
     "cell_name", [w["name"] for w in spec.load_benchmark()["workloads"]])
 def test_every_trace_metric_of_a_cell_resolves_to_a_finite_number(
-        planes, cell_name):
-    """Each per-layer metric that reads the trace, through its own
-    reader file, against the recording."""
-    cell = spec.load_cell(cell_name)
-    lo, hi = tr.window_bounds(planes)
-    ctx = {"planes": planes, "lo": lo, "hi": hi}
-    seen = 0
-    for name, reader in cell.readers.items():
-        r = reader["reader"]
-        assert r["kind"] in cellrun.READERS, name
-        if r["kind"] != "program_mean_ms":
-            continue
-        value = cellrun.READERS[r["kind"]](r, ctx)
-        assert math.isfinite(value) and value > 0, name
-        seen += 1
-    assert seen >= 1
+        recordings, cell_name):
+    """Each per-layer metric that reads a program's time from the trace,
+    through its own reader file, against the recording that holds the
+    program; a program that no recording holds fails."""
+    _contract.trace_readers_resolve(REPO, cell_name, recordings)
+
+
+def test_each_program_is_read_from_the_recording_of_its_cell(recordings):
+    held = {p: fn for p, (fn, *_rest) in recordings.items()}
+    assert held["jit_step"] == held["jit__batch_roots"] == (
+        "trace_cc_saturated_v5e.json")
+    assert held["jit_degree_step"] == held["jit__gather"] == (
+        "trace_dd_saturated_v5e.json")

@@ -24,6 +24,7 @@ for p in (REPO, HERE):
     if p not in sys.path:
         sys.path.insert(0, p)
 
+import _contract  # noqa: E402
 from _tiny import make_tiny_root  # noqa: E402
 
 from benchmarks.algorithms import cc_vsharded  # noqa: E402
@@ -164,30 +165,13 @@ def test_a_program_without_the_axis_fails_before_anything_is_allocated(
 
 # ---- the new entries of BENCHMARK.json ------------------------------- #
 def test_the_new_entries_resolve_and_keep_to_the_contract():
-    assert spec.check_names_resolve() == []
-    bench = spec.load_benchmark()
-    cell = spec.load_cell(CELL)
-    assert cell.chips == 4 and cell.traffic_name == "ingest-saturated"
-    assert set(cell.end_to_end) == {"edges_per_s", "query_p95_ms", "setup_s"}
-    assert sorted(cell.per_layer) == [
-        "answer_ms.v4", "compiles_in_window.v4", "forest_step_ms.v4",
-        "forest_step_roofline.v4", "ingest_host_ms.v4", "place_ms.v4",
-        "query_kernel_ms.v4"]
-    for name, m in cell.per_layer.items():
-        assert m["workloads"] == [CELL]
-        assert cell.readers[name]["reader"]["kind"] in cellrun.READERS
-    # one four-chip cell in four: the most a benchmark of this size may have
-    four = [w for w in bench["workloads"] if w["chips"] == 4]
-    assert [w["name"] for w in four] == [CELL]
-    assert len(four) <= max(1, len(bench["workloads"]) // 4)
-    assert all(len(w["why"]) <= 200 for w in bench["workloads"])
-    # new entries stand at the end of their lists
-    assert bench["workloads"][-1]["name"] == CELL
-    assert bench["configs"][-1]["name"] == "cc-g500-s30-v4"
-    assert [m["name"] for m in bench["per_layer"]][-7:] == [
-        "forest_step_ms.v4", "forest_step_roofline.v4", "place_ms.v4",
-        "ingest_host_ms.v4", "answer_ms.v4", "query_kernel_ms.v4",
-        "compiles_in_window.v4"]
+    """Found by NAME, wherever later entries stand: the cell on four
+    chips with its end-to-end metrics; its seven per-layer entries
+    whole, together and in their order; its configuration; and the
+    four-chip cells no more than a quarter of the cells."""
+    _contract.entries_stand(REPO, CELL)
+    _contract.four_chip_cells_fit(REPO)
+    _contract.cells_stand(REPO)
 
 
 def test_the_configuration_states_its_layout_and_its_cut():
